@@ -9,8 +9,10 @@ Design rules:
 
 * Pure C ABI loaded via ctypes (this image has no pybind11).
 * The library is built lazily from ``native/*.cpp`` with ``g++`` the first
-  time it is needed and cached beside the sources; no compiler → the
-  Python implementations are used silently.
+  time it is needed and cached beside the sources under a name that
+  carries the source's digest, so a library built from any other source
+  is never loaded; no compiler → the Python implementations are used
+  (:func:`status` says which ran).
 * Kernels are STRICT: anything surprising (malformed JSON, nulls,
   string-typed numerics) makes them decline the whole batch, and callers
   run their exact-semantics Python path instead. A kernel may be fast or
@@ -21,6 +23,7 @@ Design rules:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,7 +35,6 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libpioprops.so")
 _SRC_PATH = os.path.join(_NATIVE_DIR, "jsonprops.cpp")
 
 _lib = None
@@ -40,7 +42,21 @@ _lib_tried = False
 _lib_lock = threading.Lock()
 
 
-def _build() -> bool:
+def _source_digest() -> Optional[str]:
+    try:
+        with open(_SRC_PATH, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+
+
+def _so_path(digest: str) -> str:
+    # the digest in the name is what ties a library to the committed
+    # source: file times say nothing in a fresh checkout or a copied tree
+    return os.path.join(_NATIVE_DIR, f"libpioprops-{digest}.so")
+
+
+def _build(so_path: str) -> bool:
     """Compile the kernel library; True on success.
 
     Compiles to a per-process temp name and os.replace()s into place —
@@ -48,7 +64,7 @@ def _build() -> bool:
     topology) must never dlopen a half-written file.
     """
     gxx = os.environ.get("CXX") or "g++"
-    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             [gxx, "-O3", "-Wall", "-shared", "-fPIC", "-o", tmp, _SRC_PATH],
@@ -56,7 +72,7 @@ def _build() -> bool:
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _SO_PATH)
+        os.replace(tmp, so_path)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         logger.info("native kernel build unavailable (%s); using Python paths", e)
@@ -78,14 +94,14 @@ def load() -> Optional[ctypes.CDLL]:
         _lib_tried = True
         if os.environ.get("PIO_NATIVE", "1") == "0":
             return None
-        if not os.path.exists(_SO_PATH) or (
-            os.path.exists(_SRC_PATH)
-            and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)
-        ):
-            if not os.path.exists(_SRC_PATH) or not _build():
-                return None
+        digest = _source_digest()
+        if digest is None:
+            return None
+        so_path = _so_path(digest)
+        if not os.path.exists(so_path) and not _build(so_path):
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(so_path)
         except OSError as e:
             logger.info("native kernel load failed (%s); using Python paths", e)
             return None
@@ -106,6 +122,13 @@ def load() -> Optional[ctypes.CDLL]:
         lib.pio_props_free.argtypes = [ctypes.c_void_p]
         _lib = lib
         return _lib
+
+
+def status() -> dict:
+    """Which implementation serves this process: ``{"loaded": bool,
+    "source_sha256": digest-or-None}``.  ``loaded`` is True only for a
+    library built from the source with that digest."""
+    return {"loaded": load() is not None, "source_sha256": _source_digest()}
 
 
 def scan_numeric_props(props) -> Optional[dict[str, np.ndarray]]:

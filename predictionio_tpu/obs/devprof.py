@@ -7,9 +7,9 @@ runs (``bench.py``).  This module makes utilization a RUNTIME fact:
 * **One cost model, one peak table.**  The analytic ALS iteration cost and
   the per-chip peak table previously private to ``bench.py`` live here, so
   the bench, the training loop, and the serving fastpath all divide by the
-  same denominators.  ``PEAKS`` carries a CPU entry: fallback runs report a
-  real (if rough) MFU instead of null, which keeps regression ratios
-  comparable run-over-run on the same host.
+  same denominators.  ``PEAKS`` is keyed by ``device_kind``; a device that
+  is not in it (a CPU, another TPU generation) reports null utilization,
+  never another chip's.
 * **Rolling-window dispatch accountant** (:class:`DeviceUtilization`).
   The serving fastpath annotates every AOT bucket with FLOPs/bytes from
   ``compiled.cost_analysis()`` (analytic fallback when the compiler
@@ -48,27 +48,25 @@ __all__ = [
     "capture_profile",
 ]
 
-# Per-chip peaks for utilization accounting. v5e: 197 TFLOP/s bf16 MXU,
-# 819 GB/s HBM (public spec). mfu is defined against the bf16 peak — the
-# number the hardware markets — so a 10× utilization regression is visible
-# regardless of the dtype in use. The CPU row is an order-of-magnitude
-# stand-in for a modern server socket (~1 TFLOP/s f32 SIMD, ~100 GB/s
-# DRAM): good for run-over-run ratios on the same fallback host, not for
-# publishing as an absolute hardware number. Platforms not listed report
-# null utilization.
+# Per-chip peaks for utilization accounting, keyed by the ``device_kind``
+# JAX reports (``jax.devices()[0].device_kind``).  v5e: 197 TFLOP/s bf16
+# MXU, 819 GB/s HBM (Google Cloud documentation, "TPU v5e").  mfu is defined
+# against the bf16 peak — the number the hardware markets — so a 10×
+# utilization regression is visible regardless of the dtype in use.  A
+# device that is not listed reports null utilization: a CPU run never
+# prints an mfu, and another TPU generation never borrows v5e's peaks.
 PEAKS = {
-    "tpu": {"flops": 197e12, "hbm_gbps": 819e9},
-    "cpu": {"flops": 1e12, "hbm_gbps": 100e9},
+    "TPU v5 lite": {"flops": 197e12, "hbm_gbps": 819e9},
 }
 
 DEFAULT_WINDOW_S = 60.0
 
 
-def peak_for(platform: Optional[str]) -> Optional[dict]:
-    """Per-chip peak {flops, hbm_gbps} for a jax platform name, or None."""
-    if platform is None:
+def peak_for(device_kind: Optional[str]) -> Optional[dict]:
+    """Per-chip peak {flops, hbm_gbps} for a jax ``device_kind``, or None."""
+    if device_kind is None:
         return None
-    return PEAKS.get(str(platform).lower())
+    return PEAKS.get(str(device_kind))
 
 
 def als_train_cost(
@@ -100,7 +98,7 @@ def als_train_cost(
 
 def train_utilization(
     n_ratings, n_users, n_items, rank, iterations, dtype, dt, n_chips,
-    platform,
+    device_kind,
 ) -> dict:
     """Analytic achieved-FLOP/s + HBM-GB/s from workload dims and wall time.
 
@@ -112,7 +110,7 @@ def train_utilization(
     )
     flops = flops_per_iter * iterations / dt / n_chips
     gbps = bytes_per_iter * iterations / dt / n_chips
-    peak = peak_for(platform)
+    peak = peak_for(device_kind)
     return {
         "model_flops_per_sec_per_chip": round(flops / 1e9, 2),  # GFLOP/s
         "model_hbm_gbps_per_chip": round(gbps / 1e9, 2),
@@ -253,13 +251,13 @@ class DeviceUtilization:
     with its FLOPs/bytes once via :meth:`set_cost`, then calls
     :meth:`record` with the measured device wall per dispatch.  Records
     older than the window age out; :meth:`snapshot` reduces what's left
-    into achieved rates and utilization against the platform peak.  All
+    into achieved rates and utilization against the device's peak.  All
     methods are thread-safe; ``record`` is O(1) amortized.
     """
 
     def __init__(
         self,
-        platform: Optional[str] = None,
+        device_kind: Optional[str] = None,
         window_s: Optional[float] = None,
     ):
         if window_s is None:
@@ -267,7 +265,7 @@ class DeviceUtilization:
                 os.environ.get("PIO_DEVPROF_WINDOW", DEFAULT_WINDOW_S)
             )
         self.window_s = max(1.0, float(window_s))
-        self.platform = platform
+        self.device_kind = device_kind
         self._costs: dict = {}  # dispatch key → (flops, bytes)
         self._cost_source: dict = {}  # dispatch key → "xla" | "analytic"
         # (t_recorded, device_seconds, flops, bytes) per dispatch
@@ -334,9 +332,9 @@ class DeviceUtilization:
             n = len(self._records)
         flops_per_s = flops / elapsed
         gbps = nbytes / elapsed
-        peak = peak_for(self.platform)
+        peak = peak_for(self.device_kind)
         return {
-            "platform": self.platform,
+            "device_kind": self.device_kind,
             "window_s": self.window_s,
             "elapsed_s": round(elapsed, 3),
             "dispatches_window": n,
@@ -351,17 +349,6 @@ class DeviceUtilization:
         }
 
 
-def default_platform() -> Optional[str]:
-    """The jax default backend's platform name (lazy import; None if jax
-    is unavailable or not yet initializable)."""
-    try:
-        import jax
-
-        return jax.default_backend()
-    except Exception:
-        return None
-
-
 # -- train-side recorder ------------------------------------------------------
 # `pio train` has no HTTP server to scrape, so the train loop records into
 # a process-global accountant; the CLI and tests read the snapshot, and the
@@ -371,14 +358,14 @@ _train_lock = threading.Lock()
 _train_acc: Optional[DeviceUtilization] = None
 
 
-def train_recorder(platform: Optional[str] = None) -> DeviceUtilization:
+def train_recorder(device_kind: Optional[str] = None) -> DeviceUtilization:
     """The process-global training accountant (created on first use)."""
     global _train_acc
     with _train_lock:
         if _train_acc is None or (
-            platform is not None and _train_acc.platform != platform
+            device_kind is not None and _train_acc.device_kind != device_kind
         ):
-            _train_acc = DeviceUtilization(platform=platform)
+            _train_acc = DeviceUtilization(device_kind=device_kind)
         return _train_acc
 
 

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from predictionio_tpu.ops import pallas_mode
+
 NEG_INF = -1e30
 
 # (sublane, lane)-friendly defaults; one Q×K score block fits VMEM easily
@@ -342,8 +344,7 @@ def flash_attention(
     T must divide by the block sizes (pad beforehand for ragged lengths).
     ``interpret`` defaults to True off-TPU so tests run the kernel anywhere.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.resolve("flash_attention", interpret)
     t_q, d = q.shape[-2], q.shape[-1]
     t_kv = k.shape[-2]
     block_q = min(block_q, t_q)

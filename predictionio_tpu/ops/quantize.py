@@ -27,6 +27,21 @@ FACTOR_DTYPES = ("f32", "bf16", "int8")
 FACTOR_BYTES = {"f32": 4.0, "bf16": 2.0, "int8": 1.0}
 
 
+def contraction_precision(operand_dtype):
+    """The matmul precision a contraction over ``operand_dtype`` states.
+
+    A TPU's default rounds f32 operands to one bf16 pass — 3 decimal
+    digits where the path promised f32 — and no CPU test can see it.  So
+    an f32 contraction asks for ``HIGHEST``; bf16 operands, and f32
+    operands that merely hold dequantized narrow values, are already what
+    the MXU takes and stay at the default (None).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.Precision.HIGHEST if operand_dtype == jnp.float32 else None
+
+
 def _bf16():
     # ml_dtypes ships with jax; numpy itself has no bfloat16
     import ml_dtypes

@@ -6,9 +6,9 @@ between stages; ``docs/perf_roofline.md`` measures that round trip (plus
 the ~sector amplification on the row gather) as the reason serving MFU is
 effectively nil.  This kernel fuses all three stages on-chip:
 
-* the (B,) user rows are DMA-gathered from HBM straight into VMEM scratch
-  once per dispatch (scalar-prefetched indices — the full user matrix never
-  leaves HBM, and each 40–256 B row is fetched exactly once);
+* the (B,) user rows are gathered once per dispatch (by XLA, ahead of the
+  kernel — the full user matrix never leaves HBM) and sit VMEM-resident
+  for the whole item sweep;
 * the item-factor matrix streams through VMEM in ``BLOCK_I``-row blocks
   (1-D grid, like the K sweep in ``ops/flash_attention.py``) and is dotted
   against the resident gathered rows on the MXU;
@@ -44,6 +44,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from predictionio_tpu.ops import pallas_mode
+from predictionio_tpu.ops.quantize import contraction_precision
 
 NEG_INF = -1e30  # plain float: jnp constants would be captured as operands
 _IDX_SENTINEL = 2**31 - 1
@@ -123,24 +126,22 @@ def _merge_block(s, gidx, s_ref, vals_ref, idxs_ref, *, k: int, batch: int):
 
 
 def _score_topk_kernel(
-    u_idx_ref, *refs, k: int, block_i: int, batch: int,
+    *refs, k: int, block_i: int, batch: int,
     has_uscale: bool, has_vscale: bool,
 ):
-    """One grid step: gather (first block only), dot, merge, emit (last)."""
+    """One grid step: dot the resident user rows against this item block,
+    merge into the running top-k, emit on the last block."""
     it = iter(refs)
-    u_hbm = next(it)
-    us_hbm = next(it) if has_uscale else None
+    ug_ref = next(it)
+    us_ref = next(it) if has_uscale else None
     v_ref = next(it)
     vs_ref = next(it) if has_vscale else None
     mask_ref = next(it)
     vals_out = next(it)
     idx_out = next(it)
-    ug_ref = next(it)
-    us_ref = next(it) if has_uscale else None
     s_ref = next(it)
     vals_ref = next(it)
     idxs_ref = next(it)
-    sem = next(it)
 
     ii = pl.program_id(0)
     n_i = pl.num_programs(0)
@@ -150,27 +151,6 @@ def _score_topk_kernel(
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idxs_ref[...] = jnp.full_like(idxs_ref, jnp.int32(_IDX_SENTINEL))
 
-        # embedding-row gather: one DMA per batch row, HBM → VMEM scratch;
-        # rows then stay resident for the whole item sweep
-        def gather(r, carry):
-            row = u_idx_ref[r]
-            cp = pltpu.make_async_copy(
-                u_hbm.at[pl.ds(row, 1), :], ug_ref.at[pl.ds(r, 1), :], sem
-            )
-            cp.start()
-            cp.wait()
-            if has_uscale:
-                cps = pltpu.make_async_copy(
-                    us_hbm.at[pl.ds(row, 1), :],
-                    us_ref.at[pl.ds(r, 1), :],
-                    sem,
-                )
-                cps.start()
-                cps.wait()
-            return carry
-
-        jax.lax.fori_loop(0, batch, gather, 0)
-
     # dequantize in VMEM: HBM only ever streamed the narrow bytes
     ug = ug_ref[...].astype(jnp.float32)
     if has_uscale:
@@ -179,11 +159,11 @@ def _score_topk_kernel(
     s = jax.lax.dot_general(
         ug, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=contraction_precision(v_ref.dtype),
     )  # (B, block_i) on the MXU
     if has_vscale:
-        s = s * vs_ref[...].reshape(1, block_i)  # per-item-row scale
-    excl = mask_ref[...].reshape(1, block_i) != 0
-    s = jnp.where(excl, NEG_INF, s)
+        s = s * vs_ref[...]  # per-item scale, a (1, block_i) lane row
+    s = jnp.where(mask_ref[...] != 0, NEG_INF, s)
     gidx = ii * block_i + jax.lax.broadcasted_iota(
         jnp.int32, (batch, block_i), 1
     )
@@ -218,8 +198,7 @@ def fused_gather_score_topk(
     to :func:`pad_block_items`; ragged inputs are padded (and the tail
     masked) here.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.resolve("score_topk", interpret)
     n_items, rank = V.shape
     batch = u_idx.shape[0]
     if not 0 < k <= n_items:
@@ -238,7 +217,10 @@ def fused_gather_score_topk(
         excl = jnp.pad(excl, (0, pad_i), constant_values=True)
         if v_scale is not None:
             v_scale = jnp.pad(v_scale, ((0, pad_i), (0, 0)))
-    mask8 = excl.astype(jnp.int8)
+    # per-item operands ride as (1, n_pad) lane rows so each block already
+    # has the score tile's layout: Mosaic lowers neither a 1-D int8 block
+    # nor the (block_i, 1) -> (1, block_i) relayout of a scale column
+    mask_row = excl.astype(jnp.int32).reshape(1, n_pad)
 
     has_us = u_scale is not None
     has_vs = v_scale is not None
@@ -248,51 +230,42 @@ def fused_gather_score_topk(
         has_uscale=has_us, has_vscale=has_vs,
     )
 
-    def _pinned(ii, u_idx_ref):
+    def _pinned(ii):
         return (0, 0)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]  # full U stays in HBM
-    operands = [U]
+    # the (B,) embedding rows are gathered by XLA ahead of the kernel — B
+    # narrow rows, once per dispatch — and stay VMEM-resident for the whole
+    # item sweep; the full user matrix never leaves HBM.  (An in-kernel
+    # per-row DMA cannot slice a rank-wide row out of a 128-lane tile.)
+    u_idx = u_idx.astype(jnp.int32)
+    in_specs = [pl.BlockSpec((batch, rank), _pinned)]
+    operands = [U[u_idx]]
     if has_us:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-        operands.append(u_scale.astype(jnp.float32))
-    in_specs.append(
-        pl.BlockSpec((block_i, rank), lambda ii, u_idx_ref: (ii, 0))
-    )
+        in_specs.append(pl.BlockSpec((batch, 1), _pinned))
+        operands.append(u_scale.astype(jnp.float32)[u_idx])
+    in_specs.append(pl.BlockSpec((block_i, rank), lambda ii: (ii, 0)))
     operands.append(V)
     if has_vs:
-        in_specs.append(
-            pl.BlockSpec((block_i, 1), lambda ii, u_idx_ref: (ii, 0))
-        )
-        operands.append(v_scale.astype(jnp.float32))
-    in_specs.append(pl.BlockSpec((block_i,), lambda ii, u_idx_ref: (ii,)))
-    operands.append(mask8)
+        in_specs.append(pl.BlockSpec((1, block_i), lambda ii: (0, ii)))
+        operands.append(v_scale.astype(jnp.float32).reshape(1, n_pad))
+    in_specs.append(pl.BlockSpec((1, block_i), lambda ii: (0, ii)))
+    operands.append(mask_row)
 
-    scratch = [pltpu.VMEM((batch, rank), U.dtype)]  # gathered rows
-    if has_us:
-        scratch.append(pltpu.VMEM((batch, 1), jnp.float32))
-    scratch += [
-        pltpu.VMEM((batch, block_i), jnp.float32),  # live score tile
-        pltpu.VMEM((batch, k), jnp.float32),  # running top-k values
-        pltpu.VMEM((batch, k), jnp.int32),  # running global indices
-        pltpu.SemaphoreType.DMA,
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    vals, idx = pl.pallas_call(
+        kernel,
         grid=(n_pad // block_i,),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((batch, k), _pinned),
                    pl.BlockSpec((batch, k), _pinned)],
-        scratch_shapes=scratch,
-    )
-    vals, idx = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((batch, k), jnp.float32),
             jax.ShapeDtypeStruct((batch, k), jnp.int32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((batch, block_i), jnp.float32),  # live score tile
+            pltpu.VMEM((batch, k), jnp.float32),  # running top-k values
+            pltpu.VMEM((batch, k), jnp.int32),  # running global indices
+        ],
         interpret=interpret,
-    )(u_idx.astype(jnp.int32), *operands)
+    )(*operands)
     return vals, idx

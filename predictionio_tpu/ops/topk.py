@@ -32,6 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops.quantize import contraction_precision
+
 NEG_INF = jnp.float32(-1e30)
 
 BACKENDS = ("fused", "reference", "auto")
@@ -177,7 +179,9 @@ def gather_score_topk(
     # the same op order as the fused kernel, so the two backends round
     # identically and the equivalence suite can compare them exactly
     Vf = _dequantize(V, None)
-    scores = Uf[u_idx] @ Vf.T  # (B, rank) @ (rank, n_items_pad)
+    scores = jnp.matmul(  # (B, rank) @ (rank, n_items_pad)
+        Uf[u_idx], Vf.T, precision=contraction_precision(V.dtype)
+    )
     if v_scale is not None:
         scores = scores * v_scale.reshape(1, -1)
     mask = item_mask[None, :] if item_mask is not None else None

@@ -7,15 +7,17 @@ batched contractions (``A = einsum('edk,edl->ekl', ·)``,
 ``docs/perf_roofline.md`` derives as the dense half-step's dominant byte
 cost.  This kernel removes that term instead of hiding its latency:
 
-* the OPPOSITE factor matrix streams into VMEM **once per grid** (it fits:
-  2.4–6.5 MB at bench scale vs ~16 MB/core on v5e) via a block whose
-  index_map is pinned to ``(0, 0)`` — Pallas fetches it on the first grid
-  step and keeps it resident, one sequential HBM read at full bandwidth;
-* the random row gather then runs AGAINST VMEM (per-row
-  ``pltpu.make_async_copy`` — Mosaic has no ``gather`` lowering), where
-  sub-sector access costs nothing;
+* the OPPOSITE factor matrix streams into VMEM **once per grid** via a
+  block whose index_map is pinned to ``(0, 0)`` — Pallas fetches it on the
+  first grid step and keeps it resident, one sequential HBM read at full
+  bandwidth.  It must FIT: VMEM holds it in lane-padded tiles (512 B per
+  rank-10 f32 row, not 40), so dispatch counts the budget that way
+  (:func:`fits_vmem`) and MovieLens-25M's sides do not qualify;
+* the random row gather then runs AGAINST VMEM (a dynamic-row vector copy
+  per rating slot — Mosaic has no ``gather`` lowering), where sub-sector
+  access costs nothing;
 * the rating stream (idx/rat/msk) tiles over the grid as usual — idx rides
-  in SMEM so each row id is readable as a DMA scalar — and the per-bucket
+  in SMEM so each row id is readable as a scalar — and the per-bucket
   ``(n_b, D_b, k)`` contraction stays a batched MXU matmul accumulating
   the ``(n_b, k, k)`` normal-equation tensor in f32
   (``preferred_element_type``).
@@ -28,9 +30,10 @@ reference XLA path performs the identical math (dequantize → gather →
 contract with the same operand order), so the equivalence suite can hold
 the two backends to bit-identical solved factors.
 
-Dispatch mirrors ``ops/topk.py``: ``resolve_backend`` reads
-``PIO_TRAIN_KERNEL`` (``fused`` | ``reference`` | ``auto``), ``auto``
-takes the kernel only on real TPU (never the interpreter on CPU), and
+Dispatch is a static rule (:func:`resolve_backend`): ``PIO_TRAIN_KERNEL``
+(``fused`` | ``reference`` | ``auto``); ``auto`` takes the kernel only on
+a real TPU (never the interpreter on CPU) and only for a gathered side
+that fits the VMEM budget; an explicit ``fused`` that cannot fit raises;
 ``PIO_NATIVE=0`` kills it along with every other native kernel.  The
 identical kernel runs anywhere via ``interpret=`` — that is how the CPU
 equivalence tests exercise the real kernel body.
@@ -48,7 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from predictionio_tpu.ops.quantize import FACTOR_BYTES
+from predictionio_tpu.ops import pallas_mode
+from predictionio_tpu.ops.quantize import FACTOR_BYTES, contraction_precision
 
 BACKENDS = ("fused", "reference", "auto")
 
@@ -57,13 +61,23 @@ BACKENDS = ("fused", "reference", "auto")
 # small next to the resident opposite-factor block at every bucket width.
 BLOCK_E = 8
 
-# Index rows gathered per grid step by the segment-solver gather kernel.
-GATHER_BLOCK = 512
+# Widest rating tile one grid step contracts.  The gathered-row scratch is
+# BLOCK_E·block_d rows of one lane-padded tile row each (512 B at f32), so
+# this caps it at 2 MiB however wide a degree bucket is.
+BLOCK_D_MAX = 512
 
-# VMEM the pinned opposite-factor block may occupy before auto dispatch
-# refuses the fused path (v5e ≈ 16 MB/core; leave room for the rating
-# tiles, the gather scratch, and Pallas' own double-buffering).
+# Index rows gathered per grid step by the segment-solver gather kernel.
+GATHER_BLOCK = 1024  # a 1-D int32 SMEM block must match XLA's 1024-element tile
+
+# VMEM the pinned opposite-factor block may occupy before dispatch refuses
+# the fused path: 12 of the 16 MiB Mosaic scopes to a kernel by default on
+# v5e, leaving room for the rating tiles and the gather scratch.
 VMEM_RESIDENT_BUDGET = 12 * 1024 * 1024
+
+# Mosaic lays a VMEM array out in (sublane, 128-lane) tiles; the sublane
+# count depends on the element width.
+_LANES = 128
+_SUBLANES = {"f32": 8, "bf16": 16, "int8": 32}
 
 
 def use_fused_default() -> bool:
@@ -73,13 +87,74 @@ def use_fused_default() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve_backend(requested: Optional[str] = None) -> str:
+def _tile_bytes(rows: int, cols: int, dtype: str) -> int:
+    sub = _SUBLANES[dtype]
+    return (
+        -(-rows // sub) * sub * -(-cols // _LANES) * _LANES
+        * int(FACTOR_BYTES[dtype])
+    )
+
+
+def resident_bytes(n_opp: int, rank: int, compute_dtype: str = "f32") -> int:
+    """VMEM bytes the pinned opposite-factor block occupies, counted the
+    way Mosaic allocates it — in padded tiles, not in elements: a rank-10
+    f32 row is 40 B of data and 512 B of VMEM.  int8 adds the per-row f32
+    scale column, itself one lane-padded tile column."""
+    b = _tile_bytes(n_opp, rank, compute_dtype)
+    if compute_dtype == "int8":
+        b += _tile_bytes(n_opp, 1, "f32")
+    return b
+
+
+def fits_vmem(n_opp: int, rank: int, compute_dtype: str = "f32") -> bool:
+    """Whether the opposite factor matrix fits the VMEM residency budget —
+    the fused kernel's one hard precondition.  Pallas double-buffers every
+    blocked input, the pinned one included, hence the factor 2."""
+    return 2 * resident_bytes(n_opp, rank, compute_dtype) <= VMEM_RESIDENT_BUDGET
+
+
+def refusal(
+    n_opp: Optional[int], rank: Optional[int], compute_dtype: str
+) -> Optional[str]:
+    """Why the fused kernel cannot serve this gathered side here, or None.
+
+    The two facts the static dispatch rule rests on, each a Mosaic limit
+    met on the v5e chip rather than a preference: the pinned block must
+    fit VMEM in padded tiles, and a compiled kernel can take a dynamic
+    single row only from an unpacked (32-bit) array — bf16 and int8 rows
+    pack several to a sublane ("cannot statically prove that index in
+    dimension 0 is a multiple of 8").  The interpreter has no such limit.
+    """
+    if use_fused_default() and compute_dtype != "f32":
+        return (
+            f"compute dtype {compute_dtype} packs rows in a sublane and "
+            "Mosaic cannot gather one of them by a dynamic index"
+        )
+    if n_opp is not None and not fits_vmem(n_opp, rank, compute_dtype):
+        return (
+            f"the gathered side ({n_opp} x {rank}, {compute_dtype}) needs "
+            f"2 x {resident_bytes(n_opp, rank, compute_dtype)} B of VMEM in "
+            f"padded tiles against a budget of {VMEM_RESIDENT_BUDGET} B"
+        )
+    return None
+
+
+def resolve_backend(
+    requested: Optional[str] = None,
+    *,
+    n_opp: Optional[int] = None,
+    rank: Optional[int] = None,
+    compute_dtype: str = "f32",
+) -> str:
     """Resolve the training-kernel backend: ``"fused"`` or ``"reference"``.
 
-    ``requested`` overrides ``PIO_TRAIN_KERNEL``; ``auto`` (the default)
-    takes the fused kernel only on TPU.  ``PIO_NATIVE=0`` forces the
-    reference path — the same kill switch that disables every other
-    native kernel in the repo.
+    A static rule, never a try/except into the reference.  ``requested``
+    overrides ``PIO_TRAIN_KERNEL``.  ``PIO_NATIVE=0`` forces the reference
+    path — the same kill switch that disables every other native kernel
+    in the repo.  ``auto`` takes the fused kernel only on a TPU and only
+    where :func:`refusal` finds nothing against it; an explicit ``fused``
+    that is refused raises with the reason.  Without the gathered side's
+    shape (``n_opp``, ``rank``) the VMEM half of the rule is not applied.
     """
     req = (
         requested or os.environ.get("PIO_TRAIN_KERNEL") or "auto"
@@ -90,27 +165,17 @@ def resolve_backend(requested: Optional[str] = None) -> str:
         )
     if os.environ.get("PIO_NATIVE", "1") == "0":
         return "reference"
-    if req == "auto":
-        return "fused" if use_fused_default() else "reference"
-    return req
-
-
-def resident_bytes(n_opp: int, rank: int, compute_dtype: str = "f32") -> float:
-    """Bytes the pinned opposite-factor block occupies in VMEM (the one
-    sequential V read): the factor matrix at the compute dtype plus the
-    per-row f32 scale column when int8."""
-    s = FACTOR_BYTES.get(compute_dtype, 4.0)
-    b = float(n_opp) * float(rank) * s
-    if compute_dtype == "int8":
-        b += float(n_opp) * 4.0
-    return b
-
-
-def fits_vmem(n_opp: int, rank: int, compute_dtype: str = "f32") -> bool:
-    """Whether the opposite factor matrix fits the VMEM residency budget —
-    the fused kernel's one hard precondition.  ``auto`` dispatch in
-    ``models/als.py`` falls back to the reference path when this fails."""
-    return resident_bytes(n_opp, rank, compute_dtype) <= VMEM_RESIDENT_BUDGET
+    if req == "reference":
+        return req
+    why_not = refusal(n_opp, rank, compute_dtype)
+    if req == "fused":
+        if why_not:
+            raise ValueError(
+                f"train kernel 'fused' was requested but cannot be used: "
+                f"{why_not}; use 'auto' or 'reference'"
+            )
+        return req
+    return "fused" if use_fused_default() and not why_not else "reference"
 
 
 # -- live stats for the /metrics bridge ---------------------------------------
@@ -147,8 +212,8 @@ def _train_contract_kernel(
     block_e: int, block_d: int, k: int,
     implicit: bool, alpha: float, has_scale: bool,
 ):
-    """One grid step: DMA-gather (block_e·block_d) rows from the resident
-    V block, contract them against the rating tile, accumulate the
+    """One grid step: gather (block_e·block_d) rows from the resident V
+    block, contract them against the rating tile, accumulate the
     normal-equation outputs (resident across the d sweep)."""
     it = iter(refs)
     v_ref = next(it)
@@ -158,7 +223,6 @@ def _train_contract_kernel(
     cnt_out = next(it)
     vg_ref = next(it)
     vsg_ref = next(it) if has_scale else None
-    sem = next(it)
 
     di = pl.program_id(1)
 
@@ -168,25 +232,18 @@ def _train_contract_kernel(
         b_out[...] = jnp.zeros_like(b_out)
         cnt_out[...] = jnp.zeros_like(cnt_out)
 
-    # row gather AGAINST the VMEM-resident V block: one DMA per rating
-    # slot (idx lives in SMEM so each row id reads as a scalar); padding
-    # slots carry idx 0 — a always-valid row whose contribution the zero
-    # mask erases below
+    # row gather AGAINST the VMEM-resident V block: one dynamic-row copy
+    # per rating slot (idx lives in SMEM so each row id reads as a scalar).
+    # A vector copy, not a DMA: Mosaic cannot slice a rank-wide row out of
+    # a 128-lane tile for a DMA.  Padding slots carry idx 0 — an
+    # always-valid row whose contribution the zero mask erases below
     def gather(j, carry):
         e = j // block_d
         d = j - e * block_d
         row = idx_ref[e, d]
-        cp = pltpu.make_async_copy(
-            v_ref.at[pl.ds(row, 1), :], vg_ref.at[pl.ds(j, 1), :], sem
-        )
-        cp.start()
-        cp.wait()
+        vg_ref[pl.ds(j, 1), :] = v_ref[pl.ds(row, 1), :]
         if has_scale:
-            cps = pltpu.make_async_copy(
-                vs_ref.at[pl.ds(row, 1), :], vsg_ref.at[pl.ds(j, 1), :], sem
-            )
-            cps.start()
-            cps.wait()
+            vsg_ref[pl.ds(j, 1), :] = vs_ref[pl.ds(row, 1), :]
         return carry
 
     jax.lax.fori_loop(0, block_e * block_d, gather, 0)
@@ -204,27 +261,26 @@ def _train_contract_kernel(
     msk = msk_ref[...]
     w = msk.astype(cd)
     f32 = jnp.float32
-    # dimension_numbers spell out einsum('edk,edl->ekl') / ('edk,ed->ek'):
-    # contract d (dim 1), batch e (dim 0) — the MXU shape, f32 accumulation
+    # dimension_numbers spell out einsum('edk,edl->ekl'): contract d (dim
+    # 1), batch e (dim 0) — the MXU shape, f32 accumulation.  The b
+    # contraction einsum('edk,ed->ek') takes its right side as (e, d, 1):
+    # Mosaic's matmul wants a matrix there, not a batched vector
     contract = (((1,), (1,)), ((0,), (0,)))
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=contract,
+        preferred_element_type=f32, precision=contraction_precision(cd),
+    )
     if implicit:
         # A_u += Σ α·r · v vᵀ ;  b_u += Σ (1+α·r) · v   (p=1, c=1+αr)
         cw = (alpha * rat).astype(cd) * w
-        a_out[...] += jax.lax.dot_general(
-            vg * cw[:, :, None], vg, contract, preferred_element_type=f32
-        )
-        b_out[...] += jax.lax.dot_general(
-            vg, (1.0 + alpha * rat).astype(cd) * w, contract,
-            preferred_element_type=f32,
-        )
+        a_out[...] += dot(vg * cw[:, :, None], vg)
+        b_out[...] += dot(
+            vg, ((1.0 + alpha * rat).astype(cd) * w)[:, :, None]
+        )[:, :, 0]
     else:
         W = vg * w[:, :, None]
-        a_out[...] += jax.lax.dot_general(
-            W, W, contract, preferred_element_type=f32
-        )
-        b_out[...] += jax.lax.dot_general(
-            W, rat.astype(cd), contract, preferred_element_type=f32
-        )
+        a_out[...] += dot(W, W)
+        b_out[...] += dot(W, rat.astype(cd)[:, :, None])[:, :, 0]
         cnt_out[...] += jnp.sum(msk, axis=1, keepdims=True)
 
 
@@ -249,16 +305,16 @@ def fused_train_normal_eq(
     ``v_scale`` from :mod:`ops.quantize`); it streams into VMEM once and
     stays resident for the whole grid.  ``interpret`` defaults to True
     off-TPU so the equivalence tests run the identical kernel anywhere.
-    ``block_d`` defaults to the full bucket width — one d step, so f32
-    accumulation order matches the reference einsum exactly; overriding it
-    trades that bit-equality for a smaller rating tile.
+    ``block_d`` defaults to the bucket width up to :data:`BLOCK_D_MAX` —
+    one d step for every narrower bucket, so f32 accumulation order matches
+    the reference einsum exactly; wider buckets sweep d in ``BLOCK_D_MAX``
+    steps, trading that bit-equality for a bounded gather scratch.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.resolve("train_contract", interpret)
     n_b, D = idx.shape
     n_opp, k = V.shape
     be = min(block_e or BLOCK_E, max(1, n_b))
-    bd = min(block_d or D, D)
+    bd = min(block_d or BLOCK_D_MAX, D)
     e_pad = -(-n_b // be) * be
     d_pad = -(-D // bd) * bd
     if e_pad - n_b or d_pad - D:
@@ -295,7 +351,6 @@ def fused_train_normal_eq(
     scratch = [pltpu.VMEM((be * bd, k), V.dtype)]  # gathered rows
     if has_scale:
         scratch.append(pltpu.VMEM((be * bd, 1), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA)
 
     A, b, cnt = pl.pallas_call(
         kernel,
@@ -324,29 +379,20 @@ def fused_train_normal_eq(
 def _gather_rows_kernel(
     idx_ref, *refs, block_n: int, k: int, has_scale: bool
 ):
-    """One grid step: DMA-gather ``block_n`` rows from the resident V
-    block and emit them dequantized to f32."""
+    """One grid step: gather ``block_n`` rows from the resident V block
+    and emit them dequantized to f32."""
     it = iter(refs)
     v_ref = next(it)
     vs_ref = next(it) if has_scale else None
     out_ref = next(it)
     vg_ref = next(it)
     vsg_ref = next(it) if has_scale else None
-    sem = next(it)
 
     def gather(j, carry):
         row = idx_ref[j]
-        cp = pltpu.make_async_copy(
-            v_ref.at[pl.ds(row, 1), :], vg_ref.at[pl.ds(j, 1), :], sem
-        )
-        cp.start()
-        cp.wait()
+        vg_ref[pl.ds(j, 1), :] = v_ref[pl.ds(row, 1), :]
         if has_scale:
-            cps = pltpu.make_async_copy(
-                vs_ref.at[pl.ds(row, 1), :], vsg_ref.at[pl.ds(j, 1), :], sem
-            )
-            cps.start()
-            cps.wait()
+            vsg_ref[pl.ds(j, 1), :] = vs_ref[pl.ds(row, 1), :]
         return carry
 
     jax.lax.fori_loop(0, block_n, gather, 0)
@@ -371,8 +417,7 @@ def fused_gather_rows(
     amplification; everything downstream (``segment_sum`` accumulation)
     is unchanged.  Returns ``(len(idx), rank) float32``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.resolve("train_gather_rows", interpret)
     (n,) = idx.shape
     n_opp, k = V.shape
     bn = min(block_n or GATHER_BLOCK, max(8, n))
@@ -397,7 +442,6 @@ def fused_gather_rows(
     scratch = [pltpu.VMEM((bn, k), V.dtype)]
     if has_scale:
         scratch.append(pltpu.VMEM((bn, 1), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA)
 
     out = pl.pallas_call(
         kernel,

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import os
 from typing import Any, Mapping, Optional, Sequence
 
 import jax
@@ -39,58 +40,42 @@ MODEL_AXIS = "model"
 # traffic on a multi-process pod (see MeshContext.pod_submesh).
 HOST_AXIS = "host"
 
-# -- jax version compatibility ----------------------------------------------
-# shard_map graduated from jax.experimental to the jax namespace (and grew
-# a replication checker fed by jax.lax.pcast) around 0.5.  On older jax the
-# experimental entry point is API-compatible once check_rep is off — which
-# also makes pcast's varying-marking unnecessary, so pcast_varying below is
-# a no-op there.  ONE shim here; every shard_map/pcast user imports it.
-try:
-    from jax import shard_map as _shard_map_new
-
-    shard_map = _shard_map_new
-
-    def pcast_varying(x, axis_name):
-        """Mark ``x`` varying over ``axis_name`` for the rep checker."""
-        return jax.lax.pcast(x, axis_name, to="varying")
-
-except ImportError:  # pre-0.5 jax
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-        # new-jax callers say check_vma; the experimental API calls it
-        # check_rep (same switch: disable the replication checker)
-        kw.setdefault("check_rep", kw.pop("check_vma", False))
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-    def pcast_varying(x, axis_name):
-        """No rep checker without jax.lax.pcast — nothing to mark."""
-        return x
-
-_platform_pinned = False
+# Every shard_map / pcast user in the package imports these two names from
+# here, so the sharding vocabulary has one home.
+shard_map = jax.shard_map
 
 
-def pin_platform_from_env() -> None:
-    """Make ``JAX_PLATFORMS`` from the environment stick, config-level.
+def pcast_varying(x, axis_name):
+    """Mark ``x`` varying over ``axis_name`` for shard_map's vma checker."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
-    Some deployment images register extra PJRT backends at interpreter
-    start and re-append them to ``jax_platforms`` even when the env var
-    names only ``cpu`` — and an unreachable accelerator backend then hangs
-    the first device query indefinitely. Pinning the env value into
-    ``jax.config`` (what tests/conftest.py does) restores the documented
-    env-var semantics. No-op when JAX_PLATFORMS is unset.
+
+# -- persistent compile cache -----------------------------------------------
+# Deploy AOT-compiles every bucket rung, each fleet child does it again, and
+# a chip run starts cold; a persistent cache turns all but the first of
+# those into disk reads.  The directory is part of the cache key, so it must
+# not move: one fixed, git-ignored path inside the checkout — never a
+# temporary name, a pid or a timestamp.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
+
+def configure_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache; returns the directory this
+    code chose, or None when the operator placed it.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and
+    this function names no directory at all.  Unset: the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Idempotent; called by ``cli.main`` and
+    :meth:`MeshContext.create`, i.e. before anything compiles, so fleet
+    children, ``pio train`` and ``chip_smoke.py`` share one cache.
     """
-    global _platform_pinned
-    import os
-
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat and not _platform_pinned:
-        jax.config.update("jax_platforms", plat)
-        # latch only after an actual pin, so setting the env var later
-        # still takes effect on the next call
-        _platform_pinned = True
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def pad_to_multiple(n: int, m: int) -> int:
@@ -169,7 +154,7 @@ class MeshContext:
         axes: Optional[Mapping[str, int]] = None,
         devices: Optional[Sequence[jax.Device]] = None,
     ) -> "MeshContext":
-        pin_platform_from_env()
+        configure_compile_cache()
         conf = dict(conf or {})
         if axes is None and "mesh_axes" in conf:
             axes = {k: int(v) for k, v in conf["mesh_axes"].items()}

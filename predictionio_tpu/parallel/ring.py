@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from predictionio_tpu.ops import pallas_mode
 from predictionio_tpu.parallel.mesh import MeshContext, pcast_varying, shard_map
 
 NEG_INF = -1e30
@@ -340,8 +341,7 @@ def ring_flash_attention(
         raise ValueError(
             f"flash block sizes ({bq}, {bk}) must divide local block length {t_local}"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.resolve("ring_flash_attention", interpret)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d**0.5)
     ndim = q.ndim

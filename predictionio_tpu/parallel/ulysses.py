@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from predictionio_tpu.ops import pallas_mode
 from predictionio_tpu.parallel.mesh import MeshContext, shard_map
 from predictionio_tpu.parallel.ring import full_attention
 
@@ -122,8 +123,11 @@ def ulysses_attention(
         from predictionio_tpu.ops.flash_attention import use_flash_default
 
         use_flash = use_flash_default(t)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    # only the flash path has a kernel to run; dense attention has no mode
+    interpret = (
+        pallas_mode.resolve("ulysses_attention", interpret)
+        if use_flash else False
+    )
     ndim = q.ndim
     spec = P(*([None] * (ndim - 2) + [axis, None]))
     sharding = ctx.sharding(*spec)

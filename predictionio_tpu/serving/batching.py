@@ -1,8 +1,8 @@
 """Request micro-batching: coalesce concurrent queries into one device pass.
 
 TPU serving throughput comes from batching: a single (B, rank)×(rank, items)
-scoring pass costs barely more than B=1, and on remote-tunnel backends each
-device round trip has a fixed latency floor.  The reference has no analogue
+scoring pass costs barely more than B=1, and every device round trip has
+a fixed latency floor.  The reference has no analogue
 (its predict path is per-request JVM work, ``CreateServer.scala:508``).
 
 :class:`MicroBatcher` sits between HTTP handler threads and the engine:
@@ -19,8 +19,8 @@ The accumulation window is ADAPTIVE, not a fixed sleep:
   while a run is in flight, arrivals queue up and dispatch together.
 * A request is only worth delaying by about the cost of one extra device
   pass, so the wait budget is ``min(window_ms, EWMA(batch run time))`` —
-  on a fast local backend the window collapses toward zero, on a
-  remote-tunnel backend (ms-scale round trips) it opens up to the cap.
+  where a pass is fast the window collapses toward zero, where a pass
+  takes milliseconds it opens up to the cap.
 * Within the budget the worker stops as soon as the arrival stream goes
   quiet: it waits for the next item at most ``EWMA(inter-arrival gap) ×
   GAP_MULT`` past the last arrival (burst over ⇒ dispatch now).

@@ -281,7 +281,7 @@ class BucketedScorer:
         # gauges. One scorer == one model generation, so the accountant's
         # window never mixes generations.
         self.devprof = _devprof.DeviceUtilization(
-            platform=jax.default_backend()
+            device_kind=ctx.mesh.devices.flat[0].device_kind
         )
         # per-bucket annotated HBM bytes, kept host-side so the sharded
         # merge-time attribution doesn't re-enter the accountant per call
@@ -887,6 +887,13 @@ class BucketedScorer:
             in_specs = (
                 P(), P(shard_dim, None), P(shard_dim), P(shard_dim), P(),
             )
+        # shard_map's vma checker stays on only where it can follow the
+        # program: a pallas_call is opaque to it (out_shapes carry no vma,
+        # and the interpret-mode kernel trips it inside its own scan), and
+        # it types all_gather's result as still varying, so it cannot see
+        # that the pod merge's gathers over BOTH axes leave every device
+        # with the same (B, k) — the replicated out_specs would be refused
+        check_vma = be != "fused" and not pod
         if pod:
             # the two-tier merge already replicated the final (B, k)
             out_specs = (P(), P())
@@ -894,7 +901,7 @@ class BucketedScorer:
             def fn(*args):
                 return shard_map(
                     local, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs,
+                    out_specs=out_specs, check_vma=check_vma,
                 )(*args)
 
         else:
@@ -905,7 +912,7 @@ class BucketedScorer:
             def fn(*args):
                 lv, lg = shard_map(
                     local, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs,
+                    out_specs=out_specs, check_vma=check_vma,
                 )(*args)
                 # (S, B, lk) → (B, S·lk) candidate rows; the global
                 # reshape is what pulls the leaderboards across the mesh

@@ -325,6 +325,14 @@ class QueryServer:
         persisted last-known-good pointer (then any older COMPLETED
         generation); only a cold start with nothing deployable left fails
         loudly.
+
+        Fast-path warm-up (``batching``) is part of the load, not an
+        afterthought: a generation whose bucket programs cannot compile
+        would answer every query from the host fall-through without one
+        dispatch to the device.  So a warm-up failure at COLD START
+        propagates (``pio deploy`` exits non-zero — there is no earlier
+        generation to protect), and at RELOAD it is a failed reload: the
+        previous, warm generation keeps serving, flagged ``reloadDegraded``.
         """
         if instance_id is None and self._deployed is None:
             pin = os.environ.get("PIO_PIN_INSTANCE", "").strip()
@@ -377,24 +385,29 @@ class QueryServer:
             if fallback is None:
                 raise  # truly nothing deployable
             return fallback.instance_id
-        warm_ok = not self._warm_fastpath
         if self._warm_fastpath:
             # pre-compile the serving fast path at deploy/reload so no live
             # request ever pays trace/compile latency (ISSUE: AOT warmup)
-            warm_ok = True
-            for algo, model in zip(algorithms, models):
-                warm = getattr(algo, "warmup", None)
-                if warm is None:
-                    continue
-                try:
-                    warm(model)
-                except Exception:
-                    warm_ok = False
-                    self.counters.inc("warmup_errors")
-                    self._rl_log.exception(
-                        "warmup", "fastpath warmup failed for %s",
-                        type(algo).__name__,
-                    )
+            try:
+                for algo, model in zip(algorithms, models):
+                    warm = getattr(algo, "warmup", None)
+                    if warm is not None:
+                        warm(model)
+            except Exception:
+                self.counters.inc("warmup_errors")
+                with self._lock:
+                    last_good = self._deployed
+                if last_good is None:
+                    raise  # cold start: nothing to keep serving
+                self.counters.inc("reload_failed")
+                with self._lock:
+                    self._reload_degraded = True
+                self._rl_log.exception(
+                    "warmup", "fastpath warmup failed for instance %s; "
+                    "serving last good instance %s",
+                    instance.id, last_good.instance_id,
+                )
+                return last_good.instance_id
         deployed = _Deployed(
             instance_id=instance.id,
             algorithms=algorithms,
@@ -404,7 +417,8 @@ class QueryServer:
         )
         with self._lock:
             self._deployed = deployed
-            self._fastpath_warm = warm_ok
+            # reached only with every warm-up done (or none configured)
+            self._fastpath_warm = True
         self._note_generation_swap()
         with self._lock:
             self._reload_degraded = False

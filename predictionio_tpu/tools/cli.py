@@ -275,6 +275,9 @@ def cmd_launch(args) -> int:
         ):
             print(line)
         return 0
+    refusal = launcher.local_processes_refusal(args.num_processes)
+    if refusal:
+        return _die(refusal, code=2)
     rc = launcher.launch_local(
         pio_args,
         num_processes=args.num_processes,
@@ -392,7 +395,14 @@ def _deploy_fleet(args) -> int:
     from predictionio_tpu.serving.autoscaler import Autoscaler
     from predictionio_tpu.serving.fleet import FleetSupervisor
     from predictionio_tpu.serving.router import Router
+    from predictionio_tpu.tools import launcher
 
+    # the supervisor restarts a crashed replica forever; replicas that can
+    # never get a chip must be refused here, before the first spawn.  The
+    # router parent itself never initialises a backend.
+    refusal = launcher.local_processes_refusal(args.fleet)
+    if refusal:
+        return _die(refusal, code=2)
     ports = [args.port + 1 + i for i in range(args.fleet)]
     next_ports = itertools.count(args.port + 1 + args.fleet)
 
@@ -1887,13 +1897,12 @@ def main(argv: Optional[list[str]] = None) -> int:
         level=logging.DEBUG if args.verbose else logging.WARNING,
         format="[%(levelname)s] [%(name)s] %(message)s",
     )
-    if os.environ.get("JAX_PLATFORMS"):
-        # honor the operator's platform choice before anything can touch a
-        # device backend — an unreachable accelerator plugin must not hang
-        # CPU-only verbs (see parallel/mesh.pin_platform_from_env)
-        from predictionio_tpu.parallel.mesh import pin_platform_from_env
+    # place the persistent compile cache before any verb can compile; this
+    # imports jax but initialises no backend, so the fleet router parent
+    # still never holds a chip
+    from predictionio_tpu.parallel.mesh import configure_compile_cache
 
-        pin_platform_from_env()
+    configure_compile_cache()
     if os.environ.get("PIO_COORDINATOR"):
         # the multi-host contract requires jax.distributed.initialize()
         # before ANY backend-initializing jax call; engine/template imports

@@ -12,8 +12,9 @@ SPMD contract (``parallel/distributed.py``):
 ``pio launch`` materializes that contract two ways:
 
 * **local mode** (default): spawn all N processes on this machine —
-  exercising real cross-process collectives (the Spark ``local[N]`` role,
-  and exactly how a single multi-chip host runs).
+  exercising real cross-process collectives (the Spark ``local[N]`` role).
+  CPU platform only: an accelerator host runs ONE process that drives
+  every local chip, and :func:`local_processes_refusal` says so up front.
 * **--hosts h0,h1,...**: print the per-host command lines (host 0 is the
   coordinator) for the operator's parallel-ssh tooling; this image has no
   ssh, and the reference similarly delegates placement (to Spark).
@@ -34,6 +35,72 @@ import uuid
 from typing import Optional, Sequence
 
 WORKER_PREFIX = "[p{index}] "
+
+# first backend start-up on an accelerator host takes ~15 s; a probe that
+# is still silent after this long is reported, not waited on
+PROBE_TIMEOUT_S = 120
+
+
+def local_accelerator() -> tuple[str, int]:
+    """``(platform, local device count)`` as JAX reports them on this host.
+
+    Asked of a short-lived CHILD: a process that initialises the backend
+    holds the chip until it exits, and the callers of this function are
+    parents about to spawn the processes that need it.
+    """
+    try:
+        r = subprocess.run(
+            [
+                sys.executable, "-c",
+                "import jax; d = jax.local_devices(); "
+                "print('PROBE', d[0].platform, len(d))",
+            ],
+            capture_output=True, text=True, timeout=PROBE_TIMEOUT_S,
+        )
+    except subprocess.TimeoutExpired:
+        raise RuntimeError(
+            f"accelerator probe did not answer within {PROBE_TIMEOUT_S} s "
+            "(is another process holding the chip?)"
+        ) from None
+    for line in r.stdout.splitlines():
+        if line.startswith("PROBE "):
+            _, platform, n = line.split()
+            return platform, int(n)
+    raise RuntimeError(
+        f"accelerator probe failed (exit {r.returncode}): "
+        f"{r.stderr.strip()[-500:]}"
+    )
+
+
+def local_processes_refusal(
+    num_processes: int, env: Optional[dict] = None
+) -> Optional[str]:
+    """Why ``num_processes`` local JAX processes cannot run here, or None.
+
+    An accelerator chip belongs to one process at a time and every process
+    opens all of its host's chips; nothing assigns chips to processes yet.
+    So on an accelerator host a second local process fails or hangs at
+    backend start-up — and under a crash-restarting supervisor does so
+    forever.  ``pio deploy --fleet`` and ``pio launch`` ask here first and
+    refuse in seconds instead.  ``JAX_PLATFORMS=cpu`` (tests, rehearsals)
+    needs no probe: CPU processes share nothing.
+    """
+    if num_processes <= 1:
+        return None
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    platform, n = local_accelerator()
+    if platform == "cpu":
+        return None
+    return (
+        f"{num_processes} local processes were asked for, but this host's "
+        f"{n} {platform} chip(s) can be opened by one process at a time and "
+        "nothing assigns chips to processes yet: every process after the "
+        "first would fail or hang at backend start-up. Run ONE process (it "
+        "drives every local chip), or set JAX_PLATFORMS=cpu for a CPU "
+        "rehearsal."
+    )
 
 
 def worker_env(

@@ -10,6 +10,7 @@ import pytest
 
 from predictionio_tpu.data.batch import Interactions
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.models import als as als_mod
 from predictionio_tpu.models.als import (
     ALSConfig,
     ALSModel,
@@ -363,6 +364,44 @@ class TestDenseSolver:
         np.testing.assert_allclose(
             m_on.user_factors, m_off.user_factors, rtol=5e-2, atol=5e-3
         )
+
+
+class TestBatchedSpdSolve:
+    """The plain-ops Cholesky solve that replaced cho_factor/cho_solve:
+    XLA:TPU's expansion of those returned wrong factors once the batch was
+    spread over more than one chip (CHANGES.md PR 21), which no CPU mesh
+    reproduces — so what is pinned here is that the replacement is a
+    correct solve whose answer cannot depend on how the batch is split."""
+
+    @pytest.mark.parametrize("k", [1, 4, 10, 32])
+    def test_matches_float64_solve(self, k):
+        import jax
+
+        rng = np.random.default_rng(k)
+        M = rng.standard_normal((257, k, k))
+        A = M @ M.transpose(0, 2, 1) + 0.05 * np.eye(k)
+        b = rng.standard_normal((257, k))
+        x = np.asarray(jax.jit(als_mod._batched_spd_solve)(
+            A.astype(np.float32), b.astype(np.float32)))
+        ref = np.linalg.solve(A, b[..., None])[..., 0]
+        cond = np.linalg.cond(A).max()
+        assert x.dtype == np.float32 and x.shape == (257, k)
+        assert np.abs(x - ref).max() <= 64 * 2.0**-24 * cond * np.abs(ref).max()
+
+    def test_independent_of_batch_split(self):
+        import jax
+
+        rng = np.random.default_rng(0)
+        M = rng.standard_normal((96, 10, 10)).astype(np.float32)
+        A = M @ M.transpose(0, 2, 1) + np.eye(10, dtype=np.float32)
+        b = rng.standard_normal((96, 10)).astype(np.float32)
+        solve = jax.jit(als_mod._batched_spd_solve)
+        whole = np.asarray(solve(A, b))
+        parts = np.concatenate(
+            [np.asarray(solve(A[s:s + 24], b[s:s + 24]))
+             for s in range(0, 96, 24)]
+        )
+        np.testing.assert_array_equal(whole, parts)
 
 
 class TestImplicitALS:

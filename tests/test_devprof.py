@@ -18,17 +18,20 @@ from predictionio_tpu.obs import tracing as obs_tracing
 from predictionio_tpu.obs.tracing import Trace, Tracer
 from predictionio_tpu.serving.batching import MicroBatcher
 
+V5E = "TPU v5 lite"  # the one device_kind in devprof.PEAKS
+
 
 # -- cost models --------------------------------------------------------------
 
 
 class TestCostModels:
-    def test_peak_for_known_platforms(self):
-        assert devprof.peak_for("tpu")["flops"] == 197e12
-        assert devprof.peak_for("cpu")["hbm_gbps"] == 100e9
-        assert devprof.peak_for("TPU") is devprof.peak_for("tpu")  # case
-        assert devprof.peak_for("rocm") is None
-        assert devprof.peak_for(None) is None
+    def test_peaks_keyed_by_device_kind(self):
+        assert devprof.peak_for("TPU v5 lite")["flops"] == 197e12
+        assert devprof.peak_for("TPU v5 lite")["hbm_gbps"] == 819e9
+        # a platform name is not a device: no TPU borrows v5e's peaks,
+        # and the CPU has no row at all
+        for kind in ("tpu", "TPU v4", "TPU v6 lite", "cpu", "rocm", None):
+            assert devprof.peak_for(kind) is None
 
     def test_als_train_cost_matches_published_formula(self):
         k, nr, nu, ni = 8, 1000, 50, 40
@@ -99,7 +102,8 @@ class TestCostModels:
 
     def test_train_utilization_shape_matches_bench_contract(self):
         out = devprof.train_utilization(
-            1000, 50, 40, 8, 2, "f32", dt=2.0, n_chips=1, platform="cpu"
+            1000, 50, 40, 8, 2, "f32", dt=2.0, n_chips=1,
+            device_kind=V5E,
         )
         assert set(out) == {
             "model_flops_per_sec_per_chip", "model_hbm_gbps_per_chip",
@@ -107,9 +111,9 @@ class TestCostModels:
         }
         assert out["mfu"] is not None and out["hbm_util"] is not None
 
-    def test_train_utilization_null_on_unknown_platform(self):
+    def test_train_utilization_null_off_the_table(self):
         out = devprof.train_utilization(
-            1000, 50, 40, 8, 2, "f32", dt=2.0, n_chips=1, platform="rocm"
+            1000, 50, 40, 8, 2, "f32", dt=2.0, n_chips=1, device_kind="cpu"
         )
         assert out["mfu"] is None and out["hbm_util"] is None
 
@@ -119,29 +123,29 @@ class TestCostModels:
 
 class TestDeviceUtilization:
     def test_snapshot_none_before_first_dispatch(self):
-        acc = devprof.DeviceUtilization(platform="cpu")
+        acc = devprof.DeviceUtilization(device_kind="cpu")
         acc.set_cost("b8", 1e6, 2e6)
         assert acc.snapshot() is None
 
     def test_snapshot_rates_and_utilization(self):
-        acc = devprof.DeviceUtilization(platform="cpu", window_s=60)
+        acc = devprof.DeviceUtilization(device_kind=V5E, window_s=60)
         acc.set_cost("b8", 1e6, 2e6, source="analytic")
         acc.record("b8", 0.002)
         acc.record("b8", 0.003)
         snap = acc.snapshot()
-        assert snap["platform"] == "cpu"
+        assert snap["device_kind"] == V5E
         assert snap["dispatches_window"] == 2
         assert snap["dispatches_total"] == 2
         assert snap["busy_s"] == pytest.approx(0.005)
         assert 0.0 < snap["busy_fraction"] <= 1.0
         assert snap["flops_per_s"] > 0 and snap["hbm_gbps"] > 0
-        # cpu has a peak entry, so utilization is a real number, not null
+        # v5e is in the table, so utilization is a real number, not null
         assert snap["mfu"] is not None and snap["mfu"] > 0
         assert snap["hbm_util"] is not None and snap["hbm_util"] > 0
         assert acc.costs()["b8"]["source"] == "analytic"
 
-    def test_unknown_platform_reports_null_utilization(self):
-        acc = devprof.DeviceUtilization(platform="rocm", window_s=60)
+    def test_unlisted_device_reports_null_utilization(self):
+        acc = devprof.DeviceUtilization(device_kind="cpu", window_s=60)
         acc.set_cost("b", 1e6, 1e6)
         acc.record("b", 0.001)
         snap = acc.snapshot()
@@ -149,7 +153,7 @@ class TestDeviceUtilization:
         assert snap["flops_per_s"] > 0  # rates still real
 
     def test_uncosted_dispatch_counts_but_adds_no_flops(self):
-        acc = devprof.DeviceUtilization(platform="cpu", window_s=60)
+        acc = devprof.DeviceUtilization(device_kind="cpu", window_s=60)
         acc.record("never_annotated", 0.001)
         snap = acc.snapshot()
         assert snap["dispatches_total"] == 1
@@ -157,7 +161,7 @@ class TestDeviceUtilization:
         assert snap["busy_s"] == pytest.approx(0.001)
 
     def test_window_ages_records_out(self):
-        acc = devprof.DeviceUtilization(platform="cpu", window_s=60)
+        acc = devprof.DeviceUtilization(device_kind="cpu", window_s=60)
         acc.set_cost("b", 1e6, 1e6)
         acc.record("b", 0.001)
         acc.record("b", 0.001)
@@ -170,12 +174,12 @@ class TestDeviceUtilization:
         assert snap["dispatches_total"] == 2
 
     def test_negative_wall_clamped(self):
-        acc = devprof.DeviceUtilization(platform="cpu", window_s=60)
+        acc = devprof.DeviceUtilization(device_kind="cpu", window_s=60)
         acc.record("b", -1.0)
         assert acc.snapshot()["busy_s"] == 0.0
 
     def test_busy_fraction_clamped_at_one(self):
-        acc = devprof.DeviceUtilization(platform="cpu", window_s=60)
+        acc = devprof.DeviceUtilization(device_kind="cpu", window_s=60)
         acc.record("b", 100.0)  # more busy than elapsed: clamp, not >1
         assert acc.snapshot()["busy_fraction"] == 1.0
 
@@ -190,18 +194,18 @@ class TestTrainRecorder:
         monkeypatch.setattr(devprof, "_train_acc", None)
 
     def test_process_global_reuse(self):
-        a = devprof.train_recorder(platform="cpu")
+        a = devprof.train_recorder(device_kind="cpu")
         assert devprof.train_recorder() is a
-        assert devprof.train_recorder(platform="cpu") is a
+        assert devprof.train_recorder(device_kind="cpu") is a
 
-    def test_platform_change_recreates(self):
-        a = devprof.train_recorder(platform="cpu")
-        b = devprof.train_recorder(platform="tpu")
-        assert b is not a and b.platform == "tpu"
+    def test_device_change_recreates(self):
+        a = devprof.train_recorder(device_kind="cpu")
+        b = devprof.train_recorder(device_kind=V5E)
+        assert b is not a and b.device_kind == V5E
 
     def test_train_snapshot(self):
         assert devprof.train_snapshot() is None
-        acc = devprof.train_recorder(platform="cpu")
+        acc = devprof.train_recorder(device_kind="cpu")
         acc.set_cost("step", 1e6, 1e6)
         acc.record("step", 0.001)
         assert devprof.train_snapshot()["dispatches_total"] == 1

@@ -448,10 +448,11 @@ class TestDeviceProfilerAndFlightRecorder:
             assert series[("pio_device_hbm_gbps", gen)] > 0
             assert series[("pio_device_dispatches_total", gen)] >= 1
             assert series[("pio_device_busy_seconds", gen)] > 0
-            # the CPU fallback carries a peak-table entry, so mfu/hbm_util
-            # are real numbers even off-TPU — the acceptance bar
-            assert series[("pio_device_mfu", gen)] > 0
-            assert series[("pio_device_hbm_util", gen)] > 0
+            # the peak table is keyed by device_kind and has no CPU row:
+            # a CPU run must not print a utilization under a device
+            # metric's name
+            assert ("pio_device_mfu", gen) not in series
+            assert ("pio_device_hbm_util", gen) not in series
             # fastpath stats carry the same snapshot + the cost sources
             dev = qs._fastpath_stats()["devprof"]
             assert dev["dispatches_total"] >= 1
@@ -709,8 +710,14 @@ class TestLoadtestScrape:
             res = run_loadtest(base, {"user": "u1", "num": 3},
                                requests=8, concurrency=2)
             assert res["errors"] == 0
-            series = scrape_metrics(base)
-            summary = summarize_metrics(series)
+            # the HTTP layer counts a request after its last byte is sent,
+            # so the 8th increment can trail the client's return briefly
+            deadline = time.monotonic() + 5.0
+            while True:
+                summary = summarize_metrics(scrape_metrics(base))
+                if summary["httpRequests"] >= 8 or time.monotonic() > deadline:
+                    break
+                time.sleep(0.05)
             assert summary["seriesCount"] >= 25
             assert summary["httpRequests"] >= 8
             assert summary["batcherQueries"] >= 8

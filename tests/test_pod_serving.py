@@ -6,8 +6,10 @@ Two proofs the pod tentpole rests on:
   ``jax.distributed`` CPU mesh (2 virtual devices per process, Gloo
   collectives) serves a 4-shard / 2-host-group plan through the real
   ``BucketedScorer``; its global top-k must be BIT-identical to the
-  single-process replicated reference computed by the parent, for every
-  bucket rung × factor dtype — and the measured cross-host merge traffic
+  single-process flat sharded merge AND to the single-process replicated
+  reference computed by the parent, for every bucket rung × factor dtype
+  (the second is red on the installed XLA:CPU by 1-3 f32 ulps, ROADMAP
+  C1) — and the measured cross-host merge traffic
   must equal the ``H·B·k·8`` derivation in docs/perf_roofline.md exactly
   (the flat ``S·B·local_k`` collective never crosses hosts).
 * **Shard-aware router fan-out** — replicas advertising a pod host group
@@ -144,10 +146,13 @@ def _run_worker_pair(script_path, timeout=180) -> list[str]:
     return outs
 
 
-def _replicated_reference() -> dict:
-    """Single-process replicated answers for the worker's exact inputs."""
+def _single_process_reference(sharding: str) -> dict:
+    """Single-process answers for the worker's exact inputs: ``replicated``
+    (one full-width scan), or ``sharded`` — the FLAT single-tier merge over
+    the same 4 item blocks the pod workers score."""
     from predictionio_tpu.ops.quantize import quantize_factors
     from predictionio_tpu.parallel.mesh import MeshContext
+    from predictionio_tpu.serving import sharding as _sharding
     from predictionio_tpu.serving.fastpath import BucketedScorer
 
     rng = np.random.default_rng(SEED)
@@ -155,48 +160,89 @@ def _replicated_reference() -> dict:
     V = rng.standard_normal((N_ITEMS, RANK)).astype(np.float32)
     batches = [rng.integers(0, N_USERS, n).astype(np.int32) for n in (1, 13)]
     ctx = MeshContext.create()
+    plan = _sharding.build_plan(N_ITEMS, 4) if sharding == "sharded" else None
     ref = {}
     for dtype in DTYPES:
         Uq, us = quantize_factors(U, dtype)
         Vq, vs = quantize_factors(V, dtype)
         sc = BucketedScorer(
             ctx, Uq, Vq, max_k=K, buckets=(1, 8), factor_dtype=dtype,
-            user_scale=us, item_scale=vs, sharding="replicated",
+            user_scale=us, item_scale=vs, sharding=sharding, plan=plan,
         )
+        assert not sc._pod
         ref[dtype] = [sc.score_topk(users, K) for users in batches]
     return ref
 
 
-def test_pod_mesh_bit_identical_to_replicated_reference(tmp_path):
-    """2-process pod serving == single-process replicated, bit for bit,
-    across bucket rungs × factor dtypes — and the measured cross-host
-    merge moved (H, B, k) entries, not (S, B, local_k)."""
-    script = tmp_path / "pod_worker.py"
+@pytest.fixture(scope="module")
+def pod_results(tmp_path_factory) -> list[dict]:
+    """One 2-process pod run shared by the identity tests: each worker's
+    parsed ``POD_RESULT`` (per dtype: the cells and the tier-2 bytes)."""
+    script = tmp_path_factory.mktemp("pod") / "pod_worker.py"
     script.write_text(POD_WORKER)
-    outs = _run_worker_pair(script)
-    ref = _replicated_reference()
-    for out in outs:
+    results = []
+    for out in _run_worker_pair(script):
         assert "POD_OK" in out, out
         line = next(
             ln for ln in out.splitlines() if ln.startswith("POD_RESULT ")
         )
-        got = json.loads(line[len("POD_RESULT "):])
+        results.append(json.loads(line[len("POD_RESULT "):]))
+    return results
+
+
+def _assert_pod_equals(pod_results, ref: dict, what: str) -> None:
+    """Every worker's every cell == ``ref``, indices and values EXACTLY."""
+    for got in pod_results:
         for dtype in DTYPES:
-            # tier-2 bytes: S/H × local_k/k smaller than the flat gather
-            flat = 4 * (1 + 8 + 8) * K * 8.0
-            assert got[dtype]["pod_bytes"] * 2 == flat
             for cell, (ref_idx, ref_vals) in zip(
                 got[dtype]["cells"], ref[dtype]
             ):
                 np.testing.assert_array_equal(
                     np.asarray(cell["idx"], np.int32), ref_idx,
-                    err_msg=f"indices diverge for {dtype}",
+                    err_msg=f"indices diverge from {what} for {dtype}",
                 )
                 np.testing.assert_array_equal(
                     np.asarray(cell["vals"], np.float64),
                     np.asarray(ref_vals, np.float64),
-                    err_msg=f"values diverge for {dtype}",
+                    err_msg=f"values diverge from {what} for {dtype}",
                 )
+
+
+def test_pod_mesh_bit_identical_to_flat_merge(pod_results):
+    """2-process two-tier pod merge == single-process FLAT sharded merge,
+    bit for bit, across bucket rungs × factor dtypes: both score the same
+    four item blocks, so the second tier may change nothing — and the
+    measured cross-host merge moved (H, B, k) entries, not
+    (S, B, local_k)."""
+    for got in pod_results:
+        for dtype in DTYPES:
+            # tier-2 bytes: S/H × local_k/k smaller than the flat gather
+            flat = 4 * (1 + 8 + 8) * K * 8.0
+            assert got[dtype]["pod_bytes"] * 2 == flat
+    _assert_pod_equals(
+        pod_results, _single_process_reference("sharded"), "the flat merge"
+    )
+
+
+def test_pod_mesh_bit_identical_to_replicated_reference(pod_results):
+    """2-process pod serving == single-process replicated, bit for bit,
+    across bucket rungs × factor dtypes — the guarantee
+    docs/operations.md gives for the ``PIO_SERVING_SHARDING=replicated``
+    rollback.
+
+    KNOWN RED on the installed XLA:CPU (ROADMAP C1): the winners are
+    identical but the values differ by 1-3 f32 ulps, because the CPU dot
+    rounds the rank contraction differently for the 320-wide matrix than
+    for an 80-wide shard block (the same effect as the four
+    tests/test_ivf.py::TestBitIdentity cases).  The flat sharded path
+    shows the same gap, so it is not the pod merge's doing; the
+    assertion stays exact until the guarantee or the scoring is changed
+    on purpose.
+    """
+    _assert_pod_equals(
+        pod_results, _single_process_reference("replicated"),
+        "the replicated reference",
+    )
 
 
 # -- part 2: shard-aware router + chaos ---------------------------------------

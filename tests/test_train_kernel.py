@@ -202,21 +202,74 @@ class TestBackendResolution:
         with pytest.raises(ValueError):
             ALSConfig(compute_dtype="fp8")
 
-    def test_vmem_budget(self):
-        assert train_kernel.fits_vmem(59_000, 10, "f32")
-        assert not train_kernel.fits_vmem(10_000_000, 10, "f32")
-        # int8 carries the 4 B/row scale column
-        k = train_kernel.resident_bytes(100, 8, "int8")
-        assert k == 100 * 8 * 1.0 + 100 * 4.0
+    def test_vmem_budget_counts_padded_tiles(self):
+        # a rank-10 f32 row is 40 B of data but one 128-lane row of an
+        # (8, 128) tile in VMEM: 512 B
+        assert train_kernel.resident_bytes(8, 10, "f32") == 8 * 128 * 4
+        assert train_kernel.resident_bytes(59_000, 10, "f32") == \
+            59_000 * 512
+        # rows pad to the dtype's sublane count (bf16: 16, int8: 32), and
+        # int8 carries its f32 scale column, itself a lane-padded tile
+        assert train_kernel.resident_bytes(10, 8, "bf16") == 16 * 128 * 2
+        assert train_kernel.resident_bytes(100, 8, "int8") == \
+            128 * 128 * 1 + 104 * 128 * 4
+        # the budget holds the block twice (Pallas double-buffers it)
+        edge = train_kernel.VMEM_RESIDENT_BUDGET // (2 * 512)
+        assert train_kernel.fits_vmem(edge, 10, "f32")
+        assert not train_kernel.fits_vmem(edge + 8, 10, "f32")
+        # MovieLens-25M width fits on neither side
+        assert not train_kernel.fits_vmem(59_000, 10, "f32")
+        assert not train_kernel.fits_vmem(162_000, 10, "f32")
 
-    def test_oversized_side_demoted_to_reference(self, monkeypatch):
+    def test_explicit_fused_that_does_not_fit_raises(self, monkeypatch):
         from predictionio_tpu.models import als as als_mod
 
         monkeypatch.setenv("PIO_TRAIN_KERNEL", "fused")
         cfg = als_mod.ALSConfig(rank=10)
-        assert als_mod._resolve_side_backend(cfg, 59_000) == "fused"
-        assert als_mod._resolve_side_backend(cfg, 10_000_000) == \
+        assert als_mod._resolve_side_backend(cfg, 8_000) == "fused"
+        with pytest.raises(ValueError, match="padded tiles"):
+            als_mod._resolve_side_backend(cfg, 59_000)
+        # the kill switch is still an explicit operator override
+        monkeypatch.setenv("PIO_NATIVE", "0")
+        assert als_mod._resolve_side_backend(cfg, 59_000) == "reference"
+
+    def test_auto_resolves_by_platform_and_budget(self, monkeypatch):
+        monkeypatch.delenv("PIO_TRAIN_KERNEL", raising=False)
+        kw = dict(rank=10, compute_dtype="f32")
+        # off-TPU auto is the reference whatever the size
+        assert train_kernel.resolve_backend("auto", n_opp=8_000, **kw) == \
             "reference"
+        monkeypatch.setattr(train_kernel, "use_fused_default", lambda: True)
+        assert train_kernel.resolve_backend("auto", n_opp=8_000, **kw) == \
+            "fused"
+        assert train_kernel.resolve_backend("auto", n_opp=59_000, **kw) == \
+            "reference"
+        # a compiled kernel cannot gather one packed (bf16/int8) row: on a
+        # TPU auto gives way and an explicit request raises with the reason
+        for dt in ("bf16", "int8"):
+            assert train_kernel.resolve_backend(
+                "auto", n_opp=8_000, rank=10, compute_dtype=dt
+            ) == "reference"
+            with pytest.raises(ValueError, match="packs rows"):
+                train_kernel.resolve_backend(
+                    "fused", n_opp=8_000, rank=10, compute_dtype=dt
+                )
+
+    def test_per_side_decision_lands_in_train_stats(self, monkeypatch):
+        from predictionio_tpu.models import als as als_mod
+
+        cfg = als_mod.ALSConfig(rank=10, train_kernel="auto")
+        train_kernel.reset_stats()
+        try:
+            als_mod._record_train_kernel_stats(
+                cfg, "fused", "reference", 162_000, 8_000
+            )
+            st = train_kernel.stats()
+            assert st["backend_u_solve"] == "fused"
+            assert st["backend_v_solve"] == "reference"
+            assert st["backend"] == "mixed"
+        finally:
+            train_kernel.reset_stats()
 
 
 class TestInt8RoundTrip:
