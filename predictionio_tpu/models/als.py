@@ -801,15 +801,17 @@ def _make_dense_step(mesh, ub: _DenseBlocks, ib: _DenseBlocks, cfg: ALSConfig):
     @partial(jax.jit, donate_argnums=(0, 1))
     def step(U, V, u_bufs, i_bufs):
         zero_gram = jnp.zeros((rank, rank), jnp.float32)
-        if implicit:
-            # (k,k); XLA reduces across shards (psum on ICI)
-            gram_v = jnp.matmul(V.T, V, precision=_F32_PRECISION)
-            U = u_solve(*u_bufs, V, gram_v)
-            gram_u = jnp.matmul(U.T, U, precision=_F32_PRECISION)
-            V = v_solve(*i_bufs, U, gram_u)
-        else:
-            U = u_solve(*u_bufs, V, zero_gram)
-            V = v_solve(*i_bufs, U, zero_gram)
+        # the stable name a device trace finds the two half-steps' ops by
+        with jax.named_scope("pio.als_half_step"):
+            if implicit:
+                # (k,k); XLA reduces across shards (psum on ICI)
+                gram_v = jnp.matmul(V.T, V, precision=_F32_PRECISION)
+                U = u_solve(*u_bufs, V, gram_v)
+                gram_u = jnp.matmul(U.T, U, precision=_F32_PRECISION)
+                V = v_solve(*i_bufs, U, gram_u)
+            else:
+                U = u_solve(*u_bufs, V, zero_gram)
+                V = v_solve(*i_bufs, U, zero_gram)
         return U, V
 
     return step

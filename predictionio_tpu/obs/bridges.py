@@ -99,6 +99,22 @@ def bridge_batcher(
                 "EWMA of batch execution time driving the adaptive window.",
                 [("", (), _num(s.get("ewma_run_ms")))],
             ),
+            _fam(
+                "pio_batcher_carried_rows_total", "counter",
+                "Rows the bucket cut left for a later dispatch.",
+                [("", (), _num(s.get("carried_rows")))],
+            ),
+            _fam(
+                "pio_batcher_run_ms_max", "gauge",
+                "Longest single batch run since start, milliseconds.",
+                [("", (), _num(s.get("run_ms_max")))],
+            ),
+            _fam(
+                "pio_batcher_slow_dispatches_total", "counter",
+                "Batch runs that held the batcher past the slow-dispatch "
+                "threshold (stacks dumped to the log, record kept).",
+                [("", (), _num(s.get("slow_dispatches")))],
+            ),
         ]
         sizes = s.get("batch_sizes")
         if isinstance(sizes, dict) and sizes:

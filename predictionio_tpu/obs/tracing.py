@@ -6,10 +6,14 @@ ring exposed at ``GET /trace/recent.json``.  Stages recorded on the query
 path:
 
 ``decode`` → ``queue_wait`` (MicroBatcher) → ``batch_assembly`` → ``h2d``
-→ ``device_compute`` (via the :func:`utils.profiling.trace` hook) →
-``serialize``; whatever wall time the named stages don't cover lands in
-an explicit ``other`` remainder so the stage sum always reconciles with
-wall time.
+→ ``device_compute`` → ``d2h`` → ``postprocess`` → ``serialize``; whatever
+wall time the named stages don't cover lands in an explicit ``other``
+remainder so the stage sum always reconciles with wall time.
+
+The micro-batcher keeps one :class:`Dispatch` record per batch run whether
+or not a sampled request rides it; :func:`stage` charges the shared stages
+to that record too, and enters ``jax.profiler.TraceAnnotation("pio.<name>")``
+so a profiler session shows the host stages on the device ops' clock.
 
 Propagation contract (documented in docs/observability.md):
 
@@ -36,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 import uuid
@@ -128,6 +133,82 @@ class Trace:
             }
 
 
+class Dispatch:
+    """One batch run of the micro-batcher: a fixed-size record.
+
+    Written only by the thread that holds the batcher for the run (no
+    lock); the stage keys exist from the start, so a reader that
+    serializes a run still in flight never sees the dict change size.
+    ``collect`` and ``resolve`` are set by the batcher, the rest by
+    :func:`stage`; ``postprocess`` also takes whatever part of the run no
+    stage covered, so the stages tile the record's wall.
+    """
+
+    STAGES = (
+        "collect", "batch_assembly", "h2d", "device_compute", "d2h",
+        "postprocess", "resolve",
+    )
+
+    __slots__ = (
+        "seq", "start_unix", "t_run", "thread", "thread_id", "inline",
+        "rows", "rung", "carried", "depth_end", "stages", "dc_start",
+        "dc_end", "wall_s", "error", "slow_after_s",
+    )
+
+    def __init__(self, seq: int, inline: bool, rows: int, carried: int,
+                 t_run: float, collect_s: float, slow_after_s: float):
+        th = threading.current_thread()
+        self.seq = seq
+        self.t_run = t_run  # the run's start; the record's is collect_s earlier
+        self.slow_after_s = slow_after_s  # a run longer than this is slow
+        self.start_unix = time.time() - collect_s
+        self.thread, self.thread_id = th.name, th.ident
+        self.inline, self.rows, self.carried = inline, rows, carried
+        self.rung: Optional[int] = None
+        self.depth_end: Optional[int] = None
+        self.stages = dict.fromkeys(self.STAGES, 0.0)
+        self.stages["collect"] = collect_s
+        # first launch / last completion of the device program: what the
+        # batcher's turnaround counter is measured between
+        self.dc_start: Optional[float] = None
+        self.dc_end: Optional[float] = None
+        self.wall_s: Optional[float] = None
+        self.error: Optional[str] = None
+
+    def add_stage(self, name: str, t0: float, t1: float) -> None:
+        # one writer: the thread that holds the batcher for this run
+        self.stages[name] = self.stages.get(name, 0.0) + (t1 - t0)  # pio: ignore[race-unguarded-rmw]
+        if name == "device_compute":
+            if self.dc_start is None:
+                self.dc_start = t0
+            self.dc_end = t1
+
+    def to_dict(self) -> dict:
+        return {
+            "seq": self.seq,
+            "startUnix": round(self.start_unix, 6),
+            "startMonotonic": round(
+                self.t_run - self.stages["collect"], 6
+            ),
+            "thread": self.thread,
+            "threadId": f"{self.thread_id:#018x}",
+            "inline": self.inline,
+            "rows": self.rows,
+            "rung": self.rung,
+            "carriedRows": self.carried,
+            "depthAtEnd": self.depth_end,
+            "slowAfterMs": round(self.slow_after_s * 1e3, 4),
+            "wallMs": (
+                None if self.wall_s is None
+                else round(self.wall_s * 1e3, 4)
+            ),
+            "stagesMs": {
+                k: round(v * 1e3, 4) for k, v in self.stages.items()
+            },
+            **({"error": self.error} if self.error else {}),
+        }
+
+
 # -- active-trace propagation (thread-local) ---------------------------------
 
 _active = threading.local()
@@ -137,39 +218,71 @@ def active_traces() -> Sequence[Trace]:
     return getattr(_active, "traces", ())
 
 
+def active_dispatch() -> Optional[Dispatch]:
+    return getattr(_active, "dispatch", None)
+
+
 @contextlib.contextmanager
-def scope(traces: Sequence[Optional[Trace]]):
-    """Install traces as this thread's active set for the duration.
+def scope(
+    traces: Sequence[Optional[Trace]], dispatch: Optional[Dispatch] = None
+):
+    """Install traces (and the batch run they ride) as this thread's
+    active set for the duration.
 
     The HTTP thread scopes its single request trace around dispatch; the
-    micro-batcher worker scopes the whole batch's traces around execute.
+    micro-batcher scopes the whole batch's traces and its
+    :class:`Dispatch` record around execute.
     """
-    prev = getattr(_active, "traces", ())
+    prev = getattr(_active, "traces", ()), getattr(_active, "dispatch", None)
     _active.traces = tuple(t for t in traces if t is not None)
+    _active.dispatch = dispatch
     try:
         yield
     finally:
-        _active.traces = prev
+        _active.traces, _active.dispatch = prev
+
+
+_TraceAnnotation = None
+
+
+def annotation(name: str, **kv):
+    """``jax.profiler.TraceAnnotation(name, **kv)``: a host span on the
+    profiler's clock.  Without a profiler session entering one is a flag
+    test; a process that never loaded jax has no session to write to."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return contextlib.nullcontext()
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation(name, **kv)
 
 
 @contextlib.contextmanager
 def stage(name: str):
-    """Charge the enclosed wall time to ``name`` on every active trace.
+    """Charge the enclosed wall time to ``name`` on every active trace and
+    on the active dispatch record, as ``pio.<name>`` on the profiler's
+    clock.
 
-    The no-trace case is two attribute lookups — cheap enough to leave in
-    hot loops permanently.
+    With neither active it is three attribute lookups and allocates
+    nothing — cheap enough to leave in hot loops permanently.
     """
     traces = getattr(_active, "traces", ())
-    if not traces:
+    disp = getattr(_active, "dispatch", None)
+    if not traces and disp is None:
         yield
         return
+    kv = {} if disp is None else {"seq": disp.seq}
     t0 = time.perf_counter()
     try:
-        yield
+        with annotation("pio." + name, **kv):
+            yield
     finally:
-        dt = time.perf_counter() - t0
+        t1 = time.perf_counter()
         for t in traces:
-            t.add_stage(name, dt)
+            t.add_stage(name, t1 - t0)
+        if disp is not None:
+            disp.add_stage(name, t0, t1)
 
 
 def add_stage(name: str, seconds: float) -> None:

@@ -37,6 +37,9 @@ from predictionio_tpu.ops.quantize import contraction_precision
 NEG_INF = jnp.float32(-1e30)
 
 BACKENDS = ("fused", "reference", "auto")
+# jax.named_scope around the serving score program (gather -> score ->
+# top-k): on a TPU the Pallas call's device op is named after it
+SCORE_SCOPE = "pio.score_topk"
 
 
 def resolve_backend(requested: Optional[str] = None) -> str:
@@ -167,22 +170,26 @@ def gather_score_topk(
     ``(values (B, k), indices (B, k))``.
     """
     be = resolve_backend(backend)
-    if be == "fused":
-        from predictionio_tpu.ops import score_kernel
+    # the stable name a device trace finds this program's ops by, whatever
+    # the jitted function around it is called
+    with jax.named_scope(SCORE_SCOPE):
+        if be == "fused":
+            from predictionio_tpu.ops import score_kernel
 
-        return score_kernel.fused_gather_score_topk(
-            U, V, u_idx, k, item_mask,
-            u_scale=u_scale, v_scale=v_scale, interpret=interpret,
+            return score_kernel.fused_gather_score_topk(
+                U, V, u_idx, k, item_mask,
+                u_scale=u_scale, v_scale=v_scale, interpret=interpret,
+            )
+        Uf = _dequantize(U, u_scale)
+        # item scale applies AFTER the matmul (scores scale per item
+        # column) — the same op order as the fused kernel, so the two
+        # backends round identically and the equivalence suite can compare
+        # them exactly
+        Vf = _dequantize(V, None)
+        scores = jnp.matmul(  # (B, rank) @ (rank, n_items_pad)
+            Uf[u_idx], Vf.T, precision=contraction_precision(V.dtype)
         )
-    Uf = _dequantize(U, u_scale)
-    # item scale applies AFTER the matmul (scores scale per item column) —
-    # the same op order as the fused kernel, so the two backends round
-    # identically and the equivalence suite can compare them exactly
-    Vf = _dequantize(V, None)
-    scores = jnp.matmul(  # (B, rank) @ (rank, n_items_pad)
-        Uf[u_idx], Vf.T, precision=contraction_precision(V.dtype)
-    )
-    if v_scale is not None:
-        scores = scores * v_scale.reshape(1, -1)
-    mask = item_mask[None, :] if item_mask is not None else None
-    return top_k_with_mask(scores, k, mask=mask)
+        if v_scale is not None:
+            scores = scores * v_scale.reshape(1, -1)
+        mask = item_mask[None, :] if item_mask is not None else None
+        return top_k_with_mask(scores, k, mask=mask)

@@ -39,15 +39,27 @@ hang).  Under Zipf traffic a hot key therefore costs one device slot per
 batch regardless of popularity.  If the leader's deadline lapses before
 dispatch, a live follower is PROMOTED to leader so the survivors don't
 inherit a 504 they didn't earn.
+
+ONE RECORD PER DISPATCH, always on: every batch run gets a sequence
+number and an :class:`obs.tracing.Dispatch` (who ran it, rows, rung, rows
+the cut carried, the wall of each stage), kept in a bounded ring that
+``GET /trace/dispatches.json`` serves and summed into :meth:`stats`.  A run
+that holds the batcher past ``max(SLOW_FLOOR_S, SLOW_MULT x EWMA(run))``
+has every thread's stack written to the server's log by
+``faulthandler``'s watchdog (a C thread: it fires even if the stalled
+thread never releases the GIL) and its record kept in a second ring.
 """
 
 from __future__ import annotations
 
 import collections
+import faulthandler
 import logging
 import queue
+import sys
 import threading
 import time
+import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -68,9 +80,11 @@ class _Pending:
     result: Any = None
     error: Optional[BaseException] = None
     # obs trace riding this query (captured from the submitting thread's
-    # active scope) + enqueue stamp for the queue_wait stage
+    # active scope) + enqueue stamp for the queue_wait stage + the last
+    # dispatch that had finished by then (its own seq minus this = passes)
     trace: Any = None
     t_enq: float = 0.0
+    done_at_enq: int = 0
     # single-flight: the coalescing key this pending leads (None = not
     # coalescable) and the identical-query followers its result fans out to
     key: Any = None
@@ -83,6 +97,17 @@ class MicroBatcher:
     GAP_MULT = 2.0
     # EWMA smoothing for both the gap and run-time estimators
     ALPHA = 0.2
+    # dispatch records kept for GET /trace/dispatches.json (at 4 dispatches
+    # a second, a minute), and the slow ones ordinary traffic never evicts
+    RING = 256
+    SLOW_RING = 16
+    # a run that holds the batcher past max(floor, mult x EWMA(run)) is
+    # slow: stacks are dumped, it is counted and its record is kept
+    SLOW_FLOOR_S = 2.0
+    SLOW_MULT = 8.0
+    # where the watchdog writes the stacks: None is the server's log
+    # (sys.stderr when the run is armed); it must have a file descriptor
+    SLOW_DUMP_FILE = None
 
     def __init__(
         self,
@@ -123,6 +148,27 @@ class MicroBatcher:
         self._n_expired = 0  # pendings dropped un-executed (deadline lapsed)
         self._size_hist: collections.Counter = collections.Counter()
         self._wait_s_total = 0.0
+        # dispatch records.  Written only by the thread that holds _busy
+        # for the run; the sums below move under _stats_lock with the rest
+        self._seq = 0  # dispatches started == seq of the newest
+        self._done = 0  # seq of the newest dispatch whose run returned
+        self._ring: collections.deque = collections.deque(maxlen=self.RING)
+        self._slow_ring: collections.deque = collections.deque(
+            maxlen=self.SLOW_RING
+        )
+        self._current: Optional[_tracing.Dispatch] = None  # holds _busy now
+        # rows the worker has taken off the queue into the batch it is
+        # forming: waiting like the queued ones, but in no queue
+        self._in_hand: list = []
+        self._prev_dc_end: Optional[float] = None
+        self._prev_left_work = False
+        self._carried_rows = 0
+        self._run_s_sum = 0.0
+        self._run_s_max = 0.0
+        self._run_max_seq = 0
+        self._turnaround_s_sum = 0.0
+        self._turnaround_n = 0
+        self._n_slow = 0
         self._worker = threading.Thread(
             target=self._loop, name="query-microbatcher", daemon=True
         )
@@ -165,7 +211,8 @@ class MicroBatcher:
         active = _tracing.active_traces()
         p = _Pending(
             query, deadline=eff,
-            trace=active[0] if active else None, t_enq=now, key=key,
+            trace=active[0] if active else None, t_enq=now,
+            done_at_enq=self._done, key=key,
         )
         if eff.expired():
             # already over budget at arrival: shed before any queue/device
@@ -247,6 +294,12 @@ class MicroBatcher:
         """Per-batch latency/size/occupancy counters (``GET /`` stats)."""
         with self._stats_lock:
             n_b, n_q = self._n_batches, self._n_queries
+            cur = self._current
+            # a run past its threshold counts while it still holds the
+            # batcher: a stall shows here before (or without) its end
+            stalled = cur is not None and (
+                time.perf_counter() - cur.t_run > cur.slow_after_s
+            )
             return {
                 "batches": n_b,
                 "queries": n_q,
@@ -261,7 +314,46 @@ class MicroBatcher:
                 else None,
                 "ewma_gap_ms": round(self._ewma_gap * 1e3, 4),
                 "ewma_run_ms": round(self._ewma_run * 1e3, 4),
+                # monotone sums over the dispatch records
+                "carried_rows": self._carried_rows,
+                "run_ms_sum": round(self._run_s_sum * 1e3, 4),
+                "run_ms_max": round(self._run_s_max * 1e3, 4),
+                "run_ms_max_seq": self._run_max_seq,
+                "turnaround_ms_sum": round(self._turnaround_s_sum * 1e3, 4),
+                "turnaround_n": self._turnaround_n,
+                "slow_dispatches": self._n_slow + (1 if stalled else 0),
             }
+
+    def dispatches(self, limit: Optional[int] = None) -> dict:
+        """The dispatch rings, newest first (``GET /trace/dispatches.json``).
+
+        ``inFlight`` is the run holding the batcher right now, with the
+        stages it has finished and, read here on the caller's thread, the
+        stack its thread sits in.
+        """
+        recent, slow = list(self._ring), list(self._slow_ring)
+        if limit:
+            recent = recent[-limit:]
+        in_flight = None
+        rec = self._current
+        if rec is not None:
+            in_flight = rec.to_dict()
+            in_flight["heldMs"] = round(
+                (time.perf_counter() - rec.t_run) * 1e3, 4
+            )
+            frame = sys._current_frames().get(rec.thread_id)
+            if frame is not None:
+                in_flight["stack"] = [
+                    line.rstrip() for line in traceback.format_stack(frame)
+                ]
+        return {
+            "ringSize": self.RING,
+            "slowRingSize": self.SLOW_RING,
+            "started": self._seq,
+            "dispatches": [r.to_dict() for r in reversed(recent)],
+            "slow": [r.to_dict() for r in reversed(slow)],
+            "inFlight": in_flight,
+        }
 
     # -- worker -------------------------------------------------------------
     def _next(self, timeout: Optional[float]) -> Optional[_Pending]:
@@ -289,8 +381,11 @@ class MicroBatcher:
             if first is None:
                 continue
             t_first = time.perf_counter()
+            # on the profiler's clock: first row taken -> _busy held
+            collect = _tracing.annotation("pio.collect")
+            collect.__enter__()
             last_arrival = t_first
-            batch = [first]
+            batch = self._in_hand = [first]
             # budget: delaying a request more than one device pass costs
             # more latency than the coalescing saves
             budget = min(self.window_s, self._ewma_run)
@@ -310,6 +405,7 @@ class MicroBatcher:
             # serialize with any inline run, THEN drain: everything that
             # arrived while the previous run was in flight coalesces here
             with self._busy:
+                collect.__exit__(None, None, None)
                 while len(batch) < self.max_batch:
                     nxt = self._next(timeout=None)
                     if nxt is None:
@@ -318,10 +414,12 @@ class MicroBatcher:
                 # cut to a compile-cache bucket boundary; the tail leads
                 # the next batch instead of padding this one
                 size = self._boundary(len(batch))
+                carried = len(batch) - size
                 self._carry.extendleft(reversed(batch[size:]))
                 batch = batch[:size]
                 waited = time.perf_counter() - t_first
-                self._execute(batch, waited)
+                self._in_hand = []
+                self._execute(batch, waited, carried=carried)
 
     def _resolve(
         self,
@@ -385,13 +483,34 @@ class MicroBatcher:
             self._n_expired += 1 + len(dead)
         return promoted
 
-    def _execute(self, batch: list, waited: float, inline: bool = False) -> None:
+    def _arm_watchdog(self, threshold: float) -> bool:
+        """Have every thread's stack written once if this run is still
+        going after ``threshold`` seconds.  faulthandler's watchdog is a C
+        thread, so it fires even when the stalled thread holds the GIL; it
+        is one per process (a second batcher's run re-arms it) and lists
+        the newest 100 threads."""
+        try:
+            faulthandler.dump_traceback_later(
+                threshold, file=self.SLOW_DUMP_FILE or sys.stderr
+            )
+        except (AttributeError, OSError, ValueError):
+            return False  # the log has no file descriptor: count, no dump
+        return True
+
+    def _execute(
+        self, batch: list, waited: float, inline: bool = False,
+        carried: int = 0,
+    ) -> None:
         """Run one batch and deliver results/errors to every waiter.
 
         Expired pendings are dropped HERE, at dispatch: their waiters have
         already raised (or are about to), so executing them would spend a
         device pass on a result nobody will read.
+
+        The caller holds ``_busy``: the dispatch record and the counters
+        derived from it have this thread as their only writer.
         """
+        t_in = time.perf_counter()
         live, expired = [], []
         for p in batch:
             if p.deadline is not None and p.deadline.expired():
@@ -412,25 +531,40 @@ class MicroBatcher:
         if not batch:
             return
         t_run = time.perf_counter()
+        seq = self._seq = self._seq + 1
+        # collect: first row taken -> the run starts (the window, the wait
+        # for _busy, the drain and the cut)
+        threshold = max(self.SLOW_FLOOR_S, self.SLOW_MULT * self._ewma_run)
+        rec = _tracing.Dispatch(
+            seq, inline, len(batch), carried, t_run,
+            collect_s=waited + (t_run - t_in), slow_after_s=threshold,
+        )
         traces = [p.trace for p in batch if p.trace is not None]
         for p in batch:
             if p.trace is not None:
                 # time between enqueue and dispatch: the coalescing window
                 # the request paid for (≈0 on the inline bypass)
                 p.trace.add_stage("queue_wait", t_run - p.t_enq)
-                # flight-recorder context: how this request's batch formed
+                # flight-recorder context: how this request's batch formed,
+                # which dispatch ran it and how many it waited through (1
+                # inline, 2 behind the run in flight, 3 when the cut
+                # carried it)
                 p.trace.annotate(
                     batch=len(batch),
                     dispatch="inline" if inline else "window",
+                    dispatch_seq=seq,
+                    passes=seq - p.done_at_enq,
                     **({"coalesce": "leader"} if p.key is not None else {}),
                 )
+        self._current = rec
+        armed = self._arm_watchdog(threshold)
         results: Optional[list] = None
         run_error: Optional[BaseException] = None
         try:
             # the worker thread runs ONE batch for many requests: install
             # every member's trace so shared stages (assembly, h2d, device
-            # compute) are charged to each of them
-            with _tracing.scope(traces):
+            # compute, d2h) are charged to each of them, and to the record
+            with _tracing.scope(traces, dispatch=rec):
                 results = self._run_batch([p.query for p in batch])
             if len(results) != len(batch):
                 raise RuntimeError(
@@ -439,16 +573,37 @@ class MicroBatcher:
                 )
         except BaseException as e:  # propagate to EVERY waiter
             run_error = e
-        run_dt = time.perf_counter() - t_run
+            rec.error = type(e).__name__
+        t_end = time.perf_counter()
+        if armed:
+            faulthandler.cancel_dump_traceback_later()
+        self._done = seq
+        run_dt = t_end - t_run
+        # postprocess takes what no stage of the run covered (the rest of
+        # batch_predict, serving.serve), so `other` on a request is a true
+        # remainder and a record's stages tile its wall
+        staged = sum(rec.stages.values()) - rec.stages["collect"]
+        rest = max(0.0, run_dt - staged)
+        rec.stages["postprocess"] += rest
+        for t in traces:
+            t.add_stage("postprocess", rest)
         # both the worker thread and the trickle bypass land here; the
         # estimator shares _arr_lock with the gap EWMA
         with self._arr_lock:
             self._ewma_run += self.ALPHA * (run_dt - self._ewma_run)
-        for i, p in enumerate(batch):
-            if run_error is not None:
-                self._resolve(p, error=run_error)
-            else:
-                self._resolve(p, result=results[i])
+        with _tracing.annotation("pio.resolve", seq=seq):
+            for i, p in enumerate(batch):
+                if run_error is not None:
+                    self._resolve(p, error=run_error)
+                else:
+                    self._resolve(p, result=results[i])
+        t_done = time.perf_counter()
+        rec.stages["resolve"] = t_done - t_end
+        rec.wall_s = rec.stages["collect"] + (t_done - t_run)
+        # rows waiting as this run ends: queued, carried, or (behind an
+        # inline run) already in the worker's hands
+        rec.depth_end = self.depth() + len(self._in_hand)
+        slow = run_dt > threshold
         with self._stats_lock:
             self._n_batches += 1
             self._n_queries += len(batch)
@@ -456,3 +611,35 @@ class MicroBatcher:
             self._wait_s_total += waited
             if inline:
                 self._n_inline += 1
+            self._carried_rows += carried
+            self._run_s_sum += run_dt
+            if run_dt > self._run_s_max:
+                self._run_s_max, self._run_max_seq = run_dt, seq
+            # turnaround: the device program of the previous dispatch
+            # returned -> this one's is launched, counted only when the
+            # previous run left rows queued or carried — the time the
+            # device waited for the host with work at hand
+            if (
+                self._prev_left_work
+                and self._prev_dc_end is not None
+                and rec.dc_start is not None
+            ):
+                self._turnaround_s_sum += rec.dc_start - self._prev_dc_end
+                self._turnaround_n += 1
+            # with the count, so that stats() never sees the run both as
+            # in flight past its threshold and as counted
+            self._n_slow += slow
+            self._current = None
+        self._prev_dc_end = rec.dc_end
+        self._prev_left_work = rec.depth_end > 0
+        self._ring.append(rec)
+        if slow:
+            self._slow_ring.append(rec)
+            logger.warning(
+                "dispatch %d held the batcher for %.2f s (slow past %.2f s)"
+                " on thread %s (%#x): stages ms %s%s",
+                seq, run_dt, threshold, rec.thread, rec.thread_id,
+                rec.to_dict()["stagesMs"],
+                "; every thread's stack was written when the threshold "
+                "passed" if armed else "",
+            )

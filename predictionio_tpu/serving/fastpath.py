@@ -88,7 +88,6 @@ from predictionio_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, MeshContext, pad_to_multiple, shard_map,
 )
 from predictionio_tpu.serving import sharding as _sharding
-from predictionio_tpu.utils import profiling as _profiling
 
 logger = logging.getLogger(__name__)
 
@@ -1032,9 +1031,12 @@ class BucketedScorer:
             padded[: len(chunk)] = chunk
             for t in _tracing.active_traces():
                 t.annotate(bucket=b)
+            disp = _tracing.active_dispatch()
+            if disp is not None:
+                disp.rung = b
             with _tracing.stage("h2d"):
                 u_dev = self._put_repl(padded)
-            with _profiling.trace(stage="device_compute"):
+            with _tracing.stage("device_compute"):
                 t0 = time.perf_counter()
                 vals, idx = self._fns[b](*self._static_args, u_dev)
                 # force completion INSIDE the stage so async dispatch
@@ -1045,8 +1047,9 @@ class BucketedScorer:
                 jax.block_until_ready((vals, idx))  # pio: ignore[hotpath-block-sync]
                 wall = time.perf_counter() - t0
                 self.devprof.record(b, wall)
-            idx_h = self._fetch(idx)
-            val_h = self._fetch(vals)
+            with _tracing.stage("d2h"):
+                idx_h = self._fetch(idx)
+                val_h = self._fetch(vals)
             with self._lock:
                 self.hits[b] += 1
                 self.queries += len(chunk)

@@ -1004,12 +1004,17 @@ class QueryServer:
             for algo, model in zip(deployed.algorithms, deployed.models)
         ]
         out = []
-        for i, (_, sq) in enumerate(supplemented):
-            preds = [d[i] for d in per_algo if i in d]
-            # pair the supplemented query with its prediction so the serving
-            # pipeline downstream of the batch (plugins, feedback) sees the
-            # same supplemented query as the unbatched path
-            out.append((sq, deployed.serving.serve(sq, preds)))
+        # the batcher charges to `postprocess` whatever part of the run no
+        # stage covers (the rest of batch_predict); naming this part puts
+        # it on the profiler's clock
+        with _tracing.stage("postprocess"):
+            for i, (_, sq) in enumerate(supplemented):
+                preds = [d[i] for d in per_algo if i in d]
+                # pair the supplemented query with its prediction so the
+                # serving pipeline downstream of the batch (plugins,
+                # feedback) sees the same supplemented query as the
+                # unbatched path
+                out.append((sq, deployed.serving.serve(sq, preds)))
         return out
 
     # -- degraded fallback ---------------------------------------------------
@@ -1700,6 +1705,22 @@ class QueryServer:
 
             threading.Thread(target=_stop, daemon=True).start()
             return json_response(200, {"message": "Shutting down."})
+
+        @svc.route("GET", r"/trace/dispatches\.json")
+        def dispatches_route(req: Request):
+            # one record per batch run, kept by the batcher whether or not
+            # a request was sampled (docs/observability.md)
+            if self._batcher is None:
+                return json_response(
+                    404, {"message": "batching disabled"}
+                )
+            try:
+                limit = int(req.params.get("limit") or 0) or None
+            except (TypeError, ValueError):
+                return json_response(
+                    400, {"message": "limit must be an integer"}
+                )
+            return json_response(200, self._batcher.dispatches(limit))
 
         @svc.route("POST", r"/debug/profile")
         def profile_route(req: Request):

@@ -23,33 +23,13 @@ import numpy as np
 
 
 @contextlib.contextmanager
-def trace(log_dir: Optional[str] = None, stage: Optional[str] = None):
+def trace(log_dir: Optional[str] = None):
     """Capture a device trace if a profile dir is configured; else no-op.
 
-    With ``stage=`` this doubles as the serving pipeline's device-compute
-    hook: the enclosed wall time is charged to that stage on every active
-    obs trace (:mod:`predictionio_tpu.obs.tracing`).  Stage mode does NOT
-    consult ``PIO_PROFILE_DIR`` — it runs once per micro-batch, and
-    start/stopping the jax profiler at that cadence would trash the
-    TensorBoard trace it exists to produce; pass ``log_dir`` explicitly to
-    combine both.
+    The serving pipeline's stages reach such a trace by themselves:
+    :func:`predictionio_tpu.obs.tracing.stage` enters a
+    ``jax.profiler.TraceAnnotation`` per stage.
     """
-    if stage is not None:
-        from predictionio_tpu.obs import tracing as _obs_tracing
-
-        if log_dir:
-            import jax
-
-            jax.profiler.start_trace(log_dir)
-            try:
-                with _obs_tracing.stage(stage):
-                    yield
-            finally:
-                jax.profiler.stop_trace()
-            return
-        with _obs_tracing.stage(stage):
-            yield
-        return
     log_dir = log_dir or os.environ.get("PIO_PROFILE_DIR")
     if not log_dir:
         yield

@@ -1,0 +1,594 @@
+"""One record per dispatch, kept by the batcher itself (ISSUE 25).
+
+The batcher tests drive a fake ``run_batch`` whose runs are gated by
+events, on a clock the test moves by hand, so every wall below is exact.
+"""
+
+import glob
+import inspect
+import json
+import os
+import threading
+import time
+import types
+import urllib.error
+import urllib.request
+import uuid
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.obs import tracing
+from predictionio_tpu.serving import batching
+from predictionio_tpu.serving.batching import MicroBatcher
+
+WAIT_S = 10.0
+
+
+class Clock:
+    """perf_counter under the test's hand."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def __call__(self):
+        return self.t
+
+    def advance(self, seconds):
+        self.t += seconds
+
+
+@pytest.fixture()
+def clock(monkeypatch):
+    clk = Clock()
+    shim = types.SimpleNamespace(perf_counter=clk, time=time.time)
+    monkeypatch.setattr(batching, "time", shim)
+    monkeypatch.setattr(tracing, "time", shim)
+    return clk
+
+
+class Runs:
+    """A ``run_batch`` that charges the stages the fast path would, for as
+    long as the test's clock says, and can be held at a gate."""
+
+    def __init__(self, clock=None, h2d_s=0.0, device_s=0.0, d2h_s=0.0,
+                 post_s=0.0):
+        self.clock = clock
+        self.walls = (("h2d", h2d_s), ("device_compute", device_s),
+                      ("d2h", d2h_s))
+        self.post_s = post_s
+        self.batches = []
+        self.gate = threading.Event()
+        self.gate.set()
+        self.entered = threading.Event()
+
+    def __call__(self, queries):
+        self.batches.append(list(queries))
+        self.entered.set()
+        assert self.gate.wait(WAIT_S)
+        for name, seconds in self.walls:
+            with tracing.stage(name):
+                if self.clock is not None:
+                    self.clock.advance(seconds)
+        if self.clock is not None:
+            self.clock.advance(self.post_s)
+        return [("answer", q) for q in queries]
+
+
+def submit_traced(mb, query, key=None):
+    """Submit on a thread of its own under a sampled trace; returns the
+    thread and the trace (finished when the thread ends)."""
+    tr = tracing.Trace(f"req-{query}")
+
+    def go():
+        with tracing.scope((tr,)):
+            mb.submit(query, key=key)
+        tr.finish(200)
+
+    th = threading.Thread(target=go, daemon=True)
+    th.start()
+    return th, tr
+
+
+def wait_until(cond, what):
+    end = time.monotonic() + WAIT_S
+    while not cond():
+        assert time.monotonic() < end, what
+        time.sleep(0.002)
+
+
+@pytest.fixture()
+def three_dispatches(clock):
+    """An inline request, two that queue behind it, one the cut carries.
+
+    Ladder 1/2/4: A runs inline and is held; B, C, D arrive meanwhile; the
+    worker then dispatches B + C (the rung under three rows) and carries D
+    to a third dispatch.  Every run spends 3 ms in h2d, 250 ms on the
+    device, 1 ms reading back and 2 ms building answers; the host takes
+    5 ms between A's device program returning and the next one's launch.
+    """
+    runs = Runs(clock, h2d_s=0.003, device_s=0.250, d2h_s=0.001,
+                post_s=0.002)
+    mb = MicroBatcher(runs, max_batch=4, window_ms=2.0, buckets=(1, 2, 4))
+    try:
+        runs.gate.clear()
+        th_a, tr_a = submit_traced(mb, "A")
+        assert runs.entered.wait(WAIT_S)
+        others = [submit_traced(mb, q) for q in "BCD"]
+        # B is in the worker's hands, C and D in the queue
+        wait_until(lambda: len(mb._in_hand) + mb.depth() == 3, "B, C, D queued")
+        runs.gate.set()
+        for th, _ in [(th_a, tr_a)] + others:
+            th.join(WAIT_S)
+            assert not th.is_alive()
+        wait_until(lambda: mb.stats()["batches"] == 3, "three dispatches")
+        yield mb, runs, {"A": tr_a, **{q: tr for q, (_, tr) in
+                                      zip("BCD", others)}}
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+# -- what a request waited through -------------------------------------------
+
+
+@pytest.mark.parametrize("query, passes, seq, how", [
+    ("A", 1, 1, "inline"),   # nothing in flight: its own dispatch only
+    ("B", 2, 2, "window"),   # waited for the run in flight, rode the next
+    ("D", 3, 3, "window"),   # the cut carried it one dispatch further
+])
+def test_a_trace_names_its_dispatch_and_the_passes_it_waited(
+        three_dispatches, query, passes, seq, how):
+    _, runs, traces = three_dispatches
+    assert [len(b) for b in runs.batches] == [1, 2, 1]
+    meta = traces[query].to_dict()["meta"]
+    assert meta["passes"] == passes
+    assert meta["dispatch_seq"] == seq
+    assert meta["dispatch"] == how
+
+
+def test_counters_move_as_defined(three_dispatches):
+    mb, _, _ = three_dispatches
+    s = mb.stats()
+    assert (s["batches"], s["queries"], s["inline_batches"]) == (3, 4, 1)
+    # the cut carried D once
+    assert s["carried_rows"] == 1
+    # every run: 3 + 250 + 1 + 2 ms
+    assert s["run_ms_sum"] == pytest.approx(3 * 256.0, abs=1e-6)
+    assert s["run_ms_max"] == pytest.approx(256.0, abs=1e-6)
+    assert s["run_ms_max_seq"] == 1  # the first to reach it keeps it
+    # A ended with rows waiting, so did B + C (D was carried); after D
+    # nothing waited.  Device returned -> next launch: d2h 1 + answers 2 +
+    # h2d 3 ms, twice.
+    assert s["turnaround_n"] == 2
+    assert s["turnaround_ms_sum"] == pytest.approx(2 * 6.0, abs=1e-6)
+    assert s["slow_dispatches"] == 0
+
+
+def test_a_run_with_nothing_waiting_at_its_end_counts_no_turnaround(clock):
+    runs = Runs(clock, device_s=0.25)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        mb.submit("one")
+        clock.advance(5.0)  # the device idles, but no row waits for it
+        mb.submit("two")
+        s = mb.stats()
+        assert s["batches"] == s["inline_batches"] == 2
+        assert s["turnaround_n"] == 0 and s["turnaround_ms_sum"] == 0
+    finally:
+        mb.stop()
+
+
+def test_a_slower_run_takes_the_maximum_and_its_seq(clock):
+    runs = Runs(clock, device_s=0.25)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        mb.submit("one")
+        runs.walls = (("device_compute", 0.40),)
+        mb.submit("two")
+        runs.walls = (("device_compute", 0.10),)
+        mb.submit("three")
+        s = mb.stats()
+        assert s["run_ms_max"] == pytest.approx(400.0, abs=1e-6)
+        assert s["run_ms_max_seq"] == 2
+        assert s["run_ms_sum"] == pytest.approx(750.0, abs=1e-6)
+    finally:
+        mb.stop()
+
+
+# -- the records ---------------------------------------------------------------
+
+
+def test_a_record_says_who_ran_what_and_its_stages_tile_its_wall(
+        three_dispatches):
+    mb, _, _ = three_dispatches
+    doc = mb.dispatches()
+    recs = doc["dispatches"]
+    assert [r["seq"] for r in recs] == [3, 2, 1]  # newest first
+    by_seq = {r["seq"]: r for r in recs}
+    assert by_seq[1]["inline"] and not by_seq[2]["inline"]
+    assert by_seq[2]["thread"] == "query-microbatcher"
+    assert [by_seq[n]["rows"] for n in (1, 2, 3)] == [1, 2, 1]
+    assert [by_seq[n]["carriedRows"] for n in (1, 2, 3)] == [0, 1, 0]
+    # rows waiting as each run ended: B, C, D; then D; then none
+    assert [by_seq[n]["depthAtEnd"] for n in (1, 2, 3)] == [3, 1, 0]
+    for r in recs:
+        assert set(r["stagesMs"]) == set(tracing.Dispatch.STAGES)
+        assert sum(r["stagesMs"].values()) == pytest.approx(
+            r["wallMs"], abs=1e-3)
+        assert r["stagesMs"]["h2d"] == pytest.approx(3.0, abs=1e-6)
+        assert r["stagesMs"]["device_compute"] == pytest.approx(250.0,
+                                                                abs=1e-6)
+        assert r["stagesMs"]["d2h"] == pytest.approx(1.0, abs=1e-6)
+        # what no stage of the run covered
+        assert r["stagesMs"]["postprocess"] == pytest.approx(2.0, abs=1e-6)
+    assert doc["inFlight"] is None and doc["slow"] == []
+
+
+def test_dispatch_stages_are_recorded_without_any_sampled_request(clock):
+    runs = Runs(clock, h2d_s=0.004, device_s=0.2)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        assert not tracing.active_traces()
+        mb.submit("unsampled")
+        (rec,) = mb.dispatches()["dispatches"]
+        assert rec["stagesMs"]["h2d"] == pytest.approx(4.0, abs=1e-6)
+        assert rec["stagesMs"]["device_compute"] == pytest.approx(
+            200.0, abs=1e-6)
+        assert tracing.active_dispatch() is None  # the scope is restored
+    finally:
+        mb.stop()
+
+
+def test_the_ring_is_bounded_and_seq_has_no_holes():
+    mb = MicroBatcher(lambda qs: qs, buckets=(1, 8))
+    try:
+        n = MicroBatcher.RING + 44
+        for i in range(n):
+            mb.submit(i)
+        doc = mb.dispatches()
+        seqs = [r["seq"] for r in doc["dispatches"]]
+        assert seqs == list(range(n, n - MicroBatcher.RING, -1))
+        assert doc["started"] == n and doc["ringSize"] == MicroBatcher.RING
+        assert [r["seq"] for r in mb.dispatches(limit=3)["dispatches"]] == [
+            n, n - 1, n - 2]
+    finally:
+        mb.stop()
+
+
+def test_a_failed_run_is_recorded_with_its_error():
+    def broken(queries):
+        raise ValueError("no scores today")
+
+    mb = MicroBatcher(broken, buckets=(1, 8))
+    try:
+        with pytest.raises(ValueError):
+            mb.submit("q")
+        (rec,) = mb.dispatches()["dispatches"]
+        assert rec["error"] == "ValueError" and rec["seq"] == 1
+        assert mb.stats()["batches"] == 1
+    finally:
+        mb.stop()
+
+
+# -- request traces -------------------------------------------------------------
+
+
+def test_a_request_trace_sums_to_its_wall_with_d2h_and_postprocess(
+        three_dispatches):
+    _, _, traces = three_dispatches
+    for tr in traces.values():
+        d = tr.to_dict()
+        assert {"queue_wait", "h2d", "device_compute", "d2h",
+                "postprocess", "other"} <= set(d["stagesMs"])
+        assert d["stagesMs"]["d2h"] == pytest.approx(1.0, abs=1e-3)
+        assert d["stagesMs"]["postprocess"] == pytest.approx(2.0, abs=1e-3)
+        assert "resolve" not in d["stagesMs"]  # the dispatch's, not a request's
+        assert sum(d["stagesMs"].values()) == pytest.approx(
+            d["wallMs"], abs=1e-2)
+
+
+def test_a_follower_of_a_coalesced_leader_carries_no_device_stages(clock):
+    runs = Runs(clock, h2d_s=0.003, device_s=0.25, d2h_s=0.001)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        runs.gate.clear()
+        th_l, leader = submit_traced(mb, "same", key="k")
+        assert runs.entered.wait(WAIT_S)
+        th_f, follower = submit_traced(mb, "same", key="k")
+        wait_until(lambda: mb.stats()["coalesced"] == 1, "follower attached")
+        runs.gate.set()
+        for th in (th_l, th_f):
+            th.join(WAIT_S)
+            assert not th.is_alive()
+        assert len(runs.batches) == 1  # one device row for both
+        lead, foll = leader.to_dict(), follower.to_dict()
+        assert lead["meta"]["coalesce"] == "leader"
+        assert lead["meta"]["dispatch_seq"] == 1
+        assert lead["stagesMs"]["device_compute"] == pytest.approx(250.0,
+                                                                   abs=1e-3)
+        assert foll["meta"] == {"coalesce": "follower"}
+        assert set(foll["stagesMs"]) == {"other"}
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+# -- a run that holds the batcher -------------------------------------------------
+
+
+@pytest.fixture()
+def short_threshold(monkeypatch, tmp_path):
+    dump = open(tmp_path / "stacks.txt", "w+")
+    monkeypatch.setattr(MicroBatcher, "SLOW_FLOOR_S", 0.15)
+    monkeypatch.setattr(MicroBatcher, "SLOW_DUMP_FILE", dump)
+    yield dump
+    dump.close()
+
+
+def read_back(dump) -> str:
+    dump.flush()
+    dump.seek(0)
+    return dump.read()
+
+
+def test_a_run_past_the_threshold_leaves_one_dump_one_count_one_record(
+        short_threshold, caplog):
+    seen = {}
+
+    def sleeps_past_the_threshold(queries):
+        time.sleep(0.2)  # past the threshold: the dump is written now
+        seen["stats"] = mb.stats()
+        seen["doc"] = mb.dispatches()
+        time.sleep(0.2)
+        return queries
+
+    mb = MicroBatcher(sleeps_past_the_threshold, buckets=(1, 8))
+    try:
+        with caplog.at_level("WARNING", logger=batching.__name__):
+            mb.submit("q")
+        text = read_back(short_threshold)
+        assert text.count("Timeout (") == 1  # written once, not again
+        assert "sleeps_past_the_threshold" in text
+        assert mb.stats()["slow_dispatches"] == 1
+        doc = mb.dispatches()
+        (kept,) = doc["slow"]
+        assert kept["seq"] == 1 and kept["wallMs"] > 150
+        assert kept["threadId"] == f"{threading.get_ident():#018x}"
+        # while the run still held the batcher it already counted, and the
+        # in-flight view named the frame it sat in
+        assert seen["stats"]["slow_dispatches"] == 1
+        inflight = seen["doc"]["inFlight"]
+        assert inflight["seq"] == 1 and inflight["heldMs"] > 150
+        assert any("sleeps_past_the_threshold" in line
+                   for line in inflight["stack"])
+        assert sum("held the batcher" in r.message
+                   for r in caplog.records) == 1
+    finally:
+        mb.stop()
+
+
+def test_an_ordinary_run_leaves_no_dump_no_count_and_no_timer(
+        short_threshold):
+    mb = MicroBatcher(lambda qs: qs, buckets=(1, 8))
+    try:
+        for i in range(5):
+            mb.submit(i)
+        time.sleep(0.3)  # a timer left armed would fire by now
+        assert read_back(short_threshold) == ""
+        assert mb.stats()["slow_dispatches"] == 0
+        assert mb.dispatches()["slow"] == []
+    finally:
+        mb.stop()
+
+
+def test_slow_records_outlive_ordinary_traffic(short_threshold):
+    slow_once = {"left": 1}
+
+    def run(queries):
+        if slow_once["left"]:
+            slow_once["left"] -= 1
+            time.sleep(0.25)
+        return queries
+
+    mb = MicroBatcher(run, buckets=(1, 8))
+    try:
+        for i in range(MicroBatcher.RING + 10):
+            mb.submit(i)
+        doc = mb.dispatches()
+        assert 1 not in [r["seq"] for r in doc["dispatches"]]  # evicted
+        assert [r["seq"] for r in doc["slow"]] == [1]  # kept
+        assert read_back(short_threshold).count("Timeout") == 1
+    finally:
+        mb.stop()
+
+
+def test_a_log_without_a_file_descriptor_still_counts(monkeypatch):
+    import io
+
+    monkeypatch.setattr(MicroBatcher, "SLOW_FLOOR_S", 0.05)
+    monkeypatch.setattr(MicroBatcher, "SLOW_DUMP_FILE", io.StringIO())
+
+    def run(queries):
+        time.sleep(0.1)
+        return queries
+
+    mb = MicroBatcher(run, buckets=(1, 8))
+    try:
+        mb.submit("q")
+        assert mb.stats()["slow_dispatches"] == 1
+    finally:
+        mb.stop()
+
+
+# -- the profiler's clock ------------------------------------------------------------
+
+
+def test_stages_reach_a_profiler_session_as_pio_annotations(tmp_path):
+    import jax
+
+    runs = Runs()  # real clock; the stages are entered and left at once
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            mb.submit("q")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        mb.stop()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"pio.h2d", "pio.device_compute", "pio.d2h",
+            "pio.resolve"} <= names
+
+
+def test_the_score_program_carries_its_scope():
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import topk
+
+    def fn(U, V, idx):
+        return topk.gather_score_topk(U, V, idx, 3, backend="reference")
+
+    text = jax.jit(fn).lower(
+        jnp.zeros((4, 2)), jnp.zeros((8, 2)), jnp.zeros((2,), jnp.int32)
+    ).as_text(debug_info=True)
+    assert "jit(fn)/" + topk.SCORE_SCOPE in text
+    assert "module @jit_fn " in text  # the name the benchmark's readers match
+
+
+def test_the_dense_train_step_carries_its_scope(monkeypatch):
+    from predictionio_tpu.models import als
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.data.batch import Interactions
+    from predictionio_tpu.parallel.mesh import MeshContext
+
+    users, items = np.nonzero(np.random.default_rng(0).random((20, 12)) < 0.5)
+    ratings = Interactions(
+        user=users.astype(np.int32), item=items.astype(np.int32),
+        rating=np.ones(len(users), np.float32), t=np.zeros(len(users)),
+        user_map=BiMap.string_int(f"u{i}" for i in range(20)),
+        item_map=BiMap.string_int(f"i{i}" for i in range(12)))
+    real, seen = als._make_dense_step, {}
+
+    def lowered_once(*a, **kw):
+        step = real(*a, **kw)
+
+        def spy(*args):
+            if not seen:
+                seen["text"] = step.lower(*args).as_text(debug_info=True)
+            return step(*args)
+
+        return spy
+
+    monkeypatch.setattr(als, "_make_dense_step", lowered_once)
+    als.train_als(MeshContext.create(), ratings,
+                  als.ALSConfig(rank=2, iterations=1, solver="dense"))
+    assert "jit(step)/pio.als_half_step/" in seen["text"]
+
+
+def test_profiling_trace_has_no_stage_mode_left():
+    from predictionio_tpu.serving import fastpath
+    from predictionio_tpu.utils import profiling
+
+    assert list(inspect.signature(profiling.trace).parameters) == ["log_dir"]
+    assert not hasattr(fastpath, "_profiling")
+
+
+# -- over HTTP ------------------------------------------------------------------------
+
+
+def _http(url, body=None, headers=None):
+    req = urllib.request.Request(
+        url, data=None if body is None else json.dumps(body).encode(),
+        headers={"Content-Type": "application/json", **(headers or {})})
+    with urllib.request.urlopen(req, timeout=30) as r:
+        return json.loads(r.read().decode())
+
+
+@pytest.fixture()
+def served(storage):
+    from predictionio_tpu.core.workflow import run_train
+    from predictionio_tpu.data import Event
+    from predictionio_tpu.data import store as store_mod
+    from predictionio_tpu.data.storage import App
+    from predictionio_tpu.parallel.mesh import MeshContext
+    from predictionio_tpu.serving.query_server import QueryServer
+    from predictionio_tpu.templates.recommendation import RecommendationEngine
+
+    store_mod.set_storage(storage)
+    app_id = storage.get_meta_data_apps().insert(App(0, "dispapp"))
+    le = storage.get_l_events()
+    le.init(app_id)
+    rng = np.random.default_rng(25)
+    le.batch_insert(
+        [Event(event="rate", entity_type="user", entity_id=f"u{u}",
+               target_entity_type="item", target_entity_id=f"i{i}",
+               properties={"rating": float(rng.integers(1, 6))})
+         for u in range(10) for i in rng.choice(10, size=4, replace=False)],
+        app_id)
+    engine = RecommendationEngine.apply()
+    ep = engine.params_from_variant({
+        "datasource": {"params": {"appName": "dispapp"}},
+        "algorithms": [{"name": "als",
+                        "params": {"rank": 2, "numIterations": 2}}]})
+    ctx = MeshContext.create()
+    run_train(engine, ep, "disp", storage=storage, ctx=ctx)
+    servers = []
+
+    def start(**kw):
+        qs = QueryServer(engine, storage=storage, ctx=ctx, **kw)
+        servers.append(qs)
+        return qs, f"http://127.0.0.1:{qs.start('127.0.0.1', 0)}"
+
+    yield start
+    for qs in servers:
+        qs.stop()
+    store_mod.set_storage(None)
+
+
+def test_trace_dispatches_json_serves_one_record_per_dispatch(served):
+    qs, base = served(batching=True)
+    rids = [uuid.uuid4().hex[:16] for _ in range(6)]
+    for n, rid in enumerate(rids):
+        _http(base + "/queries.json", {"user": f"u{n}", "num": 3},
+              headers={tracing.TRACE_HEADER: rid})
+    doc = _http(base + "/trace/dispatches.json")
+    recs = doc["dispatches"]
+    assert [r["seq"] for r in recs] == list(range(len(recs), 0, -1))
+    assert len(recs) == qs._batcher.stats()["batches"] == 6
+    for r in recs:
+        assert r["rung"] == 1 and r["rows"] == 1 and r["inline"]
+        assert r["stagesMs"]["device_compute"] > 0
+        assert sum(r["stagesMs"].values()) == pytest.approx(r["wallMs"],
+                                                            abs=1e-3)
+    # every traced request names a dispatch that is there, and its own
+    # stages still sum to its wall
+    mine, end = [], time.monotonic() + 5.0
+    while len(mine) < len(rids) and time.monotonic() < end:
+        traces = _http(base + "/trace/recent.json")["traces"]
+        mine = [t for t in traces if t["requestId"] in rids]
+        time.sleep(0.02)
+    assert len(mine) == len(rids)
+    for t in mine:
+        assert t["meta"]["dispatch_seq"] in {r["seq"] for r in recs}
+        assert t["meta"]["passes"] == 1
+        assert {"d2h", "postprocess", "device_compute"} <= set(t["stagesMs"])
+        assert sum(t["stagesMs"].values()) == pytest.approx(t["wallMs"],
+                                                            abs=0.05)
+    assert len(_http(base + "/trace/dispatches.json?limit=2")["dispatches"]) == 2
+    # the counters ride GET / with the rest of the batcher's
+    root = _http(base + "/")["batching"]
+    assert root["run_ms_max_seq"] >= 1 and root["slow_dispatches"] == 0
+
+
+def test_trace_dispatches_json_without_batching_is_404(served):
+    _, base = served()
+    with pytest.raises(urllib.error.HTTPError) as err:
+        _http(base + "/trace/dispatches.json")
+    assert err.value.code == 404
