@@ -151,8 +151,8 @@ class Dispatch:
 
     __slots__ = (
         "seq", "start_unix", "t_run", "thread", "thread_id", "inline",
-        "rows", "rung", "carried", "depth_end", "stages", "dc_start",
-        "dc_end", "wall_s", "error", "slow_after_s",
+        "rows", "rung", "merge_passes", "carried", "depth_end", "stages",
+        "dc_start", "dc_end", "wall_s", "error", "slow_after_s",
     )
 
     def __init__(self, seq: int, inline: bool, rows: int, carried: int,
@@ -165,6 +165,9 @@ class Dispatch:
         self.thread, self.thread_id = th.name, th.ident
         self.inline, self.rows, self.carried = inline, rows, carried
         self.rung: Optional[int] = None
+        # the score kernel's merge passes that inserted, over the run's
+        # device programs (0 where the program does not count them)
+        self.merge_passes = 0
         self.depth_end: Optional[int] = None
         self.stages = dict.fromkeys(self.STAGES, 0.0)
         self.stages["collect"] = collect_s
@@ -195,6 +198,7 @@ class Dispatch:
             "inline": self.inline,
             "rows": self.rows,
             "rung": self.rung,
+            "mergePasses": self.merge_passes,
             "carriedRows": self.carried,
             "depthAtEnd": self.depth_end,
             "slowAfterMs": round(self.slow_after_s * 1e3, 4),

@@ -20,11 +20,29 @@ Mosaic has no ``top_k``/``sort`` lowering, so the merge is built from
 reductions and selects only: per block, candidates that beat the current
 per-row k-th value are extracted one max at a time (smallest global index
 first on ties — ``lax.top_k``'s tie order) and inserted into the sorted
-accumulator by compare/shift.  Extraction iterations that have no
-candidate anywhere in the batch are skipped via ``pl.when``; after the
-first few blocks the per-row thresholds are high and most blocks merge
-nothing, so the expected extraction work is O(k·log(n_items/k)) total,
-not O(k·n_blocks).
+accumulator by compare/shift.  One PASS serves every row of the batch at
+once (each row places at most one candidate), and the loop over passes
+ends at the first one that finds no candidate in any row: tile,
+thresholds and accumulator can no longer change, so that is exact.  Two
+costs follow, and they are different things:
+
+* inserts: a row places ~k·(ln(n_blocks) + 0.58) entries over a sweep of
+  iid scores — O(k·log(n_items/k)), not O(k·n_blocks) — because after the
+  first few blocks the per-row thresholds are high;
+* trips (each a load of the tile, a compare and a vector→scalar reduce,
+  ~0.25 µs on a v5e): a block costs its inserting passes — as many as its
+  busiest row has inserts — plus one ending check, so a block that places
+  nothing costs exactly one.  At 11,133 blocks of 512, k = 100, on the
+  chip: 1,008 / 3,466 / 5,073 / 7,265 / 9,808 passes a dispatch at rungs
+  1 / 8 / 16 / 32 / 64, in 551 … 7,879 of the blocks
+  (``tools/chip_probes/results/score_sweep.*.json``); a fixed ``k`` trips
+  a block, which this loop replaced, were 1,113,300 whatever the rung.
+  The worst input (scores ascending in item order) places min(k, block)
+  entries in every block: the fixed loop's cost, never more.
+
+``with_stats`` returns the two counters (passes that inserted, blocks
+that merged anything) so a deployment can see the exit engage
+(``BucketedScorer.stats()``: ``merge_passes`` / ``merge_blocks``).
 
 Quantized factors (``ops/quantize.py``) dequantize IN the kernel: bf16 /
 int8 blocks upcast in VMEM after the HBM stream, so the bandwidth win is
@@ -80,18 +98,23 @@ def _merge_block(s, gidx, s_ref, vals_ref, idxs_ref, *, k: int, batch: int):
     Threshold-gated max extraction: each pass pulls at most one candidate
     per row (the remaining max, smallest global index on ties) and inserts
     it into the sorted-descending accumulator by compare/shift — no sort,
-    no gather, so every op here has a Mosaic lowering.
+    no gather, so every op here has a Mosaic lowering.  The loop ends at
+    the first pass that finds no candidate in any row: nothing it reads
+    can change after that, so every later pass would be the same no-op.
+    Returns the number of passes that inserted (a traced int32 scalar).
     """
     s_ref[...] = s
     col = jax.lax.broadcasted_iota(jnp.int32, (batch, k), 1)
 
-    def extract(_, carry):
+    def extract(carry):
+        n_inserted, _ = carry
         sv = s_ref[...]
         rv = vals_ref[...]
         thresh = rv[:, k - 1]
         beat = sv > thresh[:, None]
+        found = jnp.any(beat)
 
-        @pl.when(jnp.any(beat))
+        @pl.when(found)
         def _insert():
             m = jnp.max(jnp.where(beat, sv, NEG_INF), axis=1)  # (B,)
             hit = beat & (sv == m[:, None])
@@ -103,8 +126,11 @@ def _merge_block(s, gidx, s_ref, vals_ref, idxs_ref, *, k: int, batch: int):
             # insertion point AFTER equal incumbents: earlier blocks have
             # smaller global indices, and lax.top_k orders ties that way
             pos = jnp.sum((rv >= m[:, None]).astype(jnp.int32), axis=1)
-            sh_v = jnp.concatenate([rv[:, :1], rv[:, :-1]], axis=1)
-            sh_i = jnp.concatenate([ri[:, :1], ri[:, :-1]], axis=1)
+            if k == 1:  # nothing to shift, and Mosaic has no (B, 0) vector
+                sh_v, sh_i = rv, ri
+            else:
+                sh_v = jnp.concatenate([rv[:, :1], rv[:, :-1]], axis=1)
+                sh_i = jnp.concatenate([ri[:, :1], ri[:, :-1]], axis=1)
             nv = jnp.where(
                 col < pos[:, None], rv,
                 jnp.where(col == pos[:, None], m[:, None], sh_v),
@@ -120,14 +146,22 @@ def _merge_block(s, gidx, s_ref, vals_ref, idxs_ref, *, k: int, batch: int):
                 hit & (gidx == gsel[:, None]) & valid[:, None], NEG_INF, sv
             )
 
-        return carry
+        return n_inserted + found.astype(jnp.int32), found
 
-    jax.lax.fori_loop(0, k, extract, 0)
+    # a row places at most min(k, block) entries of one block (they come
+    # out largest first, so the k-th lifts the threshold past the rest):
+    # the bound on the count never cuts an insert off
+    n_inserted, _ = jax.lax.while_loop(
+        lambda carry: (carry[0] < k) & carry[1],
+        extract,
+        (jnp.int32(0), jnp.bool_(True)),
+    )
+    return n_inserted
 
 
 def _score_topk_kernel(
     *refs, k: int, block_i: int, batch: int,
-    has_uscale: bool, has_vscale: bool,
+    has_uscale: bool, has_vscale: bool, with_stats: bool,
 ):
     """One grid step: dot the resident user rows against this item block,
     merge into the running top-k, emit on the last block."""
@@ -139,6 +173,7 @@ def _score_topk_kernel(
     mask_ref = next(it)
     vals_out = next(it)
     idx_out = next(it)
+    stats_out = next(it) if with_stats else None
     s_ref = next(it)
     vals_ref = next(it)
     idxs_ref = next(it)
@@ -150,6 +185,9 @@ def _score_topk_kernel(
     def _init():
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idxs_ref[...] = jnp.full_like(idxs_ref, jnp.int32(_IDX_SENTINEL))
+        if with_stats:
+            stats_out[0] = 0
+            stats_out[1] = 0
 
     # dequantize in VMEM: HBM only ever streamed the narrow bytes
     ug = ug_ref[...].astype(jnp.float32)
@@ -167,7 +205,14 @@ def _score_topk_kernel(
     gidx = ii * block_i + jax.lax.broadcasted_iota(
         jnp.int32, (batch, block_i), 1
     )
-    _merge_block(s, gidx, s_ref, vals_ref, idxs_ref, k=k, batch=batch)
+    n_inserted = _merge_block(
+        s, gidx, s_ref, vals_ref, idxs_ref, k=k, batch=batch
+    )
+    if with_stats:
+        # the whole (2,) output lives in SMEM for the sweep: it is its own
+        # accumulator, written back once when the grid ends
+        stats_out[0] += n_inserted
+        stats_out[1] += (n_inserted > 0).astype(jnp.int32)
 
     @pl.when(ii == n_i - 1)
     def _finalize():
@@ -186,6 +231,7 @@ def fused_gather_score_topk(
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
     block_items: Optional[int] = None,
+    with_stats: bool = False,
 ):
     """Fused top-k scores: ``(values (B, k), indices (B, k))``.
 
@@ -196,7 +242,9 @@ def fused_gather_score_topk(
     anywhere; masked/padded slots can never win (NEG_INF before merge).
     Callers wanting zero-copy dispatch should pre-pad the item dimension
     to :func:`pad_block_items`; ragged inputs are padded (and the tail
-    masked) here.
+    masked) here.  ``with_stats`` appends a third output, int32 ``(2,)``:
+    the merge passes that inserted, summed over the sweep, and the blocks
+    that merged anything (what the merge cost: module docstring).
     """
     interpret = pallas_mode.resolve("score_topk", interpret)
     n_items, rank = V.shape
@@ -227,7 +275,7 @@ def fused_gather_score_topk(
     kernel = functools.partial(
         _score_topk_kernel,
         k=k, block_i=block_i, batch=batch,
-        has_uscale=has_us, has_vscale=has_vs,
+        has_uscale=has_us, has_vscale=has_vs, with_stats=with_stats,
     )
 
     def _pinned(ii):
@@ -251,21 +299,25 @@ def fused_gather_score_topk(
     in_specs.append(pl.BlockSpec((1, block_i), lambda ii: (0, ii)))
     operands.append(mask_row)
 
-    vals, idx = pl.pallas_call(
+    out_specs = [pl.BlockSpec((batch, k), _pinned),
+                 pl.BlockSpec((batch, k), _pinned)]
+    out_shape = [
+        jax.ShapeDtypeStruct((batch, k), jnp.float32),
+        jax.ShapeDtypeStruct((batch, k), jnp.int32),
+    ]
+    if with_stats:
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_shape.append(jax.ShapeDtypeStruct((2,), jnp.int32))
+    return tuple(pl.pallas_call(
         kernel,
         grid=(n_pad // block_i,),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((batch, k), _pinned),
-                   pl.BlockSpec((batch, k), _pinned)],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, k), jnp.float32),
-            jax.ShapeDtypeStruct((batch, k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((batch, block_i), jnp.float32),  # live score tile
             pltpu.VMEM((batch, k), jnp.float32),  # running top-k values
             pltpu.VMEM((batch, k), jnp.int32),  # running global indices
         ],
         interpret=interpret,
-    )(*operands)
-    return vals, idx
+    )(*operands))

@@ -158,6 +158,7 @@ def gather_score_topk(
     v_scale: Optional[jax.Array] = None,
     backend: Optional[str] = None,
     interpret: Optional[bool] = None,
+    with_stats: bool = False,
 ):
     """Fused gather→score→top-k: the serving fast-path device program.
 
@@ -167,9 +168,13 @@ def gather_score_topk(
     slots that must never win (padded item tail, blacklists); it
     broadcasts over the batch.  ``u_scale``/``v_scale`` are the per-row
     int8 scales from :mod:`ops.quantize`.  Returns
-    ``(values (B, k), indices (B, k))``.
+    ``(values (B, k), indices (B, k))``; ``with_stats`` appends the fused
+    kernel's merge counters (int32 ``(2,)``: passes, blocks) and is an
+    error on the reference backend, which has no merge to count.
     """
     be = resolve_backend(backend)
+    if with_stats and be != "fused":
+        raise ValueError("with_stats needs the fused backend")
     # the stable name a device trace finds this program's ops by, whatever
     # the jitted function around it is called
     with jax.named_scope(SCORE_SCOPE):
@@ -179,6 +184,7 @@ def gather_score_topk(
             return score_kernel.fused_gather_score_topk(
                 U, V, u_idx, k, item_mask,
                 u_scale=u_scale, v_scale=v_scale, interpret=interpret,
+                with_stats=with_stats,
             )
         Uf = _dequantize(U, u_scale)
         # item scale applies AFTER the matmul (scores scale per item

@@ -252,6 +252,10 @@ class BucketedScorer:
         self.hits: dict[int, int] = {b: 0 for b in self.buckets}
         self.queries = 0
         self.padded_rows = 0
+        # the fused kernel's merge counters, summed over dispatches: passes
+        # that inserted, blocks that merged anything (ops/score_kernel.py)
+        self.merge_passes = 0
+        self.merge_blocks = 0
         # hot-set working set (off unless PIO_HOTSET_SIZE > 0): decayed
         # per-user request counts drive a periodic re-rank that materializes
         # the hot users' top-k once per refresh instead of once per query
@@ -309,12 +313,13 @@ class BucketedScorer:
 
         return jax.device_put(jnp.asarray(x), self._repl)
 
-    def _fetch(self, x) -> np.ndarray:
-        """Device→host for a REPLICATED result, multi-process safe: any
-        one addressable shard of a replicated array is the whole value."""
+    def _fetch(self, xs: tuple) -> tuple:
+        """Device→host for a program's REPLICATED outputs, multi-process
+        safe: any one addressable shard of a replicated array is the whole
+        value.  One ``device_get`` of the tuple, so the copies overlap."""
         if self._pod_spans:
-            return np.asarray(x.addressable_data(0))
-        return np.asarray(x)
+            xs = tuple(x.addressable_data(0) for x in xs)
+        return tuple(jax.device_get(xs))
 
     def _init_replicated_placement(
         self, user_factors, item_factors, user_scale, item_scale
@@ -690,6 +695,9 @@ class BucketedScorer:
             return self._compile_ivf(b)
         k = self.k
         be = self.backend
+        # the fused kernel also returns its merge counters: a third output
+        # that rides the leaderboard's readback (stats(): merge_passes)
+        ws = be == "fused"
 
         if self.factor_dtype == "int8":
 
@@ -697,13 +705,15 @@ class BucketedScorer:
                 return gather_score_topk(
                     U, V, u_idx, k, item_mask=item_pad_mask,
                     u_scale=u_scale, v_scale=v_scale, backend=be,
+                    with_stats=ws,
                 )
 
         else:
 
             def fn(U, V, item_pad_mask, u_idx):
                 return gather_score_topk(
-                    U, V, u_idx, k, item_mask=item_pad_mask, backend=be
+                    U, V, u_idx, k, item_mask=item_pad_mask, backend=be,
+                    with_stats=ws,
                 )
 
         dummy_idx = self._put_repl(np.zeros(b, np.int32))
@@ -1038,22 +1048,29 @@ class BucketedScorer:
                 u_dev = self._put_repl(padded)
             with _tracing.stage("device_compute"):
                 t0 = time.perf_counter()
-                vals, idx = self._fns[b](*self._static_args, u_dev)
+                # (vals, idx), and the merge counters where the program
+                # was compiled with them (_compile)
+                outs = self._fns[b](*self._static_args, u_dev)
                 # force completion INSIDE the stage so async dispatch
                 # can't smear device time into the d2h readback below —
                 # and so the utilization accountant charges true device
                 # wall, not enqueue time. (The readback two lines down
                 # would block here anyway; this only moves the wait.)
-                jax.block_until_ready((vals, idx))  # pio: ignore[hotpath-block-sync]
+                jax.block_until_ready(outs)  # pio: ignore[hotpath-block-sync]
                 wall = time.perf_counter() - t0
                 self.devprof.record(b, wall)
             with _tracing.stage("d2h"):
-                idx_h = self._fetch(idx)
-                val_h = self._fetch(vals)
+                val_h, idx_h, *merge = self._fetch(outs)
             with self._lock:
                 self.hits[b] += 1
                 self.queries += len(chunk)
                 self.padded_rows += b - len(chunk)
+                if merge:
+                    passes, blocks = map(int, merge[0])
+                    self.merge_passes += passes
+                    self.merge_blocks += blocks
+                    if disp is not None:
+                        disp.merge_passes += passes
                 if self._shard_acct is not None:
                     self._shard_acct.note(
                         idx_h[: len(chunk), :k], b, wall,
@@ -1223,6 +1240,8 @@ class BucketedScorer:
                 "calls": sum(hits.values()),
                 "queries": self.queries,
                 "padded_rows": self.padded_rows,
+                "merge_passes": self.merge_passes,
+                "merge_blocks": self.merge_blocks,
                 "row_occupancy": round(
                     self.queries / (self.queries + self.padded_rows), 4
                 )

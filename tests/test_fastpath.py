@@ -152,6 +152,27 @@ class TestFusedBackend:
         np.testing.assert_array_equal(fi, ri)
         np.testing.assert_allclose(fv, rv, rtol=1e-5, atol=1e-5)
 
+    def test_merge_counters_ride_the_readback(self, ctx, factors, scorer):
+        # the fused program's third output: summed into stats() and onto
+        # the batch run's Dispatch record; the reference program has none
+        from predictionio_tpu.obs import tracing
+
+        U, V = factors
+        fp = BucketedScorer(ctx, U, V, max_k=5, backend="fused")
+        assert fp.stats()["merge_passes"] == fp.stats()["merge_blocks"] == 0
+        disp = tracing.Dispatch(1, False, 8, 0, 0.0, 0.0, 1.0)
+        with tracing.scope((), disp):
+            fp.score_topk(np.arange(8, dtype=np.int32), k=5)
+        st = fp.stats()
+        # 29 items in one block: it merges, and its busiest row places 5
+        assert st["merge_blocks"] == 1 and 5 <= st["merge_passes"] <= 29
+        assert disp.merge_passes == st["merge_passes"]
+        assert disp.to_dict()["mergePasses"] == st["merge_passes"]
+        fp.score_topk(np.arange(70, dtype=np.int32) % 40, k=5)  # 64 + 8
+        assert fp.stats()["merge_blocks"] == 3
+        scorer.score_topk(np.arange(8, dtype=np.int32), k=5)
+        assert scorer.stats()["merge_passes"] == 0
+
     def test_fused_cost_annotation(self, fused):
         kern = fused.stats()["kernel"]
         # fused intensity must beat the reference backend's on the same

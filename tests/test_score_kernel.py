@@ -11,6 +11,12 @@ runs in interpret mode via an explicit ``backend="fused"`` opt-in; the
 Property grid: batch rungs {1, 8, 16, 32, 64} × factor dtypes
 {f32, bf16, int8} × ragged item tails, plus duplicate-score ties,
 exclusion masks, and multi-block grids (items > block_items).
+
+The merge loop ends at the first pass that places nothing (ISSUE 26):
+``TestEarlyExit`` holds the item orders that decide how soon that is, on
+integer-valued factors whose scores are exact in any accumulation order,
+so values as well as indices must equal the reference's bit for bit, and
+pins the kernel's own count of passes to a numpy model of the rule.
 """
 
 import numpy as np
@@ -118,6 +124,180 @@ class TestEquivalence:
         u_idx = np.arange(4, dtype=np.int32)
         fused, ref = _both(U, V, u_idx, 12)
         _assert_ranking_equal(fused, ref, "f32-fullk")
+
+
+def _int_factors(n_users, n_items, rank=4, seed=0, hi=4):
+    """Small-integer factors: every score is an integer well inside
+    f32's (and bf16's, int8's) exact range, so the two backends cannot
+    differ by an accumulation order, and ties are everywhere."""
+    rng = np.random.default_rng(seed)
+    U = rng.integers(-hi, hi + 1, (n_users, rank)).astype(np.float32)
+    V = rng.integers(-hi, hi + 1, (n_items, rank)).astype(np.float32)
+    return U, V
+
+
+def _ranked(scores_by_item, n_users=8):
+    """(U, V) whose score for user b and item i is (b + 1) * scores[i]:
+    every row ranks the items the same way."""
+    V = np.zeros((len(scores_by_item), 4), np.float32)
+    V[:, 0] = scores_by_item
+    U = np.zeros((n_users, 4), np.float32)
+    U[:, 0] = np.arange(1, n_users + 1)
+    return U, V
+
+
+def _merge_model(S, k, block):
+    """The merge's cost by its own rule, from a (B, n_pad) score matrix
+    (excluded slots at NEG_INF): per block, every row inserts its
+    candidates above its k-th value largest first, one a pass, so a block
+    costs as many inserting passes as its busiest row has inserts.
+    Returns (passes, blocks that merged anything)."""
+    b = S.shape[0]
+    tops = [[score_kernel.NEG_INF] * k for _ in range(b)]  # sorted desc
+    passes = blocks = 0
+    for lo in range(0, S.shape[1], block):
+        busiest = 0
+        for r in range(b):
+            inserts = 0
+            for v in sorted(S[r, lo:lo + block], reverse=True):
+                if not v > tops[r][-1]:
+                    break
+                tops[r] = sorted(tops[r] + [v], reverse=True)[:k]
+                inserts += 1
+            busiest = max(busiest, inserts)
+        passes += busiest
+        blocks += busiest > 0
+    return passes, blocks
+
+
+def _fused_with_stats(U, V, u_idx, k, block, dtype="f32", item_mask=None):
+    Uq, us = quantize_factors(U, dtype)
+    Vq, vs = quantize_factors(V, dtype)
+    fused = score_kernel.fused_gather_score_topk(
+        Uq, Vq, u_idx, k, item_mask, u_scale=us, v_scale=vs,
+        block_items=block, with_stats=True,
+    )
+    ref = gather_score_topk(
+        Uq, Vq, u_idx, k, item_mask=item_mask, backend="reference",
+        u_scale=us, v_scale=vs,
+    )
+    return fused, ref
+
+
+def _assert_identical(fused, ref):
+    np.testing.assert_array_equal(np.asarray(fused[1]), np.asarray(ref[1]))
+    np.testing.assert_array_equal(np.asarray(fused[0]), np.asarray(ref[0]))
+
+
+class TestEarlyExit:
+    BLOCK = 16
+    N_BLOCKS = 6
+    N = BLOCK * N_BLOCKS
+
+    @pytest.mark.parametrize("k", (5, 16, 20))
+    def test_ascending_every_block_places(self, k):
+        # the worst input: each block's scores all beat what came before,
+        # so every block places min(k, block) entries in every row — the
+        # fixed loop's cost, never more
+        U, V = _ranked(np.arange(1, self.N + 1))
+        u_idx = np.arange(8, dtype=np.int32)
+        fused, ref = _fused_with_stats(U, V, u_idx, k, self.BLOCK)
+        _assert_identical(fused, ref)
+        assert list(np.asarray(fused[2])) == [
+            self.N_BLOCKS * min(k, self.BLOCK), self.N_BLOCKS]
+
+    @pytest.mark.parametrize("k", (5, 16, 20))
+    def test_descending_only_the_first_blocks_place(self, k):
+        # best first: the leaderboard is full after ceil(k / block) blocks
+        # and every later block costs its one check
+        U, V = _ranked(np.arange(self.N, 0, -1))
+        u_idx = np.arange(8, dtype=np.int32)
+        fused, ref = _fused_with_stats(U, V, u_idx, k, self.BLOCK)
+        _assert_identical(fused, ref)
+        assert list(np.asarray(fused[2])) == [k, -(-k // self.BLOCK)]
+
+    def test_block_wholly_masked_places_nothing(self):
+        # the best items sit in a block that is excluded whole
+        scores = np.arange(1, self.N + 1)
+        U, V = _ranked(scores)
+        mask = np.zeros(self.N, bool)
+        mask[-self.BLOCK:] = True
+        u_idx = np.arange(8, dtype=np.int32)
+        fused, ref = _fused_with_stats(
+            U, V, u_idx, 5, self.BLOCK, item_mask=mask)
+        _assert_identical(fused, ref)
+        assert np.asarray(fused[1]).max() < self.N - self.BLOCK
+        assert list(np.asarray(fused[2])) == [
+            (self.N_BLOCKS - 1) * 5, self.N_BLOCKS - 1]
+
+    def test_everything_masked_is_one_check_a_block(self):
+        # (no winner exists, so there is no ranking to hold the reference
+        # to: an excluded slot's value is all the two agree on)
+        U, V = _ranked(np.arange(1, self.N + 1))
+        fused, ref = _fused_with_stats(
+            U, V, np.arange(8, dtype=np.int32), 5, self.BLOCK,
+            item_mask=np.ones(self.N, bool))
+        np.testing.assert_array_equal(
+            np.asarray(fused[0]), np.asarray(ref[0]))
+        assert list(np.asarray(fused[2])) == [0, 0]
+
+    def test_ties_with_the_kth_value_across_a_block_edge(self):
+        # k = 4 and the 4th value is 3: its equals at the end of block 0
+        # and the start of block 1 must not displace it (strict >), while a
+        # 5 in block 1 enters behind the earlier 5s (ties by index)
+        scores = np.ones(self.N)
+        scores[[0, 1]] = 5
+        scores[[2, 3, self.BLOCK - 1, self.BLOCK]] = 3
+        scores[self.BLOCK + 1] = 5
+        U, V = _ranked(scores)
+        fused, ref = _fused_with_stats(
+            U, V, np.arange(8, dtype=np.int32), 4, self.BLOCK)
+        _assert_identical(fused, ref)
+        assert list(np.asarray(fused[1])[0]) == [0, 1, self.BLOCK + 1, 2]
+        assert list(np.asarray(fused[2])) == [4 + 1, 2]
+
+    @pytest.mark.parametrize("batch", (1, 8, 64))
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_rungs_identical_on_exact_scores(self, batch, dtype):
+        U, V = _int_factors(50, self.N, seed=batch)
+        rng = np.random.default_rng(batch + 7)
+        u_idx = rng.integers(0, 50, batch).astype(np.int32)
+        mask = rng.random(self.N) < 0.2
+        fused, ref = _fused_with_stats(
+            U, V, u_idx, 10, self.BLOCK, dtype=dtype, item_mask=mask)
+        if dtype == "int8":  # per-row scales: the scores are not integers
+            _assert_ranking_equal(fused, ref, dtype)
+        else:
+            _assert_identical(fused, ref)
+
+    @pytest.mark.parametrize("batch,k,seed", [
+        (1, 10, 0), (8, 10, 1), (8, 20, 2), (64, 5, 3)])
+    def test_pass_count_equals_the_rule(self, batch, k, seed):
+        U, V = _int_factors(50, self.N, seed=seed)
+        rng = np.random.default_rng(seed + 11)
+        u_idx = rng.integers(0, 50, batch).astype(np.int32)
+        mask = rng.random(self.N) < 0.1
+        fused, ref = _fused_with_stats(
+            U, V, u_idx, k, self.BLOCK, item_mask=mask)
+        _assert_identical(fused, ref)
+        S = np.where(mask[None, :], score_kernel.NEG_INF, U[u_idx] @ V.T)
+        passes, blocks = _merge_model(S, k, self.BLOCK)
+        assert list(np.asarray(fused[2])) == [passes, blocks]
+        # the loop's trips: the passes and at most one ending check a
+        # block — under the k a block that the fixed loop ran
+        assert 0 < blocks <= self.N_BLOCKS
+        assert passes + self.N_BLOCKS < k * self.N_BLOCKS
+
+    def test_stats_are_off_by_default_and_fused_only(self):
+        U, V = _int_factors(50, self.N)
+        u_idx = np.arange(8, dtype=np.int32)
+        assert len(gather_score_topk(U, V, u_idx, 5, backend="fused")) == 2
+        out = gather_score_topk(
+            U, V, u_idx, 5, backend="fused", with_stats=True)
+        assert np.asarray(out[2]).shape == (2,)
+        with pytest.raises(ValueError, match="with_stats"):
+            gather_score_topk(
+                U, V, u_idx, 5, backend="reference", with_stats=True)
 
 
 class TestBackendResolution:

@@ -109,12 +109,11 @@ def main() -> int:
             t0 = time.perf_counter()
             u_dev = scorer._put_repl(users)
             t1 = time.perf_counter()
-            vals, idx = fn(*static, u_dev)
+            outs = fn(*static, u_dev)
             t2 = time.perf_counter()
-            jax.block_until_ready((vals, idx))
+            jax.block_until_ready(outs)
             t3 = time.perf_counter()
-            scorer._fetch(idx)
-            scorer._fetch(vals)
+            scorer._fetch(outs)
             t4 = time.perf_counter()
             rows.append({"phase": phase, "i": i, "t0": t0,
                          "h2d_ms": (t1 - t0) * 1e3,
