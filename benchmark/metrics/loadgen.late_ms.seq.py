@@ -1,0 +1,6 @@
+"""`loadgen.late_ms` in the sequence family's cells, which report no
+`serve.p95_ms` (PERF.md section 2): the same reading, declared as moving
+`serve.p50_ms`."""
+from pio_bench.readers import load_reader
+
+read = load_reader("loadgen.late_ms")

@@ -137,6 +137,18 @@ class _Deployed:
     start_time: float
 
 
+def _batch_buckets(algorithms, default: tuple) -> tuple:
+    """Where the batcher may cut a batch: the row ladder of the deployed
+    algorithm's device programs if it states one (``batch_row_ladder``; a
+    scorer that packs any number of rows into one program says
+    "anywhere"), else ``default``, the bucketed scorer's.  Every algorithm
+    of an engine runs every batch, so several must agree or ``default``
+    stays."""
+    ladders = {tuple(getattr(algo, "batch_row_ladder", None) or default)
+               for algo in algorithms}
+    return ladders.pop() if len(ladders) == 1 else tuple(default)
+
+
 class QueryServer:
     def __init__(
         self,
@@ -296,9 +308,12 @@ class QueryServer:
             from predictionio_tpu.serving import fastpath
             from predictionio_tpu.serving.batching import MicroBatcher
 
+            buckets = _batch_buckets(
+                self._deployed.algorithms if self._deployed else (),
+                fastpath.BUCKETS)
             self._batcher = MicroBatcher(
                 self._run_query_batch, max_batch=max_batch,
-                window_ms=batch_window_ms, buckets=fastpath.BUCKETS,
+                window_ms=batch_window_ms, buckets=buckets,
             )
         if self.telemetry is not None:
             self._register_metrics()

@@ -1,0 +1,223 @@
+"""The sequence family's serving fast path: a resident model and one device
+program per token count, compiled ahead of time.
+
+The second scorer beside :class:`serving.fastpath.BucketedScorer` (which is
+ALS-shaped: two factor tables and a ladder of ROW counts).  Here the model's
+parameter pytree stays on the device, one dispatch is the histories of the
+batcher's rows PACKED end to end on one token axis
+(``models/latent_moe.pack``), and the ladder is of TOKEN counts: a dispatch
+pads to the next rung, rows pad to a fixed count (a padded row repeats row
+0, which costs the score kernel nothing).  Every rung is lowered, compiled
+and run once before the scorer is handed out, so no request compiles; as
+with the bucketed scorer ``compile_count`` moves only then.
+
+A dispatch is ``h2d`` (one small index array) → ``device_compute`` (the
+whole forward pass and the head's top-k, ``pio_seq_forward``) → ``d2h``
+(ONE ``device_get`` of the (rows, k) values and indices and the program's
+small counters), the stage names every dispatch record and request trace
+already has.  What stays on the device unless asked for: ``h_last`` and
+the routing picks (:meth:`forward` returns them, for audits and tests).
+"""
+
+from __future__ import annotations
+
+import threading
+from typing import Optional, Sequence
+
+import jax
+import numpy as np
+
+from predictionio_tpu.models import latent_moe as _lm
+from predictionio_tpu.obs import tracing as _tracing
+from predictionio_tpu.ops.topk import resolve_backend
+
+# token counts a dispatch pads to; the top rung also bounds one dispatch
+TOKEN_LADDER = (256, 512, 1024, 2048, 4096, 8192)
+# rows of one dispatch: MicroBatcher's default max_batch
+MAX_ROWS = 64
+# what BucketedScorer compiles its leaderboard to by default
+MAX_K = 100
+
+
+class PackedSequenceScorer:
+    """AOT-compiled packed forward + top-k over a device-resident model."""
+
+    def __init__(self, config: _lm.LatentMoEConfig, params: dict, *,
+                 max_k: int = MAX_K, ladder: Sequence[int] = TOKEN_LADDER,
+                 max_rows: int = MAX_ROWS, backend: Optional[str] = None,
+                 device=None):
+        self.config = config
+        self.k = min(max_k, config.vocab_size)
+        self.ladder = tuple(sorted({int(t) for t in ladder}))
+        if config.max_len > self.ladder[-1]:
+            raise ValueError(
+                f"max_len {config.max_len} exceeds the top rung "
+                f"{self.ladder[-1]}: one history must fit one dispatch")
+        self.max_rows = int(max_rows)
+        self.backend = resolve_backend(backend)
+        self._device = device or jax.devices()[0]
+        self._lock = threading.Lock()
+        # resident: a no-op for arrays already on the device (seeded init),
+        # one upload for a model that came back from a pickle
+        self._params = jax.device_put(params, self._device)
+        self.resident_bytes = sum(
+            int(np.prod(v.shape)) * v.dtype.itemsize
+            for v in self._params.values())
+        self.compile_count = 0
+        self.warmup_executions = 0
+        self.hits = {t: 0 for t in self.ladder}
+        self.queries = 0  # rows dispatched
+        self.tokens = 0  # real tokens dispatched
+        self.padded_tokens = 0
+        # (query, key) pairs attention must visit: sum of n(n+1)/2 over rows
+        self.causal_pairs = 0
+        self.merge_passes = 0
+        # over the sparse layers of every dispatch: experts that received a
+        # token (their weights crossed HBM), assignments, and the busiest
+        # expert's load over the mean load
+        self.experts_touched = 0
+        self.expert_assignments = 0
+        self.load_max_over_mean_sum = 0.0
+        self.sparse_layer_dispatches = 0
+        self._fns = {t: self._compile(t) for t in self.ladder}
+        self._warm()
+
+    # -- compile + warm --------------------------------------------------
+    def _program(self, t: int):
+        cfg, k, be = self.config, self.k, self.backend
+
+        def pio_seq_forward(P, flat):
+            return _lm.forward_flat(cfg, P, flat, t, k, score_backend=be)
+
+        return pio_seq_forward
+
+    def _compile(self, t: int):
+        """Lower + compile the ``t``-token program ahead of time."""
+        dummy = self._put(_lm.pack([np.zeros(1, np.int32)], t, self.max_rows))
+        compiled = (
+            jax.jit(self._program(t))
+            .lower(self._params, dummy)
+            .compile()
+        )
+        with self._lock:
+            self.compile_count += 1
+        return compiled
+
+    def _warm(self) -> None:
+        for t in self.ladder:
+            batch = self._put(
+                _lm.pack([np.zeros(1, np.int32)], t, self.max_rows))
+            jax.block_until_ready(self._fns[t](self._params, batch))
+            self.warmup_executions += 1
+
+    def _put(self, batch: dict):
+        return jax.device_put(_lm.flatten(batch), self._device)
+
+    # -- dispatch --------------------------------------------------------
+    def _chunks(self, histories):
+        """Greedy cuts of the rows into dispatches of at most ``max_rows``
+        rows and the top rung's tokens."""
+        top, start, n_tok = self.ladder[-1], 0, 0
+        for i, h in enumerate(histories):
+            if i - start == self.max_rows or n_tok + len(h) > top:
+                yield start, i
+                start, n_tok = i, 0
+            n_tok += len(h)
+        yield start, len(histories)
+
+    def rung_for(self, n_tokens: int) -> int:
+        return next(t for t in self.ladder if t >= n_tokens)
+
+    def forward(self, histories) -> dict:
+        """One direct dispatch of ``histories`` (they must fit one), every
+        output of the program fetched — ``h_last`` and ``picks`` included —
+        plus the ``batch`` layout.  For audits and tests; counts nothing."""
+        n_tok = sum(len(h) for h in histories)
+        batch = _lm.pack(histories, self.rung_for(n_tok), self.max_rows)
+        out = jax.device_get(self._fns[len(batch["tokens"])](
+            self._params, self._put(batch)))
+        out["batch"] = batch
+        return out
+
+    def score_topk(self, histories, k: int):
+        """Top-``k`` (indices, values), one row per history (item-index
+        arrays, oldest first, each non-empty and at most ``max_len``)."""
+        if k > self.k:
+            raise ValueError(f"k={k} exceeds compiled top-k width {self.k}")
+        idx_parts, val_parts = [], []
+        for lo, hi in self._chunks(histories):
+            rows = histories[lo:hi]
+            n_tok = sum(len(h) for h in rows)
+            t = self.rung_for(n_tok)
+            for tr in _tracing.active_traces():
+                tr.annotate(bucket=t)
+            disp = _tracing.active_dispatch()
+            if disp is not None:
+                disp.rung = t
+            with _tracing.stage("batch_assembly"):
+                batch = _lm.pack(rows, t, self.max_rows)
+            with _tracing.stage("h2d"):
+                dev = self._put(batch)
+            with _tracing.stage("device_compute"):
+                out = self._fns[t](self._params, dev)
+                small = {name: out[name] for name in
+                         ("values", "indices", "expert_counts", "merge")
+                         if name in out}
+                # completion INSIDE the stage, as the bucketed scorer does:
+                # device time must not smear into the readback
+                jax.block_until_ready(small)  # pio: ignore[hotpath-block-sync]
+            with _tracing.stage("d2h"):
+                got = jax.device_get(small)
+            self._count(t, rows, n_tok, got, disp)
+            idx_parts.append(got["indices"][: len(rows), :k])
+            val_parts.append(got["values"][: len(rows), :k])
+        return np.concatenate(idx_parts), np.concatenate(val_parts)
+
+    def _count(self, t, rows, n_tok, got, disp) -> None:
+        counts = got["expert_counts"]  # (sparse layers, experts)
+        live = counts.sum(axis=1) > 0
+        ratios = counts[live].max(axis=1) / counts[live].mean(axis=1)
+        with self._lock:
+            self.hits[t] += 1
+            self.queries += len(rows)
+            self.tokens += n_tok
+            self.padded_tokens += t - n_tok
+            self.causal_pairs += sum(len(h) * (len(h) + 1) // 2 for h in rows)
+            self.experts_touched += int((counts > 0).sum())
+            self.expert_assignments += int(counts.sum())
+            self.load_max_over_mean_sum += float(ratios.sum())
+            self.sparse_layer_dispatches += int(live.sum())
+            if "merge" in got:
+                passes = int(got["merge"][0])
+                self.merge_passes += passes
+                if disp is not None:
+                    disp.merge_passes += passes
+
+    def stats(self) -> dict:
+        """Counters for ``GET /`` (``fastpath``); monotone except the
+        configuration."""
+        with self._lock:
+            return {
+                "family": "latent_moe_sequence",
+                "token_ladder": list(self.ladder),
+                "max_rows": self.max_rows,
+                "top_k": self.k,
+                "backend": self.backend,
+                "resident_bytes": self.resident_bytes,
+                "sparse_layers": self.config.n_moe_layers,
+                "experts": self.config.n_routed_experts,
+                "compile_count": self.compile_count,
+                "warmup_executions": self.warmup_executions,
+                "bucket_hits": {str(t): n for t, n in self.hits.items()},
+                "calls": sum(self.hits.values()),
+                "queries": self.queries,
+                "tokens": self.tokens,
+                "padded_tokens": self.padded_tokens,
+                "causal_pairs": self.causal_pairs,
+                "merge_passes": self.merge_passes,
+                "experts_touched": self.experts_touched,
+                "expert_assignments": self.expert_assignments,
+                "load_max_over_mean_sum": round(
+                    self.load_max_over_mean_sum, 4),
+                "sparse_layer_dispatches": self.sparse_layer_dispatches,
+            }

@@ -6,13 +6,26 @@ event histories train a causal-transformer recommender
 history is read live from the event store (same pattern as the e-commerce
 template's serving-time lookups) so recommendations track events newer than
 the model.
+
+Two algorithms share the template and its one history seam
+(:class:`EventStoreHistory` unless the model or the algorithm carries
+another provider): ``sasrec``, the small trained transformer, served one
+query at a time from the host; and ``latentmoe``
+(:class:`LatentMoEAlgorithm`), a latent-attention sparse-expert stack at
+published widths (:mod:`predictionio_tpu.models.latent_moe`) that serves
+through ``deploy --batching``: the batcher's rows are packed into one
+dispatch of a resident, ahead-of-time compiled device program
+(:mod:`predictionio_tpu.serving.seqpath`).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
+import threading
 from typing import Optional
+
+import numpy as np
 
 from predictionio_tpu.core import (
     Algorithm,
@@ -55,6 +68,10 @@ class PredictedResult:
 @dataclasses.dataclass
 class TrainingData(SanityCheck):
     interactions: Interactions
+    # where serving reads histories from, if not the event store: any object
+    # with EventStoreHistory's two methods (a DataSource that holds the
+    # histories in memory hands them over here)
+    histories: Optional[object] = None
 
     def sanity_check(self):
         if len(self.interactions) == 0:
@@ -88,6 +105,48 @@ class SequentialDataSource(DataSource):
         )
 
 
+class EventStoreHistory:
+    """The default history provider: a live serving-time read of the user's
+    most recent events, oldest→newest.  Anything with these two methods can
+    stand in for it (``TrainingData.histories``)."""
+
+    def __init__(self, app_name: str, event_names):
+        self.app_name, self.event_names = app_name, list(event_names)
+
+    def recent_items(self, user: str, limit: int) -> list[str]:
+        try:
+            events = LEventStore.find_by_entity(
+                self.app_name,
+                entity_type="user",
+                entity_id=user,
+                event_names=self.event_names,
+                target_entity_type="item",
+                limit=limit,
+                latest=True,
+            )
+        except Exception:
+            logger.exception("history lookup failed for %s", user)
+            return []
+        return [
+            e.target_entity_id for e in reversed(events) if e.target_entity_id
+        ]
+
+    def recent_indices(self, user: str, limit: int, item_map) -> np.ndarray:
+        """The same history as item indices of ``item_map`` (items the
+        model does not know are dropped)."""
+        idx = item_map.to_index_array(self.recent_items(user, limit))
+        return idx[idx >= 0].astype(np.int32)
+
+
+class _ServesHistories:
+    """The one seam both algorithms read histories through: the model's own
+    provider if it carries one, else the event store."""
+
+    def _histories(self, model=None):
+        return getattr(model, "histories", None) or EventStoreHistory(
+            self.params.appName, self.params.eventNames)
+
+
 @dataclasses.dataclass
 class SASRecParams(Params):
     appName: str = "default"
@@ -111,7 +170,7 @@ class SASRecParams(Params):
     checkpointInterval: int = 10
 
 
-class SASRecAlgorithm(Algorithm):
+class SASRecAlgorithm(_ServesHistories, Algorithm):
     params_cls = SASRecParams
 
     def train(self, ctx, pd: TrainingData) -> SASRecModel:
@@ -138,23 +197,7 @@ class SASRecAlgorithm(Algorithm):
         )
 
     def _history(self, user: str, limit: int) -> list[str]:
-        """Live recent-items lookup, oldest→newest (serving-time read)."""
-        try:
-            events = LEventStore.find_by_entity(
-                self.params.appName,
-                entity_type="user",
-                entity_id=user,
-                event_names=list(self.params.eventNames),
-                target_entity_type="item",
-                limit=limit,
-                latest=True,
-            )
-        except Exception:
-            logger.exception("history lookup failed for %s", user)
-            return []
-        return [
-            e.target_entity_id for e in reversed(events) if e.target_entity_id
-        ]
+        return self._histories().recent_items(user, limit)
 
     def predict(self, model: SASRecModel, query: Query) -> PredictedResult:
         history = self._history(query.user, model.config.max_len)
@@ -166,13 +209,152 @@ class SASRecAlgorithm(Algorithm):
         )
 
 
+@dataclasses.dataclass
+class LatentMoEParams(Params):
+    appName: str = "default"
+    eventNames: tuple = ("view", "buy", "rate")
+    # the model's shape under the keys of its published config.json
+    # (models/latent_moe.LatentMoEConfig.from_hf); vocab_size is the catalog
+    modelConfig: Optional[dict] = None
+    maxLen: int = 2048
+    seed: int = 0
+    # the serving program: token counts compiled ahead, rows and leaderboard
+    # width of one dispatch (serving/seqpath.py's defaults when None)
+    tokenLadder: Optional[tuple] = None
+    maxRows: Optional[int] = None
+    maxK: Optional[int] = None
+    # "auto" pickles the weights; "retrain" re-makes them at deploy
+    persistMode: str = "auto"
+
+
+class LatentMoEAlgorithm(_ServesHistories, Algorithm):
+    """The latent-attention sparse-expert recommender on the batched
+    serving path.  There is no trainer for this family yet: ``train``
+    returns SEEDED, untrained weights, and only for a model small enough to
+    be a test fixture; it refuses a published width rather than hand back
+    noise under a real model's name (the benchmark's family overrides it
+    knowingly)."""
+
+    params_cls = LatentMoEParams
+    # the largest untrained model `train` hands out
+    FIXTURE_PARAMS = 5_000_000
+
+    def __init__(self, params=None):
+        super().__init__(params)
+        self._scorers: dict = {}
+        self._scorer_lock = threading.Lock()
+
+    def _config(self, n_items: int):
+        from predictionio_tpu.models.latent_moe import LatentMoEConfig
+
+        hf = dict(self.params.modelConfig or {})
+        hf.setdefault("vocab_size", n_items)
+        if hf["vocab_size"] < n_items:
+            raise ValueError(
+                f"{n_items} items do not fit a vocabulary of "
+                f"{hf['vocab_size']}")
+        return LatentMoEConfig.from_hf(hf, max_len=self.params.maxLen)
+
+    def _seeded_model(self, pd: TrainingData):
+        from predictionio_tpu.models.latent_moe import (
+            LatentMoEModel, init_params,
+        )
+
+        cfg = self._config(pd.interactions.n_items)
+        return LatentMoEModel(
+            config=cfg, params=init_params(cfg, self.params.seed),
+            item_map=pd.interactions.item_map, histories=pd.histories)
+
+    def train(self, ctx, pd: TrainingData):
+        cfg = self._config(pd.interactions.n_items)
+        if cfg.param_count() > self.FIXTURE_PARAMS:
+            raise NotImplementedError(
+                f"no trainer for the latent-MoE family yet: a model of "
+                f"{cfg.param_count():,} parameters would be served untrained")
+        return self._seeded_model(pd)
+
+    def make_serializable_model(self, model):
+        if self.params.persistMode == "retrain":
+            from predictionio_tpu.core.persistence import RETRAIN
+
+            return RETRAIN
+        import jax
+
+        return dataclasses.replace(model, params=jax.device_get(model.params))
+
+    def _scorer(self, model):
+        with self._scorer_lock:
+            scorer = self._scorers.get(id(model))
+            if scorer is None:
+                from predictionio_tpu.serving import seqpath
+
+                p = self.params
+                scorer = seqpath.PackedSequenceScorer(
+                    model.config, model.params,
+                    max_k=p.maxK or seqpath.MAX_K,
+                    ladder=p.tokenLadder or seqpath.TOKEN_LADDER,
+                    max_rows=p.maxRows or seqpath.MAX_ROWS)
+                self._scorers = {id(model): scorer}  # one generation resident
+            return scorer
+
+    @property
+    def batch_row_ladder(self) -> tuple:
+        """Where the batcher may cut a batch: anywhere.  The device programs
+        are compiled per TOKEN count and take any number of rows up to
+        ``maxRows``, so no row is carried over to reach a rung."""
+        from predictionio_tpu.serving import seqpath
+
+        return tuple(range(1, (self.params.maxRows or seqpath.MAX_ROWS) + 1))
+
+    def warmup(self, model) -> None:
+        """Deploy/reload-time: make the weights resident and compile and run
+        every rung of the token ladder (QueryServer calls this for batching
+        deployments), so no request compiles."""
+        self._scorer(model)
+
+    def serving_stats(self, model) -> Optional[dict]:
+        scorer = self._scorers.get(id(model))
+        return scorer.stats() if scorer is not None else None
+
+    def batch_predict(self, model, queries):
+        """The batcher's rows as ONE packed dispatch (more only when they
+        exceed the top rung); a user with no history gets no items."""
+        provider = self._histories(model)
+        rows, hists, out = [], [], []
+        for i, q in queries:
+            h = provider.recent_indices(
+                q.user, model.config.max_len, model.item_map)
+            if len(h):
+                rows.append((i, q))
+                hists.append(h)
+            else:
+                out.append((i, PredictedResult(itemScores=[])))
+        if rows:
+            scorer = self._scorer(model)
+            idx, vals = scorer.score_topk(
+                hists, min(scorer.k, max(q.num for _, q in rows)))
+            inv = model.item_map.inverse
+            for row, (i, q) in enumerate(rows):
+                out.append((i, PredictedResult(itemScores=[
+                    ItemScore(item=inv[int(j)], score=float(s))
+                    for j, s in zip(idx[row][:q.num], vals[row][:q.num])
+                ])))
+        return out
+
+    def predict(self, model, query: Query) -> PredictedResult:
+        return self.batch_predict(model, [(0, query)])[0][1]
+
+
 class SequentialRecommendationEngine(EngineFactory):
     @classmethod
     def apply(cls) -> Engine:
         return Engine(
             data_source_cls=SequentialDataSource,
             preparator_cls=IdentityPreparator,
-            algorithm_cls_map={"sasrec": SASRecAlgorithm},
+            algorithm_cls_map={
+                "sasrec": SASRecAlgorithm,
+                "latentmoe": LatentMoEAlgorithm,
+            },
             serving_cls=FirstServing,
             query_cls=Query,
         )
