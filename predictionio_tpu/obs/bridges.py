@@ -105,6 +105,17 @@ def bridge_batcher(
                 [("", (), _num(s.get("carried_rows")))],
             ),
             _fam(
+                "pio_batcher_rounded_up_batches_total", "counter",
+                "Dispatches that ran short of their rung: rows between two "
+                "rungs run as one, padded by the scorer.",
+                [("", (), _num(s.get("rounded_up_batches")))],
+            ),
+            _fam(
+                "pio_batcher_padded_rows_total", "counter",
+                "Rows those dispatches were short of their rung by.",
+                [("", (), _num(s.get("padded_rows")))],
+            ),
+            _fam(
                 "pio_batcher_run_ms_max", "gauge",
                 "Longest single batch run since start, milliseconds.",
                 [("", (), _num(s.get("run_ms_max")))],
@@ -116,6 +127,21 @@ def bridge_batcher(
                 [("", (), _num(s.get("slow_dispatches")))],
             ),
         ]
+        rungs = s.get("rung_run_ms")
+        if isinstance(rungs, dict) and rungs:
+            fams.append(
+                _fam(
+                    "pio_batcher_rung_run_ms", "gauge",
+                    "The cut's estimate of one run at a rung: the least of "
+                    "its newest runs there, milliseconds.",
+                    [
+                        ("", (("rung", str(k)),), _num(v))
+                        for k, v in sorted(
+                            rungs.items(), key=lambda kv: int(kv[0])
+                        )
+                    ],
+                )
+            )
         sizes = s.get("batch_sizes")
         if isinstance(sizes, dict) and sizes:
             fams.append(

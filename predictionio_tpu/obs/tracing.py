@@ -151,12 +151,14 @@ class Dispatch:
 
     __slots__ = (
         "seq", "start_unix", "t_run", "thread", "thread_id", "inline",
-        "rows", "rung", "merge_passes", "carried", "depth_end", "stages",
+        "rows", "rung", "merge_passes", "carried", "padded", "depth_end",
+        "stages",
         "dc_start", "dc_end", "wall_s", "error", "slow_after_s",
     )
 
     def __init__(self, seq: int, inline: bool, rows: int, carried: int,
-                 t_run: float, collect_s: float, slow_after_s: float):
+                 t_run: float, collect_s: float, slow_after_s: float,
+                 padded: int = 0):
         th = threading.current_thread()
         self.seq = seq
         self.t_run = t_run  # the run's start; the record's is collect_s earlier
@@ -164,6 +166,8 @@ class Dispatch:
         self.start_unix = time.time() - collect_s
         self.thread, self.thread_id = th.name, th.ident
         self.inline, self.rows, self.carried = inline, rows, carried
+        # rows short of the batcher's rung: what a bucketed scorer pads
+        self.padded = padded
         self.rung: Optional[int] = None
         # the score kernel's merge passes that inserted, over the run's
         # device programs (0 where the program does not count them)
@@ -200,6 +204,7 @@ class Dispatch:
             "rung": self.rung,
             "mergePasses": self.merge_passes,
             "carriedRows": self.carried,
+            "paddedRows": self.padded,
             "depthAtEnd": self.depth_end,
             "slowAfterMs": round(self.slow_after_s * 1e3, 4),
             "wallMs": (

@@ -24,10 +24,17 @@ The accumulation window is ADAPTIVE, not a fixed sleep:
 * Within the budget the worker stops as soon as the arrival stream goes
   quiet: it waits for the next item at most ``EWMA(inter-arrival gap) ×
   GAP_MULT`` past the last arrival (burst over ⇒ dispatch now).
-* Dispatch drains to a BUCKET BOUNDARY of the compile-cache ladder
-  (``serving/fastpath.py``): a 9-deep queue dispatches 8 + carries 1
-  instead of padding 9→16, so device occupancy stays ≥ 50% by
-  construction and the carried tail leads the next batch (FIFO).
+* THE CUT is decided from the batcher's own run times.  Rows in hand
+  that fall between two rungs of the compile-cache ladder
+  (``serving/fastpath.py``) either all run now, one dispatch rounded up to
+  the next rung (the scorer pads it), or are cut at the rung below with
+  the tail carried to lead the next batch (FIFO) — whichever finishes the
+  waiting rows sooner by the time a run at each rung has been taking
+  (:meth:`MicroBatcher._cut`).  Where a rung costs about the same as the
+  one below it (the ALS score program: 9.2 ms at rung 1, 9.7 at rung 8)
+  2–7 waiting rows run together; where time goes with the rows (host
+  code, or 33 rows against rungs 32 / 64) the batch is cut as before.  A
+  ladder with every row count never falls between rungs.
 
 SINGLE-FLIGHT COALESCING (opt-in via ``submit(key=...)``): identical
 in-flight queries — same canonical fingerprint — attach to ONE pending
@@ -42,8 +49,9 @@ inherit a 504 they didn't earn.
 
 ONE RECORD PER DISPATCH, always on: every batch run gets a sequence
 number and an :class:`obs.tracing.Dispatch` (who ran it, rows, rung, rows
-the cut carried, the wall of each stage), kept in a bounded ring that
-``GET /trace/dispatches.json`` serves and summed into :meth:`stats`.  A run
+the cut carried or the rung padded, the wall of each stage), kept in a
+bounded ring that ``GET /trace/dispatches.json`` serves and summed into
+:meth:`stats`.  A run
 that holds the batcher past ``max(SLOW_FLOOR_S, SLOW_MULT x EWMA(run))``
 has every thread's stack written to the server's log by
 ``faulthandler``'s watchdog (a C thread: it fires even if the stalled
@@ -52,6 +60,7 @@ thread never releases the GIL) and its record kept in a second ring.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import faulthandler
 import logging
@@ -108,6 +117,9 @@ class MicroBatcher:
     # where the watchdog writes the stacks: None is the server's log
     # (sys.stderr when the run is armed); it must have a file descriptor
     SLOW_DUMP_FILE = None
+    # the cut's estimate of a run at a rung (and of the gap between two
+    # runs) is the LEAST of this many newest ones
+    RUNS_KEPT = 5
 
     def __init__(
         self,
@@ -163,6 +175,19 @@ class MicroBatcher:
         self._prev_dc_end: Optional[float] = None
         self._prev_left_work = False
         self._carried_rows = 0
+        # what the cut decides from (see _cut): the newest runs at each
+        # rung as (seq, seconds), and the newest gaps between a run's end
+        # and the next one's start with rows waiting.  Written under _busy
+        # AND _stats_lock; _cut reads under _busy, stats() under the lock
+        self._rung_runs = {
+            b: collections.deque(maxlen=self.RUNS_KEPT) for b in self.buckets
+        }
+        self._run_gaps: collections.deque = collections.deque(
+            maxlen=self.RUNS_KEPT
+        )
+        self._prev_run_end: Optional[float] = None
+        self._n_rounded_up = 0
+        self._padded_rows = 0
         self._run_s_sum = 0.0
         self._run_s_max = 0.0
         self._run_max_seq = 0
@@ -316,6 +341,16 @@ class MicroBatcher:
                 "ewma_run_ms": round(self._ewma_run * 1e3, 4),
                 # monotone sums over the dispatch records
                 "carried_rows": self._carried_rows,
+                # dispatches that ran short of their rung, the rows they
+                # were short by, and the estimates the cut decides from
+                "rounded_up_batches": self._n_rounded_up,
+                "padded_rows": self._padded_rows,
+                "rung_run_ms": {
+                    str(r): round(t * 1e3, 4)
+                    for r in self.buckets
+                    if (t := self._rung_s(r)) is not None
+                },
+                "run_gap_ms": round(min(self._run_gaps, default=0.0) * 1e3, 4),
                 "run_ms_sum": round(self._run_s_sum * 1e3, 4),
                 "run_ms_max": round(self._run_s_max * 1e3, 4),
                 "run_ms_max_seq": self._run_max_seq,
@@ -367,13 +402,60 @@ class MicroBatcher:
         except queue.Empty:
             return None
 
-    def _boundary(self, n: int) -> int:
-        """Largest ladder rung ≤ n (ladder always contains 1)."""
-        best = self.buckets[0]
-        for b in self.buckets:
-            if b <= n:
-                best = b
-        return best
+    def _rung_of(self, n: int) -> int:
+        """Smallest ladder rung ≥ n: what a bucketed scorer pads ``n`` rows
+        to (``n`` never passes ``max_batch``, the top rung)."""
+        return self.buckets[bisect.bisect_left(self.buckets, n)]
+
+    def _rung_s(self, rung: int) -> Optional[float]:
+        """What a run at ``rung`` takes, by the batcher's own clock: the
+        LEAST of its newest ``RUNS_KEPT`` runs there, inline ones too.
+
+        The least, because a machine pause must not capture it: one run in
+        a few hundred stands still for 0.1–15 s, and a mean that swallowed
+        such a run at rung 8 would stop rounding up to it and, never
+        running it again, never relearn.  A pause can only leave the least
+        alone; a rung that really got slower shows after ``RUNS_KEPT``
+        runs.  None while the rung has not run within the last ``RING``
+        dispatches: it then counts as never run, and :meth:`_cut` tries
+        it once — which is also how an estimate whose only sample was a
+        pause is replaced.
+        """
+        runs = self._rung_runs[rung]
+        if not runs or runs[-1][0] <= self._seq - self.RING:
+            return None
+        return min(dt for _, dt in runs)
+
+    def _cut(self, n: int) -> int:
+        """How many of ``n`` rows in hand to run now; the rest is carried.
+
+        With ``lo`` the largest rung ≤ n and ``hi`` the smallest ≥ n: on a
+        rung (``n == lo``) everything runs.  Between two, the summed
+        completion times of the waiting rows decide.  All ``n`` as one
+        dispatch rounded up to ``hi`` are done after ``t(hi)`` each; cut,
+        ``lo`` rows are done after ``t(lo)`` and the other ``n - lo`` after
+        ``t(lo)``, the gap between two runs and a run at their own rung.
+        ``t`` is :meth:`_rung_s`.  A rung with no estimate is tried: ``hi``
+        by running everything once, ``lo`` by cutting.  Nothing compiles
+        here: every rung was warmed at deploy.
+        """
+        i = bisect.bisect_left(self.buckets, n)
+        if self.buckets[i] == n or i == 0:
+            return n
+        lo, hi = self.buckets[i - 1], self.buckets[i]
+        t_lo, t_hi = self._rung_s(lo), self._rung_s(hi)
+        if t_hi is None:
+            return n
+        if t_lo is None:
+            return lo
+        # the rest's rung is at most lo: until it has run, t(lo) bounds it
+        t_rest = self._rung_s(self._rung_of(n - lo))
+        if t_rest is None:
+            t_rest = t_lo
+        gap = min(self._run_gaps, default=0.0)
+        together = n * t_hi
+        apart = lo * t_lo + (n - lo) * (t_lo + gap + t_rest)
+        return n if together <= apart else lo
 
     def _loop(self) -> None:
         while not self._stop.is_set():
@@ -411,9 +493,9 @@ class MicroBatcher:
                     if nxt is None:
                         break
                     batch.append(nxt)
-                # cut to a compile-cache bucket boundary; the tail leads
-                # the next batch instead of padding this one
-                size = self._boundary(len(batch))
+                # run them all, rounded up to the next rung, or cut at the
+                # rung below: the tail then leads the next batch
+                size = self._cut(len(batch))
                 carried = len(batch) - size
                 self._carry.extendleft(reversed(batch[size:]))
                 batch = batch[:size]
@@ -535,9 +617,12 @@ class MicroBatcher:
         # collect: first row taken -> the run starts (the window, the wait
         # for _busy, the drain and the cut)
         threshold = max(self.SLOW_FLOOR_S, self.SLOW_MULT * self._ewma_run)
+        # the rung these rows round up to (after the deadline drop)
+        rung = self._rung_of(len(batch))
         rec = _tracing.Dispatch(
             seq, inline, len(batch), carried, t_run,
             collect_s=waited + (t_run - t_in), slow_after_s=threshold,
+            padded=rung - len(batch),
         )
         traces = [p.trace for p in batch if p.trace is not None]
         for p in batch:
@@ -612,6 +697,13 @@ class MicroBatcher:
             if inline:
                 self._n_inline += 1
             self._carried_rows += carried
+            self._n_rounded_up += rec.padded > 0
+            self._padded_rows += rec.padded
+            # the cut's estimates; a failed run says nothing of the rung
+            if run_error is None:
+                self._rung_runs[rung].append((seq, run_dt))
+            if self._prev_left_work and self._prev_run_end is not None:
+                self._run_gaps.append(t_run - self._prev_run_end)
             self._run_s_sum += run_dt
             if run_dt > self._run_s_max:
                 self._run_s_max, self._run_max_seq = run_dt, seq
@@ -631,6 +723,7 @@ class MicroBatcher:
             self._n_slow += slow
             self._current = None
         self._prev_dc_end = rec.dc_end
+        self._prev_run_end = t_end
         self._prev_left_work = rec.depth_end > 0
         self._ring.append(rec)
         if slow:

@@ -93,8 +93,11 @@ logger = logging.getLogger(__name__)
 
 # The batch-size ladder. Powers of two above a singleton lane: 1 serves the
 # trickle case with zero padding, 64 matches MicroBatcher's default
-# max_batch. Tails between rungs pad to the next rung (worst waste: 7 rows
-# at rung 8).
+# max_batch. Rows between rungs pad to the next rung (worst waste: 6 rows
+# at rung 8); whether a batch arrives between rungs is the batcher's
+# decision (serving/batching.MicroBatcher._cut): it hands over all its rows
+# when a run at the next rung finishes them sooner than a cut at the rung
+# below and a second run would, by the run times it has measured per rung.
 BUCKETS = (1, 8, 16, 32, 64)
 
 

@@ -52,10 +52,13 @@ class Runs:
     long as the test's clock says, and can be held at a gate."""
 
     def __init__(self, clock=None, h2d_s=0.0, device_s=0.0, d2h_s=0.0,
-                 post_s=0.0):
+                 post_s=0.0, device_s_by_rung=None):
         self.clock = clock
         self.walls = (("h2d", h2d_s), ("device_compute", device_s),
                       ("d2h", d2h_s))
+        # {rung: seconds} in place of the one device_s: the batch's rows
+        # round up to the smallest rung that holds them
+        self.device_s_by_rung = device_s_by_rung
         self.post_s = post_s
         self.batches = []
         self.gate = threading.Event()
@@ -67,6 +70,9 @@ class Runs:
         self.entered.set()
         assert self.gate.wait(WAIT_S)
         for name, seconds in self.walls:
+            if name == "device_compute" and self.device_s_by_rung:
+                seconds = self.device_s_by_rung[min(
+                    r for r in self.device_s_by_rung if r >= len(queries))]
             with tracing.stage(name):
                 if self.clock is not None:
                     self.clock.advance(seconds)
@@ -90,6 +96,28 @@ def submit_traced(mb, query, key=None):
     return th, tr
 
 
+def held_then(mb, runs, first, waiting):
+    """``first`` runs inline and is held; ``waiting`` queue up behind it,
+    in order; then the gate opens and everybody is answered.  Returns the
+    traces by query."""
+    before = mb.stats()["queries"]
+    runs.entered.clear()
+    runs.gate.clear()
+    submitted = [submit_traced(mb, first)]
+    assert runs.entered.wait(WAIT_S)
+    for n, q in enumerate(waiting, start=1):
+        submitted.append(submit_traced(mb, q))
+        wait_until(lambda: len(mb._in_hand) + mb.depth() == n, f"{q} queued")
+    runs.gate.set()
+    for th, _ in submitted:
+        th.join(WAIT_S)
+        assert not th.is_alive()
+    wait_until(
+        lambda: mb.stats()["queries"] == before + 1 + len(waiting),
+        "every row counted")
+    return dict(zip([first, *waiting], (tr for _, tr in submitted)))
+
+
 def wait_until(cond, what):
     end = time.monotonic() + WAIT_S
     while not cond():
@@ -103,27 +131,23 @@ def three_dispatches(clock):
 
     Ladder 1/2/4: A runs inline and is held; B, C, D arrive meanwhile; the
     worker then dispatches B + C (the rung under three rows) and carries D
-    to a third dispatch.  Every run spends 3 ms in h2d, 250 ms on the
-    device, 1 ms reading back and 2 ms building answers; the host takes
-    5 ms between A's device program returning and the next one's launch.
+    to a third dispatch, because a run at rung 4 is known to take four
+    times one at rungs 1 and 2: three rows rounded up would be done after
+    3 x 1,006 ms, cut they are done after 2 x 256 + (256 + 256).  Every run
+    spends 3 ms in h2d, 250 ms on the device at rungs 1 and 2, 1 ms reading
+    back and 2 ms building answers; the host takes 5 ms between A's device
+    program returning and the next one's launch.
     """
-    runs = Runs(clock, h2d_s=0.003, device_s=0.250, d2h_s=0.001,
-                post_s=0.002)
+    runs = Runs(clock, h2d_s=0.003, d2h_s=0.001, post_s=0.002,
+                device_s_by_rung={1: 0.250, 2: 0.250, 4: 1.000})
     mb = MicroBatcher(runs, max_batch=4, window_ms=2.0, buckets=(1, 2, 4))
+    # one earlier run at rungs 2 and 4, as the batcher would have kept it
+    for rung, run_s in ((2, 0.256), (4, 1.006)):
+        mb._rung_runs[rung].append((0, run_s))
     try:
-        runs.gate.clear()
-        th_a, tr_a = submit_traced(mb, "A")
-        assert runs.entered.wait(WAIT_S)
-        others = [submit_traced(mb, q) for q in "BCD"]
-        # B is in the worker's hands, C and D in the queue
-        wait_until(lambda: len(mb._in_hand) + mb.depth() == 3, "B, C, D queued")
-        runs.gate.set()
-        for th, _ in [(th_a, tr_a)] + others:
-            th.join(WAIT_S)
-            assert not th.is_alive()
+        traces = held_then(mb, runs, "A", "BCD")
         wait_until(lambda: mb.stats()["batches"] == 3, "three dispatches")
-        yield mb, runs, {"A": tr_a, **{q: tr for q, (_, tr) in
-                                      zip("BCD", others)}}
+        yield mb, runs, traces
     finally:
         runs.gate.set()
         mb.stop()
@@ -194,6 +218,202 @@ def test_a_slower_run_takes_the_maximum_and_its_seq(clock):
         assert s["run_ms_sum"] == pytest.approx(750.0, abs=1e-6)
     finally:
         mb.stop()
+
+
+# -- the cut ---------------------------------------------------------------------
+
+
+def test_rows_between_rungs_run_as_one_dispatch_and_are_counted(clock):
+    """The device takes the same 10 ms at rungs 1 and 8: B and C, waiting
+    behind A, run together, rounded up to rung 8 -- the first time because
+    rung 8 has never run, afterwards because 2 x 10 ms beats 10 + 20."""
+    runs = Runs(clock, device_s_by_rung={1: 0.010, 8: 0.010})
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        assert mb.stats()["rung_run_ms"] == {}
+        for _ in range(2):
+            traces = held_then(mb, runs, "A", "BC")
+            assert traces["C"].to_dict()["meta"]["passes"] == 2
+        assert [len(b) for b in runs.batches] == [1, 2, 1, 2]
+        s = mb.stats()
+        assert s["rounded_up_batches"] == 2 and s["padded_rows"] == 2 * 6
+        assert s["carried_rows"] == 0
+        assert s["rung_run_ms"] == {"1": 10.0, "8": 10.0}
+        assert s["run_gap_ms"] == 0.0  # this clock moves inside runs only
+        recs = {r["seq"]: r for r in mb.dispatches()["dispatches"]}
+        assert [recs[n]["paddedRows"] for n in (1, 2, 3, 4)] == [0, 6, 0, 6]
+        assert [recs[n]["carriedRows"] for n in (1, 2, 3, 4)] == [0] * 4
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_an_unmeasured_rung_is_tried_once(clock):
+    """A run at rung 4 takes ten times one at rung 1.  Nobody knows until
+    it has run: the first three waiting rows are handed over together;
+    the next three are cut, one row a dispatch, each carried in turn."""
+    runs = Runs(clock, device_s_by_rung={1: 0.1, 4: 1.0})
+    mb = MicroBatcher(runs, max_batch=4, buckets=(1, 4))
+    try:
+        held_then(mb, runs, "A", "BCD")
+        assert [len(b) for b in runs.batches] == [1, 3]
+        traces = held_then(mb, runs, "E", "FGH")
+        assert runs.batches[2:] == [["E"], ["F"], ["G"], ["H"]]
+        assert traces["H"].to_dict()["meta"]["passes"] == 4
+        s = mb.stats()
+        assert s["rounded_up_batches"] == 1 and s["padded_rows"] == 1
+        assert s["carried_rows"] == 2 + 1
+        assert s["rung_run_ms"] == {"1": 100.0, "4": 1000.0}
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_one_run_of_three_seconds_does_not_stop_rounding_up(clock):
+    """A machine pause inside one rung-8 run must not capture the
+    estimate: it is the least of the newest runs, so it stays 10 ms and
+    the next waiting rows are rounded up as before."""
+    by_rung = {1: 0.010, 8: 0.010}
+    runs = Runs(clock, device_s_by_rung=by_rung)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        held_then(mb, runs, "A", "BC")       # teaches rung 8
+        by_rung[8] = 3.0                     # the pause
+        held_then(mb, runs, "D", "EF")
+        by_rung[8] = 0.010
+        s = mb.stats()
+        assert s["run_ms_max"] == pytest.approx(3000.0)
+        assert s["slow_dispatches"] == 1     # past max(2 s, 8 x ewma_run)
+        assert s["rung_run_ms"]["8"] == pytest.approx(10.0)
+        held_then(mb, runs, "G", "HI")
+        assert [len(b) for b in runs.batches] == [1, 2, 1, 2, 1, 2]
+        assert mb.stats()["rounded_up_batches"] == 3
+        assert mb.stats()["carried_rows"] == 0
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_an_estimate_whose_only_run_was_a_pause_is_replaced(clock):
+    """Rung 8's first run ever meets the pause, so the batcher cuts; once
+    that run has left the horizon of RING dispatches the rung counts as
+    never run, is tried again, and rounding up resumes."""
+    by_rung = {1: 0.010, 8: 3.0}
+    runs = Runs(clock, device_s_by_rung=by_rung)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        held_then(mb, runs, "A", "BC")       # tried: 3 s
+        by_rung[8] = 0.010
+        held_then(mb, runs, "D", "EF")
+        assert [len(b) for b in runs.batches] == [1, 2, 1, 1, 1]
+        assert mb.stats()["rung_run_ms"]["8"] == pytest.approx(3000.0)
+        for i in range(MicroBatcher.RING):
+            mb.submit(i)
+        assert "8" not in mb.stats()["rung_run_ms"]
+        held_then(mb, runs, "G", "HI")
+        assert [len(b) for b in runs.batches[-2:]] == [1, 2]
+        assert mb.stats()["rung_run_ms"]["8"] == pytest.approx(10.0)
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_a_failed_run_teaches_no_estimate(clock):
+    def broken(queries):
+        raise ValueError("no scores today")
+
+    mb = MicroBatcher(broken, buckets=(1, 8))
+    try:
+        with pytest.raises(ValueError):
+            mb.submit("q")
+        assert mb.stats()["rung_run_ms"] == {}
+    finally:
+        mb.stop()
+
+
+def test_a_ladder_of_every_row_count_neither_pads_nor_carries(clock):
+    """The sequence family's ladder: whatever waits is a rung, so the cut
+    never decides and the device time (here growing with the rows) is
+    not asked."""
+    runs = Runs(clock, device_s_by_rung={n: 0.01 * n for n in range(1, 9)})
+    mb = MicroBatcher(runs, max_batch=8, buckets=tuple(range(1, 9)))
+    try:
+        held_then(mb, runs, "A", "BCDEF")
+        held_then(mb, runs, "G", "HIJ")
+        assert [len(b) for b in runs.batches] == [1, 5, 1, 3]
+        s = mb.stats()
+        assert (s["rounded_up_batches"], s["padded_rows"],
+                s["carried_rows"]) == (0, 0, 0)
+        assert all(r["paddedRows"] == 0
+                   for r in mb.dispatches()["dispatches"])
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_a_row_whose_deadline_lapsed_is_dropped_from_a_rounded_up_batch(
+        clock):
+    from predictionio_tpu.common.resilience import Deadline, DeadlineExceeded
+
+    runs = Runs(clock, device_s_by_rung={1: 0.010, 8: 0.010})
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    outcome = {}
+
+    def impatient():
+        try:
+            outcome["C"] = mb.submit("C", deadline=Deadline.after_ms(30))
+        except DeadlineExceeded as e:
+            outcome["C"] = e
+
+    try:
+        runs.gate.clear()
+        th_a, _ = submit_traced(mb, "A")
+        assert runs.entered.wait(WAIT_S)
+        th_b, _ = submit_traced(mb, "B")
+        wait_until(lambda: len(mb._in_hand) + mb.depth() == 1, "B queued")
+        th_c = threading.Thread(target=impatient, daemon=True)
+        th_c.start()
+        wait_until(lambda: len(mb._in_hand) + mb.depth() == 2, "C queued")
+        th_d, _ = submit_traced(mb, "D")
+        wait_until(lambda: len(mb._in_hand) + mb.depth() == 3, "D queued")
+        th_c.join(WAIT_S)  # C gives up while A still holds the batcher
+        assert isinstance(outcome["C"], DeadlineExceeded)
+        runs.gate.set()
+        for th in (th_a, th_b, th_d):
+            th.join(WAIT_S)
+            assert not th.is_alive()
+        assert runs.batches == [["A"], ["B", "D"]]  # C never ran
+        s = mb.stats()
+        assert s["expired_dropped"] == 1 and s["queries"] == 3
+        # the record says what ran: two rows, six short of rung 8
+        (rec, _) = mb.dispatches()["dispatches"]
+        assert (rec["rows"], rec["paddedRows"]) == (2, 6)
+        assert s["padded_rows"] == 6
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_the_batchers_new_counters_reach_the_registry_under_the_contract():
+    """`rounded_up_batches`, `padded_rows` and `rung_run_ms` are bridged
+    like the other batcher counters, and the repo's metric catalog
+    (`analysis/metrics_contract`) has them."""
+    from predictionio_tpu import analysis
+    from predictionio_tpu.obs import bridges
+    from predictionio_tpu.obs.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    bridges.bridge_batcher(reg, lambda: {
+        "batches": 5, "rounded_up_batches": 2, "padded_rows": 11,
+        "rung_run_ms": {"1": 9.2, "8": 9.7}})
+    text = reg.render_prometheus()
+    assert "pio_batcher_rounded_up_batches_total 2" in text
+    assert "pio_batcher_padded_rows_total 11" in text
+    assert 'pio_batcher_rung_run_ms{rung="8"} 9.7' in text
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    report = analysis.run(root, analyzers=["metrics"])
+    assert [f for f in report.findings
+            if f.symbol.startswith("pio_batcher")] == []
 
 
 # -- the records ---------------------------------------------------------------

@@ -6,14 +6,16 @@ across repeated sizes, and the adaptive-window micro-batcher under burst
 vs. trickle arrival.
 """
 
+import itertools
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
 
 from predictionio_tpu.parallel.mesh import MeshContext
-from predictionio_tpu.serving import fastpath
+from predictionio_tpu.serving import batching, fastpath
 from predictionio_tpu.serving.batching import MicroBatcher
 from predictionio_tpu.serving.fastpath import BUCKETS, BucketedScorer, bucket_for
 
@@ -186,6 +188,12 @@ class TestFusedBackend:
             ref.stats()["kernel"]["intensity_flops_per_byte"]
 
 
+# one run at each rung, ms: the ALS score program as measured on a v5e
+# (CHANGES.md, PR 26), and host code whose time goes with the rows
+_ALS_RUN_MS = {1: 9.24, 8: 9.65, 16: 10.57, 32: 11.76, 64: 14.01}
+_ROWS_RUN_MS = {b: float(b) for b in BUCKETS}
+
+
 class TestAdaptiveBatcher:
     def test_burst_coalesces(self):
         """64 concurrent submitters with a real window must land in far
@@ -231,8 +239,32 @@ class TestAdaptiveBatcher:
         finally:
             mb.stop()
 
-    def test_drains_to_bucket_boundary_and_carries_tail(self):
-        """9 queued queries dispatch as 8 + a carried 1 — never pad to 16."""
+    @pytest.mark.parametrize("run_ms, waiting, expected", [
+        # a rung costs about what the one below does (the ALS score
+        # program on a v5e): rows between two rungs run as ONE dispatch,
+        # which the scorer pads
+        (_ALS_RUN_MS, 2, [1, 2]),
+        (_ALS_RUN_MS, 7, [1, 7]),
+        (_ALS_RUN_MS, 9, [1, 9]),
+        # ... but not at any price: 33 x 14.0 ms against 32 x 11.8 and one
+        # row through a second run -- the cut still carries
+        (_ALS_RUN_MS, 33, [1, 32, 1]),
+        # on a rung there is nothing to decide
+        (_ALS_RUN_MS, 8, [1, 8]),
+        (_ALS_RUN_MS, 64, [1, 64]),
+        # time goes with the rows (host code): 9 queued run as 8 + a
+        # carried 1, never 9 -> 16, and 63 walk down the ladder
+        (_ROWS_RUN_MS, 9, [1, 8, 1]),
+        (_ROWS_RUN_MS, 63, [1, 32, 16, 8, 1, 1, 1, 1, 1, 1, 1]),
+    ])
+    def test_the_cut_rounds_up_or_carries_by_its_own_run_times(
+            self, monkeypatch, run_ms, waiting, expected):
+        """A first run holds the batcher while ``waiting`` more rows queue
+        up.  Time is the test's: a run takes what ``run_ms`` says for its
+        rung, and the batcher has seen one run at every rung before."""
+        clock = [1000.0]
+        monkeypatch.setattr(batching, "time", types.SimpleNamespace(
+            perf_counter=lambda: clock[0], time=time.time))
         calls = []
         in_first = threading.Event()
         release = threading.Event()
@@ -240,46 +272,44 @@ class TestAdaptiveBatcher:
         def run(batch):
             if not in_first.is_set():
                 in_first.set()
-                release.wait(2)  # hold the worker while 9 more enqueue
-            calls.append(len(batch))
+                release.wait(5)  # hold the batcher while the rest enqueue
+            calls.append(list(batch))
+            clock[0] += run_ms[bucket_for(len(batch))] / 1e3
             return list(batch)
 
         mb = MicroBatcher(run, max_batch=64, window_ms=20.0)
+        for rung, ms in run_ms.items():
+            mb._rung_runs[rung].append((0, ms / 1e3))
         try:
-            results = [None] * 10
+            results = [None] * (waiting + 1)
             threads = [
                 threading.Thread(
                     target=lambda i=i: results.__setitem__(i, mb.submit(i))
                 )
-                for i in range(10)
+                for i in range(waiting + 1)
             ]
             threads[0].start()
-            assert in_first.wait(2)  # worker now held inside run([0])
-            for t in threads[1:]:
-                t.start()
-            deadline = time.time() + 2
-            while mb._queue.qsize() < 9 and time.time() < deadline:
-                time.sleep(0.001)
+            assert in_first.wait(5)  # the batcher is held inside run([0])
+            for n, t in enumerate(threads[1:], start=1):
+                t.start()  # one at a time: queue order is submit order
+                deadline = time.time() + 5
+                while (mb.depth() + len(mb._in_hand) < n
+                       and time.time() < deadline):
+                    time.sleep(0.0005)
             release.set()
             for t in threads:
-                t.join()
-            assert results == list(range(10))
-            assert calls[0] == 1
-            # the 9 already-queued queries cut at the rung-8 boundary; the
-            # tail is carried into the following batch instead of padding
-            assert calls[1] == 8
-            assert calls[2] == 1
-        finally:
-            mb.stop()
-
-    def test_boundary_math(self):
-        mb = MicroBatcher(lambda b: list(b), max_batch=64, window_ms=1.0)
-        try:
-            assert mb._boundary(9) == 8
-            assert mb._boundary(8) == 8
-            assert mb._boundary(63) == 32
-            assert mb._boundary(64) == 64
-            assert mb._boundary(1) == 1
+                t.join(5)
+            assert results == list(range(waiting + 1))
+            assert [len(c) for c in calls] == expected
+            # FIFO: a carried tail leads the next batch, nothing overtakes
+            assert [q for c in calls for q in c] == list(range(waiting + 1))
+            s = mb.stats()
+            # what every dispatch of the worker but the last left behind
+            assert s["carried_rows"] == sum(
+                waiting - done
+                for done in itertools.accumulate(expected[1:-1]))
+            assert s["padded_rows"] == sum(
+                bucket_for(n) - n for n in expected)
         finally:
             mb.stop()
 
