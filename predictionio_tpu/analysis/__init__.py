@@ -30,9 +30,8 @@ powers three more:
 * ``collective`` — shard_map/mesh axis consistency, pallas_call
   index_map arity, and host-sync taint extended one call deep.
 
-Entry points: ``pio analyze`` in the CLI, :func:`run` for tests and
-``tools/bench_matrix.py``.  Findings at severity ``error`` gate tier-1
-via ``tests/test_analysis.py``.
+Entry points: ``pio analyze`` in the CLI, :func:`run` for tests.  Findings
+at severity ``error`` gate tier-1 via ``tests/test_analysis.py``.
 """
 
 from predictionio_tpu.analysis.core import (

@@ -42,8 +42,7 @@ analyzer the shared interprocedural substrate:
   docs/analysis.md rather than papered over with false cycles.
 
 The graph is built once per :class:`RepoIndex` and cached on it, so
-``lockorder``/``deadline``/``collective`` and the bench artifact all
-share one build.
+``lockorder``/``deadline``/``collective`` all share one build.
 """
 
 from __future__ import annotations

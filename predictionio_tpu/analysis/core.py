@@ -41,7 +41,6 @@ SEVERITIES = ("error", "warning", "info")
 # python sources scanned when the root is a full checkout; a root without
 # these (the test fixtures) is scanned wholesale instead
 PY_ROOTS = ("predictionio_tpu", "tools")
-PY_TOP_FILES = ("bench.py",)
 SKIP_DIR_PREFIXES = ("__", ".")
 
 _SUPPRESS_RE = re.compile(
@@ -215,12 +214,7 @@ class RepoIndex:
             for d in PY_ROOTS
             if os.path.isdir(os.path.join(self.root, d))
         ]
-        if roots:
-            for f in PY_TOP_FILES:
-                p = os.path.join(self.root, f)
-                if os.path.isfile(p):
-                    yield p
-        else:
+        if not roots:
             roots = [self.root]  # fixture layout: scan everything
         for base in roots:
             for dirpath, dirnames, files in os.walk(base):

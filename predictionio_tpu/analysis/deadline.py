@@ -114,9 +114,7 @@ def _fn_segment(mod: Module, fn: ast.AST) -> str:
 
 def _entry_points(index: RepoIndex, graph: callgraph.CallGraph) -> set[str]:
     out: set[str] = set()
-    # fixture layout (all files flat): every file is an "entry layer";
-    # in the real checkout the flat top-level files are bench harnesses,
-    # not request handlers
+    # fixture layout (all files flat): every file is an "entry layer"
     fixture = all("/" not in m.rel for m in index.modules)
     for qual, node in graph.nodes.items():
         if node.ast_node is None:

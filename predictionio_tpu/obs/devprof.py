@@ -1,15 +1,13 @@
 """Device-utilization accounting: cost models, peaks, live rates, capture.
 
-ROADMAP item 4 is gated on measurement — "MFU is effectively unmeasured" —
-and before this module the only utilization numbers lived in offline bench
-runs (``bench.py``).  This module makes utilization a RUNTIME fact:
+Utilization as a RUNTIME fact, from a model of the work (what the chip
+measured is the benchmark's: ``benchmark/``, ``PERF_LEDGER.jsonl``):
 
 * **One cost model, one peak table.**  The analytic ALS iteration cost and
-  the per-chip peak table previously private to ``bench.py`` live here, so
-  the bench, the training loop, and the serving fastpath all divide by the
-  same denominators.  ``PEAKS`` is keyed by ``device_kind``; a device that
-  is not in it (a CPU, another TPU generation) reports null utilization,
-  never another chip's.
+  the per-chip peak table live here, so the training loop and the serving
+  fastpath divide by the same denominators.  ``PEAKS`` is keyed by
+  ``device_kind``; a device that is not in it (a CPU, another TPU
+  generation) reports null utilization, never another chip's.
 * **Rolling-window dispatch accountant** (:class:`DeviceUtilization`).
   The serving fastpath annotates every AOT bucket with FLOPs/bytes from
   ``compiled.cost_analysis()`` (analytic fallback when the compiler
@@ -37,10 +35,8 @@ __all__ = [
     "PEAKS",
     "peak_for",
     "als_train_cost",
-    "als_train_cost_amplified",
     "fused_train_cost",
     "fused_train_vread_bytes",
-    "train_utilization",
     "score_cost",
     "DeviceUtilization",
     "train_recorder",
@@ -96,59 +92,9 @@ def als_train_cost(
     return float(flops_per_iter), float(bytes_per_iter)
 
 
-def train_utilization(
-    n_ratings, n_users, n_items, rank, iterations, dtype, dt, n_chips,
-    device_kind,
-) -> dict:
-    """Analytic achieved-FLOP/s + HBM-GB/s from workload dims and wall time.
-
-    The shape ``bench.py`` publishes in its ``utilization`` block; the
-    cost model is :func:`als_train_cost`, the denominators :data:`PEAKS`.
-    """
-    flops_per_iter, bytes_per_iter = als_train_cost(
-        n_ratings, n_users, n_items, rank, dtype
-    )
-    flops = flops_per_iter * iterations / dt / n_chips
-    gbps = bytes_per_iter * iterations / dt / n_chips
-    peak = peak_for(device_kind)
-    return {
-        "model_flops_per_sec_per_chip": round(flops / 1e9, 2),  # GFLOP/s
-        "model_hbm_gbps_per_chip": round(gbps / 1e9, 2),
-        "mfu": round(flops / peak["flops"], 6) if peak else None,
-        "hbm_util": round(gbps / peak["hbm_gbps"], 6) if peak else None,
-    }
-
-
 # bytes per factor element by serving dtype (mirrors ops/quantize.py;
 # duplicated here so the obs layer never imports the ops layer)
 _FACTOR_BYTES = {"f32": 4.0, "bf16": 2.0, "int8": 1.0}
-
-# XLA's TPU row gather reads one sector per row regardless of row width —
-# the read-amplification constant docs/perf_roofline.md derives (~512 B
-# per 40 B factor row at rank 10).
-SECTOR_BYTES = 512.0
-
-
-def als_train_cost_amplified(
-    n_ratings: int, n_users: int, n_items: int, rank: int, dtype: str = "f32"
-) -> tuple[float, float]:
-    """:func:`als_train_cost` with the gather term XLA actually pays.
-
-    The plain model charges ``k·s`` bytes per gathered factor row; on TPU
-    the XLA gather reads a full ~512 B sector per row (``SECTOR_BYTES``),
-    a ~12.8× amplification at rank 10 f32 that dominates the half-step's
-    bytes.  This is the honest reference-backend roofline the fused
-    kernel's intensity is compared against in ``bench.py``.
-    """
-    k = rank
-    s = _FACTOR_BYTES.get(dtype, 4.0)
-    flops, _ = als_train_cost(n_ratings, n_users, n_items, rank, dtype)
-    ents = n_users + n_items
-    nbytes = (
-        n_ratings * 2 * (max(SECTOR_BYTES, k * s) + 12)  # sector reads
-        + ents * k * (4 + s)  # factor write (f32) + opposite read
-    )
-    return float(flops), float(nbytes)
 
 
 def fused_train_vread_bytes(
@@ -157,8 +103,7 @@ def fused_train_vread_bytes(
     """Bytes of the fused kernel's ONE sequential opposite-factor read per
     iteration (both half-steps): each side streams the other side's
     matrix into VMEM once at the compute dtype, plus the per-row f32
-    scale column when int8.  This is the term the compute dtype narrows —
-    the bench gate holds int8 to ≤ 0.5× the f32 value.
+    scale column when int8.  This is the term the compute dtype narrows.
     """
     s = _FACTOR_BYTES.get(compute_dtype, 4.0)
     ents = float(n_users + n_items)
@@ -176,7 +121,7 @@ def fused_train_cost(
 
     The Pallas training kernel (``ops/train_kernel.py``) streams the
     opposite factor matrix into VMEM once per half-step and gathers rows
-    against VMEM, so the per-rating gather term — ``SECTOR_BYTES`` under
+    against VMEM, so the per-rating gather term — a ~512 B sector under
     XLA, ``k·s`` even in the charitable model — disappears from HBM
     entirely.  What remains:
 

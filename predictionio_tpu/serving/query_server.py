@@ -1163,8 +1163,8 @@ class QueryServer:
                 # malformed query values are a CLIENT bug: surface them
                 # through the route's TypeError → 400 mapping, never mask
                 # them behind a stale degraded 200 (which would also pollute
-                # the `degraded` counter bench.py's clean gate reads as a
-                # server regression)
+                # the `degraded` counter, which reads as a server
+                # regression)
                 self.counters.inc("query_errors")
                 raise
             except Exception as e:

@@ -1,9 +1,8 @@
 """Serving load test: concurrent queries against a deployed engine.
 
-The p50-predict-latency companion to bench.py's training throughput
-(BASELINE.md headline metrics). Fires N concurrent workers at
-``/queries.json`` and reports client-side latency quantiles + QPS; the
-server's own histogram (its ``GET /`` route) gives the service-side view.
+Fires N concurrent workers at ``/queries.json`` and reports client-side
+latency quantiles + QPS; the server's own histogram (its ``GET /`` route)
+gives the service-side view.
 
 Each worker holds ONE persistent HTTP/1.1 connection (keep-alive) for its
 whole run — the realistic client shape (SDKs pool connections), and the
@@ -27,10 +26,9 @@ def zipf_mandelbrot_weights(n: int, s: float = 1.1, q: float = 50.0):
 
     The q shift matches real catalogs: at s=1.1, q=50 the hottest of ~59k
     ids draws ~0.4% of traffic, like ML-25M's ~0.32% — a pure Zipf head
-    would take ~10%, which no real workload does.  Shared with bench.py's
-    ``_sample_ids`` so the load test and the training bench agree on what
-    "skewed" means.  Returns a normalized float64 numpy array (numpy is
-    imported lazily: round-robin load tests stay stdlib-only).
+    would take ~10%, which no real workload does.  Returns a normalized
+    float64 numpy array (numpy is imported lazily: round-robin load tests
+    stay stdlib-only).
     """
     import numpy as np
 

@@ -365,6 +365,22 @@ class TestDenseSolver:
             m_on.user_factors, m_off.user_factors, rtol=5e-2, atol=5e-3
         )
 
+    def test_xla_cost_analysis_positive_and_scales_with_ratings(self, ctx):
+        from predictionio_tpu.models.als import dense_step_cost_analysis
+
+        small = self._zipf_interactions(nu=300, ni=120, nr=4_000)
+        big = self._zipf_interactions(nu=300, ni=120, nr=16_000)
+        cfg = ALSConfig(rank=4, solver="dense")
+        ca_s = dense_step_cost_analysis(ctx, small, cfg)
+        ca_b = dense_step_cost_analysis(ctx, big, cfg)
+        assert ca_s["flops_per_iter_per_device"] > 0
+        assert ca_s["bytes_per_iter_per_device"] > 0
+        # 4x the ratings must cost materially more compiled work
+        assert (
+            ca_b["flops_per_iter_per_device"]
+            > 2 * ca_s["flops_per_iter_per_device"]
+        )
+
 
 class TestBatchedSpdSolve:
     """The plain-ops Cholesky solve that replaced cho_factor/cho_solve:

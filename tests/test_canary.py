@@ -11,6 +11,7 @@ verdict is never lost.
 """
 
 import datetime as dt
+import itertools
 import json
 import os
 import subprocess
@@ -276,6 +277,36 @@ def test_error_breach_rolls_back_and_quarantines(canary_env):
         c.start_canary()
 
 
+@pytest.mark.parametrize("slo_ms,p99_ms,reason", [
+    ("120", 150.0, "> SLO 120ms"),       # over the absolute SLO
+    (None, 100.0, "x baseline 40.0ms"),  # no SLO: 2.5x the baseline's p99
+    ("120", 100.0, None),                # an SLO set: the ratio is not read
+])
+def test_latency_verdict_absolute_slo_or_baseline_ratio(
+    canary_env, monkeypatch, slo_ms, p99_ms, reason
+):
+    """A candidate that answers everything, slowly: judged against the
+    absolute p99 SLO when one is set, else against a ratio of the
+    baseline's live p99; a breach rolls back and quarantines."""
+    if slo_ms is not None:
+        monkeypatch.setenv("PIO_CANARY_P99_SLO_MS", slo_ms)
+    router = three_replica_router()
+    c = make_controller(router, storage=FakeStorage(["g2", "g1"]))
+    assert c.start_canary()
+    router.gens = {k: dict(v) for k, v in HEALTHY_GENS.items()}
+    router.gens["g2"]["p99Ms"] = p99_ms
+    if reason is None:
+        assert c._verify_tick() is False  # passed: promoted, soak next
+        assert c.stats()["state"] == SOAKING
+        return
+    assert c._verify_tick() is True
+    outcome = c.stats()["lastOutcome"]
+    assert outcome["outcome"] == "quarantined"
+    assert reason in outcome["reason"]
+    assert c.reloads == [("http://c", "g2"), ("http://c", "g1")]
+    assert persistence.is_quarantined("g2")
+
+
 def test_pass_promotes_then_soaks_clean(canary_env):
     router = three_replica_router()
     fleet = FakeFleet()
@@ -508,6 +539,116 @@ def test_worker_thread_drives_verify_promote_soak(canary_env):
         assert all(r["instanceId"] == "g2" for r in router.replicas)
     finally:
         c.stop()
+
+
+# ---------------------------------------------------------------------------
+# the real Router under the controller: attribution, blast radius, retries
+# ---------------------------------------------------------------------------
+
+
+class GenerationStub:
+    """A query-server-shaped replica that serves whichever engine instance
+    it was last told to ``/reload``; the instance named ``bad`` answers 500
+    to every third query in whichever process serves it (a model that
+    fails on some inputs: never five in a row, so the replica's breaker
+    stays closed and the canary keeps its share of traffic)."""
+
+    def __init__(self, instance_id, bad):
+        from predictionio_tpu.common.http import (
+            HttpService, Response, json_response,
+        )
+
+        self.instance_id = instance_id
+        self._served = itertools.count()
+        self.svc = HttpService("genstub")
+
+        @self.svc.route("GET", r"/readyz")
+        def readyz(req):
+            return json_response(200, {
+                "status": "ready", "fastpathWarm": True, "draining": False,
+                "generation": 1, "engineInstanceId": self.instance_id,
+            })
+
+        @self.svc.route("POST", r"/reload")
+        def reload(req):
+            self.instance_id = req.params["instanceId"]
+            return json_response(
+                200, {"engineInstanceId": self.instance_id}
+            )
+
+        @self.svc.route("POST", r"/queries\.json")
+        def queries(req):
+            if self.instance_id == bad and next(self._served) % 3 == 0:
+                return Response(status=500, body={"message": "bad model"})
+            return json_response(
+                200, {"itemScores": [{"item": "i1", "score": 1.0}]}
+            )
+
+    def start(self):
+        return f"http://127.0.0.1:{self.svc.start('127.0.0.1', 0)}"
+
+
+def test_bad_candidate_under_load_never_reaches_a_client(
+    canary_env, monkeypatch
+):
+    """Three replicas behind the real Router, clients pounding it, and a
+    candidate generation that fails a third of its queries: the router
+    attributes each attempt to the instance that served it, its retries
+    absorb the canary's failures (ZERO client-visible errors), the
+    controller rolls the one canaried replica back and quarantines the
+    candidate, no more than the canary's share of attempts ever met it,
+    and the receipt refuses a second attempt."""
+    from predictionio_tpu.serving.router import ADMITTED, Router
+    from tests.test_fleet import _LoadGen
+
+    monkeypatch.setenv("PIO_CANARY_TICK_MS", "50")
+    monkeypatch.setenv("PIO_CANARY_MIN_SAMPLES", "50")
+    monkeypatch.setenv("PIO_CANARY_WINDOW_S", "30")
+    stubs = [GenerationStub("g1", bad="g2") for _ in range(3)]
+    router = Router([s.start() for s in stubs], telemetry=False)
+    router.health_interval_ms = 50.0
+    ctrl = CanaryController(router, storage=FakeStorage(["g2", "g1"]))
+    router.attach_canary(ctrl)
+    load = _LoadGen(
+        f"http://127.0.0.1:{router.start('127.0.0.1', 0)}", workers=4
+    )
+    try:
+        deadline = time.monotonic() + 20.0
+        while time.monotonic() < deadline and not all(
+            r["state"] == ADMITTED and r["instanceId"] == "g1"
+            for r in router.replica_view()
+        ):
+            time.sleep(0.02)
+        load.start()
+        assert ctrl.start_canary()
+        deadline = time.monotonic() + 30.0
+        while ctrl.active() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        load.stop()
+        outcome = ctrl.stats()["lastOutcome"]
+        assert outcome["outcome"] == "quarantined", ctrl.stats()
+        assert outcome["candidate"] == "g2"
+        assert "error rate" in outcome["reason"]
+        assert load.failures == [] and load.ok > 0
+        gens = router.generation_stats()
+        assert gens["g2"]["requests"] >= 10 and gens["g2"]["errors"] >= 1
+        # (until the next health probe reads the swapped replica's
+        # /readyz, its first failures are still attributed to g1)
+        assert gens["g1"]["p99Ms"] is not None
+        blast = gens["g2"]["requests"] / sum(
+            g["requests"] for g in gens.values()
+        )
+        assert blast <= 0.5, gens
+        assert [s.instance_id for s in stubs] == ["g1", "g1", "g1"]
+        assert persistence.is_quarantined("g2")
+        with pytest.raises(ValueError):
+            ctrl.start_canary()
+    finally:
+        load.stop()
+        ctrl.stop()
+        router.stop()
+        for s in stubs:
+            s.svc.stop()
 
 
 # ---------------------------------------------------------------------------

@@ -978,6 +978,14 @@ class _LoadGen:
         for t in self.threads:
             t.start()
 
+    def wait_answered(self, n, timeout=120.0):
+        """Keep the load on until ``n`` requests were answered: a count,
+        not a rate, so a busy machine takes longer and does not fail."""
+        wait_until(
+            lambda: self.ok > n, timeout=timeout,
+            msg=f"load never reached {n} answered requests",
+        )
+
     def stop(self):
         self.stop_evt.set()
         for t in self.threads:
@@ -1005,10 +1013,10 @@ class TestFleetChaos:
                 t_end = time.monotonic() + 4.0
                 while time.monotonic() < t_end:
                     time.sleep(0.1)
+                load.wait_answered(100)
             finally:
                 load.stop()
             assert load.failures == []  # THE acceptance line
-            assert load.ok > 100
             # the fleet self-heals: child respawned, warmed, re-admitted
             wait_until(
                 lambda: fleet.status()["replicas"][0]["restarts"] >= 1,
@@ -1114,10 +1122,10 @@ class TestFleetChaos:
                     ),
                     timeout=30.0, msg="preempted replica never respawned",
                 )
+                load.wait_answered(100)
             finally:
                 load.stop()
             assert load.failures == []  # THE acceptance line
-            assert load.ok > 100
             assert scaler.stats()["scaleUps"] >= 1
             # the crowd has passed: the surge replica drains back out
             wait_until(

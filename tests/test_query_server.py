@@ -481,6 +481,21 @@ class TestLoadtest:
         finally:
             srv.shutdown()
 
+    def test_zipf_mandelbrot_weights_cover_range_and_skew(self):
+        import numpy as np
+
+        from predictionio_tpu.tools.loadtest import zipf_mandelbrot_weights
+
+        p = zipf_mandelbrot_weights(1000, s=1.1, q=50.0)
+        assert p.shape == (1000,) and abs(p.sum() - 1.0) < 1e-12
+        assert (np.diff(p) < 0).all()  # rank 0 is the hottest key
+        rng = np.random.default_rng(0)
+        z = rng.choice(1000, size=100_000, p=p)
+        u = rng.integers(0, 1000, 100_000)
+        assert z.min() >= 0 and z.max() < 1000
+        # zipf concentrates mass on low ids far beyond uniform
+        assert (z < 50).mean() > 2 * (u < 50).mean()
+
 
 class TestBatchPredict:
     def test_batch_predict_file(self, trained, tmp_path):

@@ -620,6 +620,29 @@ class TestQueryServerChaos:
         finally:
             qs.stop()
 
+    def test_clean_run_leaves_every_counter_quiet(self, trained):
+        """No fault planted, no overload: a load test through the batched
+        path answers everything and the resilience layer records nothing —
+        any shed, deadline, degraded answer or query error here is a
+        regression, not noise."""
+        from predictionio_tpu.tools.loadtest import run_loadtest
+
+        qs, base = self._server(trained, batching=True)
+        try:
+            res = run_loadtest(
+                base, {"num": 3}, requests=48, concurrency=4,
+                samples={"user": [f"u{u}" for u in range(8)]},
+            )
+            assert res["ok"] == 48 and res["errors"] == 0
+            assert res["shed"] == 0 and res["deadlineExceeded"] == 0
+            _, info, _ = _call("GET", base + "/")
+            counters = info["resilience"]["counters"]
+            for name in ("shed", "deadline_exceeded", "breaker_open",
+                         "degraded", "query_errors"):
+                assert counters.get(name, 0) == 0, (name, counters)
+        finally:
+            qs.stop()
+
     def test_loadtest_carries_deadline_and_breaks_out_sheds(self, trained):
         from predictionio_tpu.tools.loadtest import run_loadtest
 

@@ -271,6 +271,41 @@ class TestShardedBitIdentical:
         assert len(sh["resident_bytes"]) == 4
 
 
+def test_popularity_plan_balances_live_result_share(ctx):
+    """A catalog past one device's byte budget, served sharded under Zipf
+    users: every shard's resident block fits the budget, and a plan built
+    from the measured per-item wins keeps the LIVE max/min result share —
+    the share the attributed per-shard busy fraction is split by — within
+    1.5.  (A count over what the scorer records, not a time.)"""
+    from predictionio_tpu.tools.loadtest import zipf_mandelbrot_weights
+
+    n_items, rank, k, budget = 1024, 16, 20, 18_000
+    rng = np.random.default_rng(12)
+    U = rng.normal(size=(128, rank)).astype(np.float32)
+    V = rng.normal(size=(n_items, rank)).astype(np.float32)
+    users = rng.choice(
+        128, size=256, p=zipf_mandelbrot_weights(128, s=1.1)
+    ).astype(np.int32)
+    repl = BucketedScorer(ctx, U, V, max_k=k, sharding="replicated")
+    ref_idx, _ = repl.score_topk(users, k)
+    wins = np.bincount(
+        np.asarray(ref_idx).reshape(-1), minlength=n_items
+    ).astype(np.float64)
+    n_shards = sharding.shard_count_for_budget(n_items, rank * 4.0, budget)
+    assert V.nbytes > budget and n_shards == 4
+    plan = sharding.build_plan(
+        n_items, n_shards, weights=wins, strategy="popularity",
+        capacity_budget_bytes=budget,
+    )
+    shrd = BucketedScorer(ctx, U, V, max_k=k, plan=plan, sharding="sharded")
+    shrd.score_topk(users, k)
+    st = shrd.stats()["sharding"]
+    assert max(st["resident_bytes"]) <= budget
+    share = st["result_share"]
+    assert min(share) > 0 and max(share) / min(share) <= 1.5
+    assert all(b is not None for b in st["busy_fraction"])
+
+
 class TestCrossShardTies:
     def test_duplicate_rows_on_different_shards_tie_break_by_id(
         self, ctx, factors
