@@ -320,7 +320,11 @@ def forward_packed(cfg: LatentMoEConfig, P: dict, tokens, positions,
                              interpret=interpret)
     h_last = rms_norm(x[last_idx], P["final_norm"],
                       cfg.rms_norm_eps).astype(P["head"].dtype)
-    pad_mask = jnp.arange(P["head"].shape[0]) >= cfg.vocab_size
+    # the score kernel's lane row, made in the form it reads
+    pad_mask = (
+        jnp.arange(P["head"].shape[0], dtype=jnp.int32)[None, :]
+        >= cfg.vocab_size
+    ).astype(jnp.int32)
     be = resolve_backend(score_backend)
     outs = gather_score_topk(
         h_last, P["head"], jnp.arange(last_idx.shape[0], dtype=jnp.int32), k,

@@ -165,8 +165,9 @@ def gather_score_topk(
     ``U[u_idx] @ V.T`` then masked top-k — as one Pallas kernel on the
     fused backend, or separate XLA ops on the reference backend (see the
     module docstring for the dispatch rules).  ``item_mask`` is True for
-    slots that must never win (padded item tail, blacklists); it
-    broadcasts over the batch.  ``u_scale``/``v_scale`` are the per-row
+    slots that must never win (padded item tail, blacklists): a bool
+    ``(n_items,)`` vector or the int32 ``(1, n_items)`` lane row of
+    ``score_kernel.item_mask_row``; it broadcasts over the batch.  ``u_scale``/``v_scale`` are the per-row
     int8 scales from :mod:`ops.quantize`.  Returns
     ``(values (B, k), indices (B, k))``; ``with_stats`` appends the fused
     kernel's merge counters (int32 ``(2,)``: passes, blocks) and is an
@@ -197,5 +198,8 @@ def gather_score_topk(
         )
         if v_scale is not None:
             scores = scores * v_scale.reshape(1, -1)
-        mask = item_mask[None, :] if item_mask is not None else None
+        # a bool (n,) vector, or the fused kernel's int32 (1, n) lane row
+        mask = (
+            item_mask.reshape(1, -1) != 0 if item_mask is not None else None
+        )
         return top_k_with_mask(scores, k, mask=mask)

@@ -351,9 +351,12 @@ class BucketedScorer:
             )
         else:
             self._Uscale = self._Vscale = None
-        self._item_pad_mask = ctx.replicate(
-            np.arange(self._n_items_pad) >= self.n_items
-        )
+        pad_mask = np.arange(self._n_items_pad) >= self.n_items
+        if self.backend == "fused":
+            # the lane row the kernel reads, built once: a bool mask would
+            # be converted by every dispatch
+            pad_mask = _score_kernel.item_mask_row(pad_mask)
+        self._item_pad_mask = ctx.replicate(pad_mask)
         # everything the compiled programs take except the per-call indices
         if self.factor_dtype == "int8":
             # construction-time: no other thread holds the scorer yet
@@ -1162,10 +1165,13 @@ class BucketedScorer:
                 "backend": self.backend,
                 "factor_dtype": self.factor_dtype,
                 "resident_factor_bytes": self.resident_factor_bytes,
-                "block_items": (
-                    min(_score_kernel.BLOCK_I, self._n_items_pad)
-                    if self.backend == "fused" else None
-                ),
+                # the sweep's tile per compiled rung, from the function
+                # the kernel itself calls (per probe block under IVF, per
+                # shard when sharded)
+                "block_items": _score_kernel.tile_report(
+                    self.buckets, self._V.shape[1], self._V.dtype,
+                    self._n_items_pad,
+                ) if self.backend == "fused" else None,
                 "warmup_executions": self.warmup_executions,
                 # top-rung arithmetic intensity: the roofline position the
                 # docs derive (docs/perf_roofline.md)

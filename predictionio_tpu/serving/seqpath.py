@@ -29,6 +29,7 @@ import numpy as np
 
 from predictionio_tpu.models import latent_moe as _lm
 from predictionio_tpu.obs import tracing as _tracing
+from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.topk import resolve_backend
 
 # token counts a dispatch pads to; the top rung also bounds one dispatch
@@ -196,6 +197,7 @@ class PackedSequenceScorer:
     def stats(self) -> dict:
         """Counters for ``GET /`` (``fastpath``); monotone except the
         configuration."""
+        head = self._params["head"]
         with self._lock:
             return {
                 "family": "latent_moe_sequence",
@@ -203,6 +205,12 @@ class PackedSequenceScorer:
                 "max_rows": self.max_rows,
                 "top_k": self.k,
                 "backend": self.backend,
+                # the head's score tile (every dispatch is max_rows rows),
+                # as BucketedScorer reports its rungs'
+                "block_items": _score_kernel.tile_report(
+                    (self.max_rows,), head.shape[1], head.dtype,
+                    head.shape[0],
+                ) if self.backend == "fused" else None,
                 "resident_bytes": self.resident_bytes,
                 "sparse_layers": self.config.n_moe_layers,
                 "experts": self.config.n_routed_experts,

@@ -135,6 +135,35 @@ class TestFusedBackend:
         assert kern["warmup_executions"] == len(BUCKETS)
         assert kern["intensity_flops_per_byte"] > 0
 
+    def test_kernel_stats_report_each_rungs_tile(self, ctx, fused, scorer):
+        # what the kernel's own rule gives each compiled rung: 29 items
+        # pad to 32, one block; rows in whole sublane tiles
+        tiles = fused.stats()["kernel"]["block_items"]
+        assert tiles == {
+            str(b): {"tile_rows": max(b, 8), "block_items": 32}
+            for b in BUCKETS}
+        assert scorer.stats()["kernel"]["block_items"] is None
+        rng = np.random.default_rng(2)
+        wide = BucketedScorer(
+            ctx, rng.normal(size=(8, 128)).astype(np.float32),
+            rng.normal(size=(9000, 128)).astype(np.float32),
+            max_k=5, buckets=(1, 64), backend="fused")
+        # 9,000 items pad to 9,216 = 18 x 512 (not to a block multiple)
+        assert wide.stats()["kernel"]["block_items"] == {
+            "1": {"tile_rows": 8, "block_items": 4096},
+            "64": {"tile_rows": 64, "block_items": 2048}}
+        assert wide._static_args[1].shape == (9216, 128)
+        # placement built the lane row once: the program converts nothing
+        assert wide._static_args[2].shape == (1, 9216)
+        assert wide._static_args[2].dtype == np.int32
+        users = np.arange(5, dtype=np.int32)
+        idx, vals = wide.score_topk(users, k=5)
+        ri, rv = _reference_topk(
+            np.asarray(wide._static_args[0]),
+            np.asarray(wide._static_args[1])[:9000], users, 5)
+        np.testing.assert_array_equal(idx, ri)
+        np.testing.assert_allclose(vals, rv, rtol=1e-5, atol=1e-5)
+
     def test_zero_compiles_under_load(self, fused, monkeypatch):
         before = fused.compile_count
 

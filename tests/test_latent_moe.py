@@ -268,6 +268,16 @@ def test_scorer_compiles_ahead_and_never_again(weights):
     assert st["sparse_layer_dispatches"] == 3 * CFG.n_moe_layers
     with pytest.raises(ValueError, match="exceeds"):
         sc.score_topk(hists, K + 1)
+    # the head's score tile, as the kernel's own rule gives it; there is
+    # none to report on the reference backend (the CPU's `auto`)
+    assert st["block_items"] is None
+    head = weights["f32"]["head"]
+    fused = PackedSequenceScorer(CFG, weights["f32"], max_k=K, ladder=(64,),
+                                 max_rows=4, backend="fused")
+    assert fused.stats()["block_items"] == {"4": {
+        "tile_rows": 8, "block_items": min(head.shape[0], 4096)}}
+    fi, fv = fused.score_topk(hists[:2], 5)
+    np.testing.assert_array_equal(fi, idx[:2])
 
 
 def _http(url, body=None):

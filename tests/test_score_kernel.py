@@ -17,8 +17,14 @@ The merge loop ends at the first pass that places nothing (ISSUE 26):
 integer-valued factors whose scores are exact in any accumulation order,
 so values as well as indices must equal the reference's bit for bit, and
 pins the kernel's own count of passes to a numpy model of the rule.
+
+The sweep's tile is sized from the shapes it runs (ISSUE 30):
+``TestTileGeometry`` holds the rule itself and the two things it brought —
+rows filled up to whole sublane tiles by repeating the last one, and a
+last block that hangs over the table's end.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -298,6 +304,148 @@ class TestEarlyExit:
         with pytest.raises(ValueError, match="with_stats"):
             gather_score_topk(
                 U, V, u_idx, 5, backend="reference", with_stats=True)
+
+
+class TestTileGeometry:
+    N = 96  # one block of its own size; 16 divides it; 40 leaves a tail
+    BLOCKS = (96, 16, 40)
+
+    @pytest.mark.parametrize("batch,rank,dtype,n_pad,want", [
+        (1, 128, "float32", 5_700_096, (8, 4096)),  # als-wgde-d128
+        (8, 128, "float32", 5_700_096, (8, 4096)),
+        (16, 128, "float32", 5_700_096, (16, 4096)),
+        (64, 128, "float32", 5_700_096, (64, 2048)),  # rows narrow it
+        (64, 2048, "bfloat16", 129_536, (64, 512)),  # the sequence head
+        (1, 10, "float32", 59_392, (8, 4096)),  # a row is whole lane tiles
+        (8, 64, "float32", 304, (8, 304)),  # a 300-item IVF cluster
+        (3, 128, "float32", 1024, (8, 1024)),  # at most the table
+    ])
+    def test_rule(self, batch, rank, dtype, n_pad, want):
+        assert score_kernel.tile_geometry(batch, rank, dtype, n_pad) == want
+
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16", "int8"))
+    @pytest.mark.parametrize("rank", (8, 100, 128, 256, 2048, 7168))
+    def test_rule_keeps_whole_tiles_under_the_budget(self, rank, dtype):
+        itemsize = jnp.dtype(dtype).itemsize
+        lanes = -(-rank // 128) * 128
+        for batch in (1, 2, 7, 8, 9, 16, 33, 64, 256):
+            for n_items in (5, 300, 512, 513, 5000, 129_280, 5_700_000):
+                n_pad = score_kernel.pad_block_items(n_items)
+                rows, block = score_kernel.tile_geometry(
+                    batch, rank, dtype, n_pad)
+                assert rows >= batch and rows % 8 == 0 and rows - batch < 8
+                # whole lane tiles, or the whole (small) table
+                assert block == n_pad or block % score_kernel.BLOCK_I == 0
+                assert 0 < block <= n_pad
+                assert block == min(score_kernel.BLOCK_I, n_pad) or (
+                    score_kernel._live_tile_bytes(rows, block, lanes, itemsize)
+                    <= score_kernel.VMEM_TILE_BUDGET)
+                assert block * lanes * itemsize <= max(
+                    score_kernel.BLOCK_BYTES,
+                    score_kernel.BLOCK_I * lanes * itemsize)
+
+    @pytest.mark.parametrize("n_items,want", [
+        (300, 304), (512, 512), (513, 1024), (129_280, 129_536),
+        (5_700_000, 5_700_096)])
+    def test_padding_does_not_follow_the_block(self, n_items, want):
+        # tables, clusters and shards pad to BLOCK_I whatever block the
+        # sweep takes: seeded weights and byte counts hang on these shapes
+        assert score_kernel.pad_block_items(n_items) == want
+
+    @pytest.mark.parametrize("batch", (1, 3, 8, 9))
+    @pytest.mark.parametrize("block", BLOCKS)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_rows_and_blocks_identical_on_exact_scores(
+            self, batch, block, dtype, assert_same_topk):
+        U, V = _int_factors(50, self.N, seed=batch + block)
+        rng = np.random.default_rng(batch * 100 + block)
+        u_idx = rng.integers(0, 50, batch).astype(np.int32)
+        mask = rng.random(self.N) < 0.2
+        fused, ref = _fused_with_stats(
+            U, V, u_idx, 10, block, dtype=dtype, item_mask=mask)
+        assert np.asarray(fused[0]).shape == (batch, 10)
+        if dtype == "int8":
+            # per-row scales: integer scores that tie exactly come out an
+            # ulp apart, and on XLA:CPU the two backends round them apart
+            assert_same_topk(fused[1], fused[0], ref[1], ref[0])
+        else:
+            _assert_identical(fused, ref)
+
+    @pytest.mark.parametrize("batch", (1, 3, 8, 9))
+    @pytest.mark.parametrize("block", BLOCKS)
+    def test_repeated_rows_add_no_merge_pass(self, batch, block):
+        # the tile is filled to 8 or 16 rows by repeating the last real
+        # row: the counters must be those of the real rows alone
+        U, V = _int_factors(50, self.N, seed=block)
+        rng = np.random.default_rng(batch + block)
+        u_idx = rng.integers(0, 50, batch).astype(np.int32)
+        mask = rng.random(self.N) < 0.1
+        fused, ref = _fused_with_stats(U, V, u_idx, 10, block, item_mask=mask)
+        _assert_identical(fused, ref)
+        S = np.where(mask[None, :], score_kernel.NEG_INF, U[u_idx] @ V.T)
+        assert list(np.asarray(fused[2])) == list(_merge_model(S, 10, block))
+
+    @pytest.mark.parametrize("batch", (1, 3, 8))
+    def test_tie_with_the_kth_value_across_a_ragged_block_edge(self, batch):
+        # blocks of 40 over 96 items: edges at 40 and 80, the last block
+        # hangs 24 lanes over the end.  k = 4 and the 4th value is 3: its
+        # equals on both sides of each edge must not displace it, and a 5
+        # just past the first edge enters behind the earlier 5s
+        scores = np.ones(self.N)
+        scores[[0, 1]] = 5
+        scores[[2, 3, 39, 40, 79, 80, 95]] = 3
+        scores[41] = 5
+        U, V = _ranked(scores)
+        fused, ref = _fused_with_stats(
+            U, V, np.arange(batch, dtype=np.int32), 4, 40)
+        _assert_identical(fused, ref)
+        assert list(np.asarray(fused[1])[0]) == [0, 1, 41, 2]
+        assert list(np.asarray(fused[2])) == [4 + 1, 2]
+
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_masked_item_in_the_ragged_tail(self, dtype):
+        # ascending scores put the winners in the block that hangs over
+        # the end; the best three are excluded, and what the overhang read
+        # (of V, the mask row, an int8 scale row) must never win
+        U, V = _ranked(np.arange(1, self.N + 1))
+        mask = np.zeros(self.N, bool)
+        mask[-3:] = True
+        fused, ref = _fused_with_stats(
+            U, V, np.arange(3, dtype=np.int32), 5, 40, dtype=dtype,
+            item_mask=mask)
+        _assert_ranking_equal(fused, ref, dtype)
+        assert list(np.asarray(fused[1])[0]) == list(
+            range(self.N - 4, self.N - 9, -1))
+
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_unpadded_table_with_a_ragged_block(self, dtype, assert_same_topk):
+        # 1,000 items pad to 1,024 here; blocks of 384 leave a tail of 256
+        U, V = _int_factors(20, 1000, seed=5)
+        mask = np.random.default_rng(6).random(1000) < 0.3
+        fused, ref = _fused_with_stats(
+            U, V, np.arange(3, dtype=np.int32), 10, 384, dtype=dtype,
+            item_mask=mask)
+        assert_same_topk(fused[1], fused[0], ref[1], ref[0])
+        assert np.asarray(fused[1]).max() < 1000
+
+    @pytest.mark.parametrize("backend", ("fused", "reference"))
+    def test_mask_as_the_lane_row_placement_builds(self, backend):
+        U, V = _int_factors(50, self.N)
+        u_idx = np.arange(3, dtype=np.int32)
+        mask = np.random.default_rng(1).random(self.N) < 0.3
+        row = score_kernel.item_mask_row(mask)
+        assert row.shape == (1, self.N) and row.dtype == np.int32
+        a = gather_score_topk(U, V, u_idx, 7, item_mask=mask, backend=backend)
+        b = gather_score_topk(U, V, u_idx, 7, item_mask=row, backend=backend)
+        _assert_identical(a, b)
+
+    def test_mask_of_another_form_is_refused(self):
+        U, V = _int_factors(50, self.N)
+        u_idx = np.arange(3, dtype=np.int32)
+        with pytest.raises(ValueError, match="item_mask"):
+            gather_score_topk(
+                U, V, u_idx, 5, backend="fused",
+                item_mask=np.zeros((1, self.N), bool))
 
 
 class TestBackendResolution:

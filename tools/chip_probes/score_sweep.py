@@ -8,7 +8,13 @@ from the seed, no server, no batcher) and times the bare compiled program:
 
 * fused, rungs x k in {1, 10, 100} — time that scales with k and not with
   the rung is the trips; time that scales with neither is the block sweep;
-* fused at other `block_items` (k = 100), for the per-grid-step cost;
+* fused at other `block_items` (k = 100), for the per-grid-step cost, at
+  every rung of `--block-rungs`; and the rung-1 user repeated to 8 and to 16
+  rows at each width (`tile_rows`: what a 1-row request costs as a whole
+  sublane tile — ISSUE 30's row floor is decided from these rows);
+* where the tree's kernel takes the mask as a lane row (PR 30 on), the
+  program with the row built once against the program that converts a bool
+  mask every dispatch (`mask_row`: `hoisted_ms` / `converted_ms`);
 * the `reference` backend (XLA gather, matmul, `lax.top_k`) at k = 100, at
   every rung whose program fits beside `--resident-gb` of live tables (the
   deployment holds both tables twice: 11.69 GB).
@@ -56,7 +62,11 @@ def main() -> int:
                     help="the parent's kernel does not compile at k = 1: give it 2")
     ap.add_argument("--blocks", default="",
                     help="other block_items to time at k = max_k")
+    ap.add_argument("--block-rungs", default="",
+                    help="rungs timed at every block (default: --k-rungs)")
     ap.add_argument("--reference", type=int, default=1)
+    ap.add_argument("--reference-rungs", default="",
+                    help="rungs the reference backend runs (default: all)")
     ap.add_argument("--resident-gb", type=float, default=11.69)
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
@@ -76,6 +86,9 @@ def main() -> int:
     n_pad = score_kernel.pad_block_items(n_i)
     has_stats = "with_stats" in inspect.signature(
         score_kernel.fused_gather_score_topk).parameters
+    # PR 30 on: the tile is sized by the kernel (any block width runs over
+    # the table as it is padded, and the mask may come as a lane row)
+    geometry = getattr(score_kernel, "tile_geometry", None)
 
     ku, kv = jax.random.split(jax.random.PRNGKey(args.seed % (2**31)))
     std = (1.0 / rank) ** 0.5
@@ -138,7 +151,11 @@ def main() -> int:
            "device": str(dev.device_kind), "platform": dev.platform,
            "items_padded": n_pad, "rank": rank, "n": args.n,
            "block_items": score_kernel.BLOCK_I, "with_stats": has_stats,
-           "fused": [], "blocks": [], "reference": []}
+           "geometry": {
+               str(b): list(geometry(b, rank, jnp.float32, n_pad))
+               for b in rungs} if geometry else None,
+           "fused": [], "blocks": [], "tile_rows": [], "mask_row": [],
+           "reference": []}
 
     def note(kind, row):
         out[kind].append(row)
@@ -151,23 +168,54 @@ def main() -> int:
             note("fused", {"rung": b, "k": k,
                            **timed(fused(k), users[b], args.n)})
     blocks = [int(x) for x in args.blocks.split(",") if x]
+    block_rungs = sorted(
+        {int(r) for r in args.block_rungs.split(",") if r} or k_rungs)
     if blocks:
-        # the table's padding is a multiple of BLOCK_I only (11,133 blocks,
-        # an odd number): pad a copy to the widest block, the rest excluded
-        wide = -(-n_pad // max(blocks)) * max(blocks)
-        Vw = jnp.pad(V, ((0, wide - n_pad), (0, 0)))
-        mask_w = jnp.arange(wide) >= n_i
+        if geometry:
+            Vw, mask_w = V, pad_mask
+        else:
+            # this tree's kernel needs a block that divides the table, and
+            # the table's padding is a multiple of BLOCK_I only (11,133
+            # blocks, an odd number): pad a copy to the widest block, the
+            # rest excluded
+            wide = -(-n_pad // max(blocks)) * max(blocks)
+            Vw = jnp.pad(V, ((0, wide - n_pad), (0, 0)))
+            mask_w = jnp.arange(wide) >= n_i
+
+        def at_block(kind, row, u_idx):
+            try:
+                row.update(timed(fused(max_k, row["block_items"]), u_idx,
+                                 args.n, Vw, mask_w))
+            except Exception as e:  # Mosaic may refuse a tile this wide
+                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            note(kind, row)
+
         for block in blocks:
-            for b in sorted(k_rungs & set(rungs)):
-                row = {"rung": b, "k": max_k, "block_items": block,
-                       "items_padded": wide}
-                try:
-                    row.update(timed(fused(max_k, block), users[b], args.n,
-                                     Vw, mask_w))
-                except Exception as e:  # Mosaic may refuse a tile this wide
-                    row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
-                note("blocks", row)
+            for b in block_rungs:
+                if b in users:
+                    at_block("blocks", {
+                        "rung": b, "k": max_k, "block_items": block,
+                        "items_padded": int(Vw.shape[0])}, users[b])
+            if 1 in users:
+                # the one-row request as 8 and as 16 equal rows: a repeated
+                # row adds no merge pass, so this is the tile's cost alone
+                for rows in (8, 16):
+                    at_block("tile_rows", {
+                        "rung": 1, "tile_rows": rows, "k": max_k,
+                        "block_items": block}, jnp.tile(users[1], rows))
         del Vw, mask_w
+    if geometry:
+        mask_row = jax.block_until_ready(
+            jnp.asarray(score_kernel.item_mask_row(np.asarray(pad_mask))))
+        for b in rungs:
+            conv = timed(fused(max_k), users[b], args.n)
+            hoist = timed(fused(max_k), users[b], args.n, V, mask_row)
+            note("mask_row", {
+                "rung": b, "k": max_k,
+                "converted_ms": conv["piped_ms"],
+                "hoisted_ms": hoist["piped_ms"],
+                "same_answer": conv["digest"] == hoist["digest"]})
+        del mask_row
     if args.reference:
         # stand in for the rest of what a deployment keeps on the device
         live = 4.0 * rank * (n_u + n_pad)
@@ -175,7 +223,8 @@ def main() -> int:
         filler = jnp.zeros((fill // 4,), jnp.float32)
         jax.block_until_ready(filler)
         out["reference_beside_bytes"] = int(live + fill)
-        for b in rungs:
+        ref_rungs = [int(r) for r in args.reference_rungs.split(",") if r]
+        for b in ref_rungs or rungs:
             row = {"rung": b, "k": max_k}
             try:
                 row.update(timed(reference, users[b], max(2, args.n // 2)))
