@@ -86,23 +86,26 @@ def bridge_batcher(
             ),
             _fam(
                 "pio_batcher_window_wait_ms", "gauge",
-                "Mean window wait per batched query, milliseconds.",
+                "Mean wait per dispatch from the first row taken to the run's "
+                "start, milliseconds.",
                 [("", (), _num(s.get("avg_window_wait_ms")))],
             ),
             _fam(
-                "pio_batcher_ewma_gap_ms", "gauge",
-                "EWMA of inter-arrival gap driving the adaptive window.",
-                [("", (), _num(s.get("ewma_gap_ms")))],
-            ),
-            _fam(
                 "pio_batcher_ewma_run_ms", "gauge",
-                "EWMA of batch execution time driving the adaptive window.",
+                "EWMA of batch execution time (the slow-dispatch threshold "
+                "reads it).",
                 [("", (), _num(s.get("ewma_run_ms")))],
             ),
             _fam(
                 "pio_batcher_carried_rows_total", "counter",
                 "Rows the bucket cut left for a later dispatch.",
                 [("", (), _num(s.get("carried_rows")))],
+            ),
+            _fam(
+                "pio_batcher_joined_rows_total", "counter",
+                "Arrivals that found the device free but older rows waiting "
+                "and left in their dispatch instead of running inline.",
+                [("", (), _num(s.get("joined_rows")))],
             ),
             _fam(
                 "pio_batcher_rounded_up_batches_total", "counter",

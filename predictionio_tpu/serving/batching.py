@@ -11,19 +11,22 @@ coalesces a batch, routes it through ``Algorithm.batch_predict`` (which
 engines like ALS vectorize on device), and wakes each handler with its
 result.  Errors are delivered per-request.
 
-The accumulation window is ADAPTIVE, not a fixed sleep:
+There is NO accumulation window: a free device is never held.
 
-* TRICKLE BYPASS: a request arriving to an empty queue with no run in
-  flight executes inline on its own handler thread — zero added latency
-  over the unbatched path.  Batches form exactly when they can help:
-  while a run is in flight, arrivals queue up and dispatch together.
-* A request is only worth delaying by about the cost of one extra device
-  pass, so the wait budget is ``min(window_ms, EWMA(batch run time))`` —
-  where a pass is fast the window collapses toward zero, where a pass
-  takes milliseconds it opens up to the cap.
-* Within the budget the worker stops as soon as the arrival stream goes
-  quiet: it waits for the next item at most ``EWMA(inter-arrival gap) ×
-  GAP_MULT`` past the last arrival (burst over ⇒ dispatch now).
+* TRICKLE BYPASS: a request that finds nothing waiting anywhere (queued,
+  carried by the cut, or in the worker's hand) and no run in flight
+  executes inline on its own handler thread — zero added latency over
+  the unbatched path.  Batches form exactly when they can help: while a
+  run is in flight, arrivals queue up and dispatch together.
+* THE WORKER WAITS FOR THE RUN IN FLIGHT, never for a clock.  With a
+  first row in hand it takes ``_busy`` — which blocks exactly while a run
+  is in flight, the only time waiting is free — drains what queued up
+  meanwhile and runs.  With the device free that is immediate: a row's
+  wait is the rest of the run in flight, the hand-off, its own run.
+* A NEWCOMER JOINS THE ROWS THAT WAIT.  One that finds the device free
+  but older rows waiting (the instant of a hand-off) queues behind them
+  and leaves in their dispatch (``joined_rows``): no dispatch runs while
+  an older row waits outside it that its rung had room for (FIFO).
 * THE CUT is decided from the batcher's own run times.  Rows in hand
   that fall between two rungs of the compile-cache ladder
   (``serving/fastpath.py``) either all run now, one dispatch rounded up to
@@ -101,10 +104,7 @@ class _Pending:
 
 
 class MicroBatcher:
-    # dispatch when the stream has been quiet for GAP_MULT × the EWMA
-    # inter-arrival gap (the burst is over; waiting longer is pure latency)
-    GAP_MULT = 2.0
-    # EWMA smoothing for both the gap and run-time estimators
+    # EWMA smoothing of the run-time estimator
     ALPHA = 0.2
     # dispatch records kept for GET /trace/dispatches.json (at 4 dispatches
     # a second, a minute), and the slow ones ordinary traffic never evicts
@@ -125,23 +125,26 @@ class MicroBatcher:
         self,
         run_batch: Callable[[list], list],
         max_batch: int = 64,
-        window_ms: float = 2.0,
         buckets=_DEFAULT_BUCKETS,
     ):
         self._run_batch = run_batch
         self.max_batch = max_batch
-        self.window_s = window_ms / 1e3
         self.buckets = tuple(
             sorted({b for b in buckets if b <= max_batch} | {max_batch})
         )
         self._queue: "queue.Queue[_Pending]" = queue.Queue()
         self._carry: collections.deque[_Pending] = collections.deque()
         self._stop = threading.Event()
-        # arrival-side estimator state
+        # rows submitted and not yet in a dispatch: queued, carried by the
+        # cut, or in the worker's hand.  Counted from the put on, so a row
+        # the worker has just taken is never overtaken.  _arr_lock guards
+        # the count with the put and the drain; it is taken after _busy,
+        # never before it
         self._arr_lock = threading.Lock()
-        self._last_arrival: Optional[float] = None
-        self._ewma_gap = self.window_s  # pessimistic until traffic teaches it
-        # worth-waiting budget: ~one batch run; 0 until the first run returns
+        self._waiting = 0
+        self._n_joined = 0  # arrivals that found _busy free, rows waiting
+        # what a run has been taking (the slow-run threshold reads it); 0
+        # until the first run returns
         self._ewma_run = 0.0
         # held for the duration of every batch run (worker or inline)
         self._busy = threading.Lock()
@@ -169,9 +172,6 @@ class MicroBatcher:
             maxlen=self.SLOW_RING
         )
         self._current: Optional[_tracing.Dispatch] = None  # holds _busy now
-        # rows the worker has taken off the queue into the batch it is
-        # forming: waiting like the queued ones, but in no queue
-        self._in_hand: list = []
         self._prev_dc_end: Optional[float] = None
         self._prev_left_work = False
         self._carried_rows = 0
@@ -225,13 +225,6 @@ class MicroBatcher:
         leader slot, or one tenant's answer leaks to the other.
         """
         now = time.perf_counter()
-        with self._arr_lock:
-            if self._last_arrival is not None:
-                # clamp: an idle night must not blow the estimator past any
-                # useful scale — one window of silence already means "quiet"
-                gap = min(now - self._last_arrival, self.window_s)
-                self._ewma_gap += self.ALPHA * (gap - self._ewma_gap)
-            self._last_arrival = now
         eff = Deadline.min(deadline, Deadline.after_ms(timeout * 1e3))
         active = _tracing.active_traces()
         p = _Pending(
@@ -269,25 +262,28 @@ class MicroBatcher:
                 if p.error is not None:
                     raise p.error
                 return p.result
-        # TRICKLE BYPASS: nothing queued and no run in flight — execute the
-        # singleton inline on this handler thread.  A lone request then pays
-        # exactly the direct-path cost (no worker hop, no window), while
-        # coalescing still happens whenever a run IS in flight: arrivals
-        # pile into the queue and the worker drains them as one batch.
-        if (
-            self._queue.empty()
-            and not self._carry
-            and self._busy.acquire(blocking=False)
-        ):
-            try:
+        # TRICKLE BYPASS: nothing waits anywhere and no run is in flight —
+        # execute the singleton inline on this handler thread.  A lone
+        # request then pays exactly the direct-path cost (no worker hop),
+        # while coalescing still happens whenever a run IS in flight:
+        # arrivals pile into the queue and the worker drains them as one
+        # batch.  With older rows waiting the free device is theirs: this
+        # one queues behind them while it still holds _busy, so the worker
+        # (which takes _busy before it drains) finds it in their dispatch.
+        free = self._busy.acquire(blocking=False)
+        try:
+            with self._arr_lock:
+                inline = free and self._waiting == 0
+                if not inline:
+                    self._n_joined += free
+                    self._waiting += 1
+                    self._queue.put(p)
+            if inline:
                 self._execute([p], waited=0.0, inline=True)
-            finally:
+        finally:
+            if free:
                 self._busy.release()
-            if p.error is not None:
-                raise p.error
-            return p.result
-        self._queue.put(p)
-        if not p.event.wait(eff.remaining_s()):
+        if not inline and not p.event.wait(eff.remaining_s()):
             # the pending stays queued, but its deadline has passed — the
             # worker is GUARANTEED to drop it at dispatch (same monotonic
             # clock), so the device never runs an abandoned query
@@ -299,21 +295,25 @@ class MicroBatcher:
     def stop(self) -> None:
         self._stop.set()
         self._worker.join(timeout=5)
-        # wake anything still queued so handlers fail fast, not on timeout
-        pending = list(self._carry)
-        self._carry.clear()
-        while True:
-            try:
-                pending.append(self._queue.get_nowait())
-            except queue.Empty:
-                break
+        # wake anything still waiting so handlers fail fast, not on
+        # timeout (the worker has put back what it had in hand)
+        with self._arr_lock:
+            pending = list(self._carry)
+            self._carry.clear()
+            while True:
+                try:
+                    pending.append(self._queue.get_nowait())
+                except queue.Empty:
+                    break
+            self._waiting = 0
         err = RuntimeError("server shutting down")
         for p in pending:
             self._resolve(p, error=err)
 
     def depth(self) -> int:
-        """Queued + carried pendings (admission-control signal)."""
-        return self._queue.qsize() + len(self._carry)
+        """Rows waiting for a dispatch: queued, carried, or in the worker's
+        hand (admission-control signal)."""
+        return self._waiting
 
     def stats(self) -> dict:
         """Per-batch latency/size/occupancy counters (``GET /`` stats)."""
@@ -337,10 +337,12 @@ class MicroBatcher:
                 "avg_window_wait_ms": round(self._wait_s_total / n_b * 1e3, 4)
                 if n_b
                 else None,
-                "ewma_gap_ms": round(self._ewma_gap * 1e3, 4),
                 "ewma_run_ms": round(self._ewma_run * 1e3, 4),
                 # monotone sums over the dispatch records
                 "carried_rows": self._carried_rows,
+                # arrivals that found the device free but older rows
+                # waiting, and left in their dispatch instead of inline
+                "joined_rows": self._n_joined,
                 # dispatches that ran short of their rung, the rows they
                 # were short by, and the estimates the cut decides from
                 "rounded_up_batches": self._n_rounded_up,
@@ -391,14 +393,15 @@ class MicroBatcher:
         }
 
     # -- worker -------------------------------------------------------------
-    def _next(self, timeout: Optional[float]) -> Optional[_Pending]:
-        """Carried tail first (FIFO), then the live queue."""
+    def _next(self, idle_s: float = 0.0) -> Optional[_Pending]:
+        """Carried tail first (FIFO), then the live queue, for which an
+        empty-handed worker waits up to ``idle_s``."""
         if self._carry:
             return self._carry.popleft()
         try:
-            if timeout is None or timeout <= 0:
+            if idle_s <= 0:
                 return self._queue.get_nowait()
-            return self._queue.get(timeout=timeout)
+            return self._queue.get(timeout=idle_s)
         except queue.Empty:
             return None
 
@@ -459,49 +462,41 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            first = self._next(timeout=0.1)
+            first = self._next(idle_s=0.1)
             if first is None:
                 continue
             t_first = time.perf_counter()
-            # on the profiler's clock: first row taken -> _busy held
-            collect = _tracing.annotation("pio.collect")
-            collect.__enter__()
-            last_arrival = t_first
-            batch = self._in_hand = [first]
-            # budget: delaying a request more than one device pass costs
-            # more latency than the coalescing saves
-            budget = min(self.window_s, self._ewma_run)
-            deadline = t_first + budget
-            while len(batch) < self.max_batch:
-                now = time.perf_counter()
-                # stop early once the arrival stream has gone quiet
-                quiet_cut = last_arrival + self._ewma_gap * self.GAP_MULT
-                wait = min(deadline, quiet_cut) - now
-                if wait <= 0:
-                    break
-                nxt = self._next(timeout=wait)
-                if nxt is None:
-                    break
-                batch.append(nxt)
-                last_arrival = time.perf_counter()
-            # serialize with any inline run, THEN drain: everything that
-            # arrived while the previous run was in flight coalesces here
-            with self._busy:
-                collect.__exit__(None, None, None)
-                while len(batch) < self.max_batch:
-                    nxt = self._next(timeout=None)
-                    if nxt is None:
-                        break
-                    batch.append(nxt)
-                # run them all, rounded up to the next rung, or cut at the
-                # rung below: the tail then leads the next batch
-                size = self._cut(len(batch))
-                carried = len(batch) - size
-                self._carry.extendleft(reversed(batch[size:]))
-                batch = batch[:size]
+            batch = [first]
+            # a run in flight (inline: the worker's own have returned) is
+            # all the worker waits for, and arrivals pile up behind it;
+            # with the device free this is immediate.  The timeout is
+            # stop()'s, which fails the rows put back.  On the profiler's
+            # clock: first row taken -> _busy held
+            with _tracing.annotation("pio.collect"):
+                while not self._busy.acquire(timeout=0.1):
+                    if self._stop.is_set():
+                        self._carry.extendleft(reversed(batch))
+                        return
+            try:
+                # against the arrivals' decision: a row is either drained
+                # here or put once this run is in flight
+                with self._arr_lock:
+                    while len(batch) < self.max_batch:
+                        nxt = self._next()
+                        if nxt is None:
+                            break
+                        batch.append(nxt)
+                    # run them all, rounded up to the next rung, or cut at
+                    # the rung below: the tail then leads the next batch
+                    size = self._cut(len(batch))
+                    carried = len(batch) - size
+                    self._carry.extendleft(reversed(batch[size:]))
+                    batch = batch[:size]
+                    self._waiting -= size
                 waited = time.perf_counter() - t_first
-                self._in_hand = []
                 self._execute(batch, waited, carried=carried)
+            finally:
+                self._busy.release()
 
     def _resolve(
         self,
@@ -614,8 +609,8 @@ class MicroBatcher:
             return
         t_run = time.perf_counter()
         seq = self._seq = self._seq + 1
-        # collect: first row taken -> the run starts (the window, the wait
-        # for _busy, the drain and the cut)
+        # collect: first row taken -> the run starts (the wait for _busy,
+        # the drain and the cut)
         threshold = max(self.SLOW_FLOOR_S, self.SLOW_MULT * self._ewma_run)
         # the rung these rows round up to (after the deadline drop)
         rung = self._rung_of(len(batch))
@@ -627,8 +622,8 @@ class MicroBatcher:
         traces = [p.trace for p in batch if p.trace is not None]
         for p in batch:
             if p.trace is not None:
-                # time between enqueue and dispatch: the coalescing window
-                # the request paid for (≈0 on the inline bypass)
+                # time between enqueue and dispatch: the run in flight it
+                # waited out (≈0 on the inline bypass)
                 p.trace.add_stage("queue_wait", t_run - p.t_enq)
                 # flight-recorder context: how this request's batch formed,
                 # which dispatch ran it and how many it waited through (1
@@ -672,10 +667,6 @@ class MicroBatcher:
         rec.stages["postprocess"] += rest
         for t in traces:
             t.add_stage("postprocess", rest)
-        # both the worker thread and the trickle bypass land here; the
-        # estimator shares _arr_lock with the gap EWMA
-        with self._arr_lock:
-            self._ewma_run += self.ALPHA * (run_dt - self._ewma_run)
         with _tracing.annotation("pio.resolve", seq=seq):
             for i, p in enumerate(batch):
                 if run_error is not None:
@@ -686,10 +677,11 @@ class MicroBatcher:
         rec.stages["resolve"] = t_done - t_end
         rec.wall_s = rec.stages["collect"] + (t_done - t_run)
         # rows waiting as this run ends: queued, carried, or (behind an
-        # inline run) already in the worker's hands
-        rec.depth_end = self.depth() + len(self._in_hand)
+        # inline run) already in the worker's hand
+        rec.depth_end = self.depth()
         slow = run_dt > threshold
         with self._stats_lock:
+            self._ewma_run += self.ALPHA * (run_dt - self._ewma_run)
             self._n_batches += 1
             self._n_queries += len(batch)
             self._size_hist[len(batch)] += 1

@@ -164,7 +164,6 @@ class QueryServer:
         plugins: Optional[list[EngineServerPlugin]] = None,
         batching: bool = False,
         max_batch: int = 64,
-        batch_window_ms: float = 2.0,
         max_inflight: int = 256,
         shed_retry_after_s: float = 1.0,
         default_deadline_ms: Optional[float] = None,
@@ -312,8 +311,7 @@ class QueryServer:
                 self._deployed.algorithms if self._deployed else (),
                 fastpath.BUCKETS)
             self._batcher = MicroBatcher(
-                self._run_query_batch, max_batch=max_batch,
-                window_ms=batch_window_ms, buckets=buckets,
+                self._run_query_batch, max_batch=max_batch, buckets=buckets,
             )
         if self.telemetry is not None:
             self._register_metrics()

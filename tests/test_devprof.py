@@ -256,7 +256,7 @@ class TestDeviceChargedOncePerDispatch:
                 time.sleep(0.001)
             return [f"r:{q}" for q in queries]
 
-        mb = MicroBatcher(run_batch, max_batch=4, window_ms=1.0)
+        mb = MicroBatcher(run_batch, max_batch=4)
         tracer = Tracer(sample_rate=1.0, slow_quantile=0.0)
         results = {}
 
@@ -317,7 +317,7 @@ class TestDeviceChargedOncePerDispatch:
             assert release.wait(5.0)
             return list(queries)
 
-        mb = MicroBatcher(run_batch, max_batch=4, window_ms=1.0)
+        mb = MicroBatcher(run_batch, max_batch=4)
         try:
             threads = [
                 threading.Thread(
@@ -367,7 +367,7 @@ class TestDeviceChargedOncePerDispatch:
                     time.sleep(0.001)
             return [f"r:{q}" for q in queries]
 
-        mb = MicroBatcher(run_batch, max_batch=4, window_ms=1.0)
+        mb = MicroBatcher(run_batch, max_batch=4)
         tracer = Tracer(sample_rate=1.0, slow_quantile=0.0)
         results = {}
 

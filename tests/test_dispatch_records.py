@@ -8,6 +8,7 @@ import glob
 import inspect
 import json
 import os
+import sys
 import threading
 import time
 import types
@@ -107,7 +108,7 @@ def held_then(mb, runs, first, waiting):
     assert runs.entered.wait(WAIT_S)
     for n, q in enumerate(waiting, start=1):
         submitted.append(submit_traced(mb, q))
-        wait_until(lambda: len(mb._in_hand) + mb.depth() == n, f"{q} queued")
+        wait_until(lambda: mb.depth() == n, f"{q} queued")
     runs.gate.set()
     for th, _ in submitted:
         th.join(WAIT_S)
@@ -140,7 +141,7 @@ def three_dispatches(clock):
     """
     runs = Runs(clock, h2d_s=0.003, d2h_s=0.001, post_s=0.002,
                 device_s_by_rung={1: 0.250, 2: 0.250, 4: 1.000})
-    mb = MicroBatcher(runs, max_batch=4, window_ms=2.0, buckets=(1, 2, 4))
+    mb = MicroBatcher(runs, max_batch=4, buckets=(1, 2, 4))
     # one earlier run at rungs 2 and 4, as the batcher would have kept it
     for rung, run_s in ((2, 0.256), (4, 1.006)):
         mb._rung_runs[rung].append((0, run_s))
@@ -370,12 +371,12 @@ def test_a_row_whose_deadline_lapsed_is_dropped_from_a_rounded_up_batch(
         th_a, _ = submit_traced(mb, "A")
         assert runs.entered.wait(WAIT_S)
         th_b, _ = submit_traced(mb, "B")
-        wait_until(lambda: len(mb._in_hand) + mb.depth() == 1, "B queued")
+        wait_until(lambda: mb.depth() == 1, "B queued")
         th_c = threading.Thread(target=impatient, daemon=True)
         th_c.start()
-        wait_until(lambda: len(mb._in_hand) + mb.depth() == 2, "C queued")
+        wait_until(lambda: mb.depth() == 2, "C queued")
         th_d, _ = submit_traced(mb, "D")
-        wait_until(lambda: len(mb._in_hand) + mb.depth() == 3, "D queued")
+        wait_until(lambda: mb.depth() == 3, "D queued")
         th_c.join(WAIT_S)  # C gives up while A still holds the batcher
         assert isinstance(outcome["C"], DeadlineExceeded)
         runs.gate.set()
@@ -411,6 +412,256 @@ def test_the_batchers_new_counters_reach_the_registry_under_the_contract():
     assert "pio_batcher_padded_rows_total 11" in text
     assert 'pio_batcher_rung_run_ms{rung="8"} 9.7' in text
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    report = analysis.run(root, analyzers=["metrics"])
+    assert [f for f in report.findings
+            if f.symbol.startswith("pio_batcher")] == []
+
+
+# -- a free device is never held (ISSUE 33) -----------------------------------------
+
+
+class HandOff:
+    """``_busy`` with the instant of a hand-off under the test's hand: the
+    worker (the one blocking acquirer) stands at ``gate`` with its rows in
+    hand before it may take the lock; arrivals' non-blocking tries pass."""
+
+    def __init__(self, lock):
+        self.lock = lock
+        self.gate = threading.Event()
+        self.gate.set()
+        self.worker_waits = threading.Event()
+
+    def acquire(self, blocking=True, timeout=-1):
+        if blocking:
+            self.worker_waits.set()
+            assert self.gate.wait(WAIT_S)
+        return self.lock.acquire(blocking, timeout)
+
+    def release(self):
+        self.lock.release()
+
+    def locked(self):
+        return self.lock.locked()
+
+
+def test_rows_in_hand_run_with_no_timed_wait_while_the_device_is_free(clock):
+    """A held; B, C, D queue behind it; the cut runs B + C and carries D.
+    D finds the device free in the worker's hand: its dispatch starts at
+    once (``collect`` 0 on a clock that moves inside runs only, where B +
+    C's is the run they waited out), and at no time did the worker sit
+    out a ``queue.get`` timeout with a row waiting."""
+    runs = Runs(clock, h2d_s=0.003, d2h_s=0.001, post_s=0.002,
+                device_s_by_rung={1: 0.250, 2: 0.250, 4: 1.000})
+    mb = MicroBatcher(runs, max_batch=4, buckets=(1, 2, 4))
+    for rung, run_s in ((2, 0.256), (4, 1.006)):
+        mb._rung_runs[rung].append((0, run_s))
+    real_get, sat_out = mb._queue.get, []
+
+    def get(block=True, timeout=None):
+        waiting = mb.depth()
+        try:
+            return real_get(block, timeout)
+        except batching.queue.Empty:
+            if block and timeout and waiting:
+                sat_out.append((timeout, waiting))
+            raise
+
+    mb._queue.get = get
+    try:
+        held_then(mb, runs, "A", "BCD")
+        wait_until(lambda: mb.stats()["batches"] == 3, "three dispatches")
+        assert runs.batches == [["A"], ["B", "C"], ["D"]]
+        assert sat_out == []
+        recs = {r["seq"]: r for r in mb.dispatches()["dispatches"]}
+        assert recs[2]["stagesMs"]["collect"] == pytest.approx(256.0, abs=1e-6)
+        assert recs[3]["stagesMs"]["collect"] == 0.0
+        # first row taken -> run starts, a mean over the three dispatches
+        assert mb.stats()["avg_window_wait_ms"] == pytest.approx(
+            256.0 / 3, abs=1e-3)
+        assert mb.stats()["joined_rows"] == 0  # a run was in flight for all
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_a_newcomer_joins_the_rows_in_the_workers_hand(clock):
+    """B waited out A's run in the worker's hand; A has returned, the
+    device is free and the worker has not yet taken it.  N arrives in that
+    instant: it leaves in B's dispatch, behind B, not inline past it."""
+    runs = Runs(clock, device_s_by_rung={1: 0.010, 8: 0.010})
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    hand_off = mb._busy = HandOff(mb._busy)
+    try:
+        hand_off.gate.clear()
+        runs.gate.clear()
+        th_a, _ = submit_traced(mb, "A")
+        assert runs.entered.wait(WAIT_S)
+        th_b, tr_b = submit_traced(mb, "B")
+        assert hand_off.worker_waits.wait(WAIT_S)  # B is in its hand
+        runs.gate.set()
+        th_a.join(WAIT_S)
+        assert not th_a.is_alive() and not hand_off.locked()
+        assert mb.depth() == 1
+        th_n, tr_n = submit_traced(mb, "N")
+        wait_until(lambda: mb.depth() == 2, "N queued behind B")
+        assert runs.batches == [["A"]]  # nobody took the free device
+        hand_off.gate.set()
+        for th in (th_b, th_n):
+            th.join(WAIT_S)
+            assert not th.is_alive()
+        assert runs.batches == [["A"], ["B", "N"]]
+        s = mb.stats()
+        assert s["joined_rows"] == 1
+        assert (s["batches"], s["inline_batches"], s["depth"]) == (2, 1, 0)
+        for tr in (tr_b, tr_n):
+            meta = tr.to_dict()["meta"]
+            assert (meta["dispatch"], meta["dispatch_seq"]) == ("window", 2)
+    finally:
+        hand_off.gate.set()
+        runs.gate.set()
+        mb.stop()
+
+
+def test_an_arrival_during_a_run_is_not_counted_as_joined(clock):
+    runs = Runs(clock, device_s_by_rung={1: 0.010, 8: 0.010})
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    try:
+        held_then(mb, runs, "A", "BC")
+        mb.submit("lone")  # free device, nothing waiting: inline
+        s = mb.stats()
+        assert s["joined_rows"] == 0 and s["inline_batches"] == 2
+        assert "ewma_gap_ms" not in s
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_stop_fails_every_waiting_row_fast_the_one_in_hand_too(clock):
+    runs = Runs(clock, device_s=0.010)
+    mb = MicroBatcher(runs, buckets=(1, 8))
+    outcomes = {}
+
+    def ask(q):
+        try:
+            outcomes[q] = mb.submit(q)
+        except RuntimeError as e:
+            outcomes[q] = e
+
+    try:
+        runs.gate.clear()
+        threads = {q: threading.Thread(target=ask, args=(q,), daemon=True)
+                   for q in "ABCD"}
+        threads["A"].start()
+        assert runs.entered.wait(WAIT_S)
+        for n, q in enumerate("BCD", start=1):
+            threads[q].start()
+            wait_until(lambda: mb.depth() == n, f"{q} queued")
+        t0 = time.monotonic()
+        mb.stop()  # A still holds the batcher; B is in the worker's hand
+        for q in "BCD":
+            threads[q].join(WAIT_S)
+            assert not threads[q].is_alive()
+        assert time.monotonic() - t0 < 2.0
+        assert not mb._worker.is_alive() and mb.depth() == 0
+        for q in "BCD":
+            assert isinstance(outcomes[q], RuntimeError)
+            assert "shutting down" in str(outcomes[q])
+        runs.gate.set()
+        threads["A"].join(WAIT_S)
+        assert outcomes["A"] == ("answer", "A")
+        assert runs.batches == [["A"]]
+    finally:
+        runs.gate.set()
+        mb.stop()
+
+
+def test_under_contention_no_row_is_lost_doubled_or_overtaken_in_the_queue():
+    """More submitters than cores, a short switch interval: every query is
+    answered once with its own answer, the count of waiting rows returns
+    to zero (a lost update would leave it off), and the worker's rows run
+    in the order they were queued (a ladder of every count: no carry)."""
+    put_order, ran = [], []
+    lock = threading.Lock()
+
+    def run(queries):
+        if threading.current_thread().name == "query-microbatcher":
+            ran.extend(queries)
+        time.sleep(0.0005)
+        return [q * 2 for q in queries]
+
+    mb = MicroBatcher(run, max_batch=16, buckets=tuple(range(1, 17)))
+    real_put = mb._queue.put
+
+    def put(p, *a, **kw):  # called under the batcher's arrival lock
+        put_order.append(p.query)
+        return real_put(p, *a, **kw)
+
+    mb._queue.put = put
+    n_threads, per_thread = 24, 40
+    results = {}
+
+    def client(t):
+        for i in range(per_thread):
+            q = t * per_thread + i
+            r = mb.submit(q)
+            with lock:
+                results[q] = r
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(t,), daemon=True)
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+        mb.stop()
+    n = n_threads * per_thread
+    assert results == {q: q * 2 for q in range(n)}
+    s = mb.stats()
+    assert s["queries"] == n and s["depth"] == 0 and s["carried_rows"] == 0
+    assert ran == put_order
+    assert s["batches"] - s["inline_batches"] <= len(put_order)
+
+
+@pytest.mark.parametrize("cls, gone", [
+    ("MicroBatcher", "window_ms"), ("QueryServer", "batch_window_ms")])
+def test_the_window_is_no_longer_an_argument(cls, gone):
+    from predictionio_tpu.serving import query_server
+
+    owner = {"MicroBatcher": MicroBatcher,
+             "QueryServer": query_server.QueryServer}[cls]
+    assert gone not in inspect.signature(owner.__init__).parameters
+    for name in ("GAP_MULT", "window_s"):
+        assert not hasattr(MicroBatcher, name)
+
+
+def test_joined_rows_reaches_the_registry_and_the_gap_gauge_has_left():
+    from predictionio_tpu import analysis
+    from predictionio_tpu.obs import bridges
+    from predictionio_tpu.obs.metrics import MetricsRegistry
+
+    mb = MicroBatcher(lambda qs: qs, buckets=(1, 8))
+    try:
+        mb.submit("q")
+        stats = mb.stats()
+    finally:
+        mb.stop()
+    assert stats["joined_rows"] == 0
+    reg = MetricsRegistry()
+    bridges.bridge_batcher(reg, lambda: dict(stats, joined_rows=7))
+    text = reg.render_prometheus()
+    assert "pio_batcher_joined_rows_total 7" in text
+    assert "ewma_gap" not in text
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for doc in ("docs/observability.md", "predictionio_tpu/obs/bridges.py"):
+        with open(os.path.join(root, doc)) as f:
+            body = f.read()
+        assert "joined_rows" in body and "ewma_gap" not in body
     report = analysis.run(root, analyzers=["metrics"])
     assert [f for f in report.findings
             if f.symbol.startswith("pio_batcher")] == []

@@ -225,8 +225,9 @@ _ROWS_RUN_MS = {b: float(b) for b in BUCKETS}
 
 class TestAdaptiveBatcher:
     def test_burst_coalesces(self):
-        """64 concurrent submitters with a real window must land in far
-        fewer than 64 batches, each cut at a ladder rung."""
+        """64 concurrent submitters must land in far fewer than 64
+        batches (they pile up behind the run in flight), each cut at a
+        ladder rung."""
         calls = []
         done = threading.Event()
 
@@ -236,7 +237,7 @@ class TestAdaptiveBatcher:
             calls.append(len(batch))
             return [q * 2 for q in batch]
 
-        mb = MicroBatcher(run, max_batch=64, window_ms=50.0)
+        mb = MicroBatcher(run, max_batch=64)
         try:
             results = [None] * 64
             threads = [
@@ -257,14 +258,14 @@ class TestAdaptiveBatcher:
             mb.stop()
 
     def test_trickle_dispatches_immediately(self):
-        """A lone request must not wait out the full window: the wait
-        budget is min(window, EWMA run time), which starts at zero."""
-        mb = MicroBatcher(lambda b: list(b), max_batch=64, window_ms=200.0)
+        """A lone request on a free device runs at once, inline."""
+        mb = MicroBatcher(lambda b: list(b), max_batch=64)
         try:
             t0 = time.perf_counter()
             mb.submit("x")
             dt = time.perf_counter() - t0
-            assert dt < 0.1  # far below the 200 ms cap
+            assert dt < 0.1
+            assert mb.stats()["inline_batches"] == 1
         finally:
             mb.stop()
 
@@ -306,7 +307,7 @@ class TestAdaptiveBatcher:
             clock[0] += run_ms[bucket_for(len(batch))] / 1e3
             return list(batch)
 
-        mb = MicroBatcher(run, max_batch=64, window_ms=20.0)
+        mb = MicroBatcher(run, max_batch=64)
         for rung, ms in run_ms.items():
             mb._rung_runs[rung].append((0, ms / 1e3))
         try:
@@ -322,8 +323,7 @@ class TestAdaptiveBatcher:
             for n, t in enumerate(threads[1:], start=1):
                 t.start()  # one at a time: queue order is submit order
                 deadline = time.time() + 5
-                while (mb.depth() + len(mb._in_hand) < n
-                       and time.time() < deadline):
+                while mb.depth() < n and time.time() < deadline:
                     time.sleep(0.0005)
             release.set()
             for t in threads:
@@ -346,7 +346,7 @@ class TestAdaptiveBatcher:
         def run(batch):
             raise RuntimeError("boom")
 
-        mb = MicroBatcher(run, max_batch=8, window_ms=5.0)
+        mb = MicroBatcher(run, max_batch=8)
         try:
             with pytest.raises(RuntimeError, match="boom"):
                 mb.submit("q")
@@ -354,7 +354,7 @@ class TestAdaptiveBatcher:
             mb.stop()
 
     def test_stats_counters(self):
-        mb = MicroBatcher(lambda b: list(b), max_batch=8, window_ms=1.0)
+        mb = MicroBatcher(lambda b: list(b), max_batch=8)
         try:
             for _ in range(3):
                 mb.submit("q")

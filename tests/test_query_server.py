@@ -238,7 +238,7 @@ class TestMicroBatching:
         )
         batched = QueryServer(
             trained["engine"], storage=trained["storage"], ctx=trained["ctx"],
-            batching=True, batch_window_ms=20,
+            batching=True,
         )
         # count device-batch invocations
         calls = []
@@ -321,7 +321,7 @@ class TestMicroBatching:
                 qs = QueryServer(
                     trained["engine"], storage=trained["storage"],
                     ctx=trained["ctx"], plugins=[recorder(tag)],
-                    batching=batching, batch_window_ms=5,
+                    batching=batching,
                 )
                 # make supplement observable: tag the query it returns
                 serving = qs._deployed.serving

@@ -96,6 +96,10 @@ def main() -> int:
                     delta(win, "batcher.carried_rows"), queries),
                 "rounded_up_batches": delta(win, "batcher.rounded_up_batches"),
                 "padded_rows": delta(win, "batcher.padded_rows"),
+                "joined_rows": delta(win, "batcher.joined_rows"),
+                "turnaround_ms": (
+                    delta(win, "batcher.turnaround_ms_sum") / n
+                    if (n := delta(win, "batcher.turnaround_n")) else None),
                 "dispatches_by_rung": delta(
                     win, "fastpath.bucket_hits", sub=True),
                 "backlog_mid": backlog_at(recs, seconds / 2),
