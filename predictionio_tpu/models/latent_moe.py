@@ -50,6 +50,10 @@ from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.latent_attention import mla_attention
 from predictionio_tpu.ops.topk import gather_score_topk, resolve_backend
 
+# what `PackedSequenceScorer.stats()["family"]` says of this module's models
+FAMILY = "latent_moe_sequence"
+
+
 @dataclasses.dataclass(frozen=True)
 class LatentMoEConfig:
     """The shape of the model, under the keys of the published config."""
@@ -318,20 +322,32 @@ def forward_packed(cfg: LatentMoEConfig, P: dict, tokens, positions,
     """
     x, picks, counts = trunk(cfg, P, tokens, positions, seg_start, valid,
                              interpret=interpret)
-    h_last = rms_norm(x[last_idx], P["final_norm"],
-                      cfg.rms_norm_eps).astype(P["head"].dtype)
+    res = score_head(P, cfg.vocab_size, cfg.rms_norm_eps, x[last_idx], k,
+                     interpret=interpret, score_backend=score_backend)
+    res.update(picks=picks, expert_counts=counts)
+    return res
+
+
+def score_head(P: dict, vocab_size: int, eps: float, x_last, k: int, *,
+               interpret: Optional[bool] = None,
+               score_backend: Optional[str] = None) -> dict:
+    """What every packed sequence family ends in: each row's last residual
+    state ``x_last`` (R, hidden) through the final norm, and its top-``k``
+    items by ``h_last @ head^T`` taken on the device.  Returns ``values``
+    and ``indices`` (R, k), ``h_last`` (R, hidden) in the head's dtype —
+    what the head scored — and, on the fused score backend, ``merge``."""
+    h_last = rms_norm(x_last, P["final_norm"], eps).astype(P["head"].dtype)
     # the score kernel's lane row, made in the form it reads
     pad_mask = (
         jnp.arange(P["head"].shape[0], dtype=jnp.int32)[None, :]
-        >= cfg.vocab_size
+        >= vocab_size
     ).astype(jnp.int32)
     be = resolve_backend(score_backend)
     outs = gather_score_topk(
-        h_last, P["head"], jnp.arange(last_idx.shape[0], dtype=jnp.int32), k,
+        h_last, P["head"], jnp.arange(x_last.shape[0], dtype=jnp.int32), k,
         item_mask=pad_mask, backend=be, interpret=interpret,
         with_stats=be == "fused")
-    res = {"values": outs[0], "indices": outs[1], "h_last": h_last,
-           "picks": picks, "expert_counts": counts}
+    res = {"values": outs[0], "indices": outs[1], "h_last": h_last}
     if be == "fused":
         res["merge"] = outs[2]
     return res
@@ -382,6 +398,42 @@ def forward_flat(cfg: LatentMoEConfig, P: dict, flat, t_pad: int, k: int,
                           flat[4 * t_pad:], k, **kw)
 
 
+class DispatchCounters:
+    """This family's own counters in the packed scorer (``serving/seqpath``
+    holds the lock): over the sparse layers of every dispatch, the experts
+    that received a token (their weights crossed HBM), the assignments, and
+    the busiest expert's load over the mean load."""
+
+    # outputs of the program fetched with every dispatch's answer
+    fetch = ("expert_counts",)
+
+    def __init__(self, config: LatentMoEConfig):
+        self.config = config
+        self.experts_touched = 0
+        self.expert_assignments = 0
+        self.load_max_over_mean_sum = 0.0
+        self.sparse_layer_dispatches = 0
+
+    def add(self, t_pad: int, n_rows: int, n_tokens: int, got: dict) -> None:
+        counts = got["expert_counts"]  # (sparse layers, experts)
+        live = counts.sum(axis=1) > 0
+        ratios = counts[live].max(axis=1) / counts[live].mean(axis=1)
+        self.experts_touched += int((counts > 0).sum())
+        self.expert_assignments += int(counts.sum())
+        self.load_max_over_mean_sum += float(ratios.sum())
+        self.sparse_layer_dispatches += int(live.sum())
+
+    def stats(self) -> dict:
+        return {
+            "sparse_layers": self.config.n_moe_layers,
+            "experts": self.config.n_routed_experts,
+            "experts_touched": self.experts_touched,
+            "expert_assignments": self.expert_assignments,
+            "load_max_over_mean_sum": round(self.load_max_over_mean_sum, 4),
+            "sparse_layer_dispatches": self.sparse_layer_dispatches,
+        }
+
+
 @dataclasses.dataclass
 class LatentMoEModel:
     """What the sequence template serves: the config, the parameter pytree
@@ -393,3 +445,8 @@ class LatentMoEModel:
     params: dict
     item_map: object
     histories: object = None
+
+
+# the names the sequence template and the packed scorer find a family's
+# parts under (models/gdn_hybrid.py has the same)
+Config, Model = LatentMoEConfig, LatentMoEModel

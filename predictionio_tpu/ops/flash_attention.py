@@ -366,3 +366,111 @@ def flash_attention(
     for _ in range(q.ndim - 2):
         fn = jax.vmap(fn)
     return fn(q, k, v)
+
+
+# -- packed histories (serving, forward only) ---------------------------------
+
+PACKED_SCOPE = "pio.packed_attention"
+PACKED_BLOCK = 256
+_LANES = 128
+
+
+def _packed_kernel(lo_ref, q_ref, start_ref, k_ref, v_ref, o_ref, acc_ref,
+                   m_ref, l_ref, *, scale: float, block: int):
+    qi = pl.program_id(1)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[...]
+    q_pos = qi * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 0)
+    q_start = start_ref[...][:, :1]  # (block, 1)
+
+    def step(kb, carry):
+        at = pl.multiple_of(kb * block, block)
+        k = k_ref[pl.ds(at, block), :]
+        v = v_ref[pl.ds(at, block), :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        k_pos = at + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        mask = (k_pos <= q_pos) & (k_pos >= q_start)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row with no key in this block keeps m at NEG_INF: exp(0) must
+        # not count its masked entries
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(lo_ref[qi], qi + 1, step, 0)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def packed_causal_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, seg_start: jax.Array, *,
+    scale: Optional[float] = None, block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Plain multi-head ``softmax(q.k * scale).v`` over several histories
+    PACKED into one token axis, each causal within itself (forward only).
+
+    ``q``/``k``/``v`` (H, T, d); ``seg_start[t]`` (T,) int32 is the index of
+    the first token of token ``t``'s history (histories contiguous, in
+    order; a padded token is a history of its own), so token ``t`` attends
+    to ``seg_start[t] <= s <= t``.  ``T`` must be a multiple of the block
+    (256, or ``T`` itself when shorter).  The layout of
+    ``ops/latent_attention.py``: grid ``(heads, q_blocks)``, a head's whole
+    K and V in VMEM while its query blocks sweep, the loop over key blocks
+    INSIDE the kernel from the block that holds the start of the query
+    block's first history to the diagonal — blocks above the diagonal or
+    wholly in other histories cost neither a grid step nor a DMA.
+    """
+    heads, t, d = q.shape
+    block = block or min(PACKED_BLOCK, t)
+    if t % block:
+        raise ValueError(f"{t} tokens are not a multiple of the block {block}")
+    scale = float(scale if scale is not None else 1.0 / (d ** 0.5))
+    interpret = pallas_mode.resolve("packed_attention", interpret)
+    # first key block each query block needs (starts never decrease)
+    lo = (seg_start[::block] // block).astype(jnp.int32)
+    start_lanes = jnp.broadcast_to(
+        seg_start.astype(jnp.int32)[:, None], (t, _LANES))
+
+    def per_q(h, qi, lo):
+        return (h, qi, 0)
+
+    def per_head(h, qi, lo):
+        return (h, 0, 0)
+
+    with jax.named_scope(PACKED_SCOPE):
+        return pl.pallas_call(
+            functools.partial(_packed_kernel, scale=scale, block=block),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(heads, t // block),
+                in_specs=[
+                    pl.BlockSpec((None, block, d), per_q),
+                    pl.BlockSpec((block, _LANES), lambda h, qi, lo: (qi, 0)),
+                    pl.BlockSpec((None, t, d), per_head),
+                    pl.BlockSpec((None, t, d), per_head),
+                ],
+                out_specs=pl.BlockSpec((None, block, d), per_q),
+                scratch_shapes=[
+                    pltpu.VMEM((block, d), jnp.float32),
+                    pltpu.VMEM((block, 1), jnp.float32),
+                    pltpu.VMEM((block, 1), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((heads, t, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=64 * 1024 * 1024,
+            ),
+            interpret=interpret,
+        )(lo, q, start_lanes, k, v)

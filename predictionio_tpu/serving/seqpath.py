@@ -11,6 +11,14 @@ pads to the next rung, rows pad to a fixed count (a padded row repeats row
 and run once before the scorer is handed out, so no request compiles; as
 with the bucketed scorer ``compile_count`` moves only then.
 
+ONE scorer for every packed sequence family.  The family is the module the
+model's config class lives in (``models/latent_moe``, ``models/gdn_hybrid``)
+and hands over what differs: ``FAMILY`` (its name in ``stats()``),
+``pack`` / ``flatten`` (the host side of a dispatch), ``forward_flat`` (the
+device program) and ``DispatchCounters`` (its own counters, and ``fetch``:
+the program's small outputs they are counted from).  The ladder, the
+compile-and-warm, the dispatch and the common counters are here, once.
+
 A dispatch is ``h2d`` (one small index array) → ``device_compute`` (the
 whole forward pass and the head's top-k, ``pio_seq_forward``) → ``d2h``
 (ONE ``device_get`` of the (rows, k) values and indices and the program's
@@ -21,13 +29,13 @@ the routing picks (:meth:`forward` returns them, for audits and tests).
 
 from __future__ import annotations
 
+import importlib
 import threading
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 
-from predictionio_tpu.models import latent_moe as _lm
 from predictionio_tpu.obs import tracing as _tracing
 from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.topk import resolve_backend
@@ -43,11 +51,13 @@ MAX_K = 100
 class PackedSequenceScorer:
     """AOT-compiled packed forward + top-k over a device-resident model."""
 
-    def __init__(self, config: _lm.LatentMoEConfig, params: dict, *,
+    def __init__(self, config, params: dict, *,
                  max_k: int = MAX_K, ladder: Sequence[int] = TOKEN_LADDER,
                  max_rows: int = MAX_ROWS, backend: Optional[str] = None,
                  device=None):
         self.config = config
+        # the model family: the module of the config it was handed
+        self._family = importlib.import_module(type(config).__module__)
         self.k = min(max_k, config.vocab_size)
         self.ladder = tuple(sorted({int(t) for t in ladder}))
         if config.max_len > self.ladder[-1]:
@@ -73,28 +83,24 @@ class PackedSequenceScorer:
         # (query, key) pairs attention must visit: sum of n(n+1)/2 over rows
         self.causal_pairs = 0
         self.merge_passes = 0
-        # over the sparse layers of every dispatch: experts that received a
-        # token (their weights crossed HBM), assignments, and the busiest
-        # expert's load over the mean load
-        self.experts_touched = 0
-        self.expert_assignments = 0
-        self.load_max_over_mean_sum = 0.0
-        self.sparse_layer_dispatches = 0
+        self._own = self._family.DispatchCounters(config)
         self._fns = {t: self._compile(t) for t in self.ladder}
         self._warm()
 
     # -- compile + warm --------------------------------------------------
     def _program(self, t: int):
         cfg, k, be = self.config, self.k, self.backend
+        forward_flat = self._family.forward_flat
 
         def pio_seq_forward(P, flat):
-            return _lm.forward_flat(cfg, P, flat, t, k, score_backend=be)
+            return forward_flat(cfg, P, flat, t, k, score_backend=be)
 
         return pio_seq_forward
 
     def _compile(self, t: int):
         """Lower + compile the ``t``-token program ahead of time."""
-        dummy = self._put(_lm.pack([np.zeros(1, np.int32)], t, self.max_rows))
+        dummy = self._put(self._family.pack(
+            [np.zeros(1, np.int32)], t, self.max_rows))
         compiled = (
             jax.jit(self._program(t))
             .lower(self._params, dummy)
@@ -106,13 +112,13 @@ class PackedSequenceScorer:
 
     def _warm(self) -> None:
         for t in self.ladder:
-            batch = self._put(
-                _lm.pack([np.zeros(1, np.int32)], t, self.max_rows))
+            batch = self._put(self._family.pack(
+                [np.zeros(1, np.int32)], t, self.max_rows))
             jax.block_until_ready(self._fns[t](self._params, batch))
             self.warmup_executions += 1
 
     def _put(self, batch: dict):
-        return jax.device_put(_lm.flatten(batch), self._device)
+        return jax.device_put(self._family.flatten(batch), self._device)
 
     # -- dispatch --------------------------------------------------------
     def _chunks(self, histories):
@@ -131,10 +137,12 @@ class PackedSequenceScorer:
 
     def forward(self, histories) -> dict:
         """One direct dispatch of ``histories`` (they must fit one), every
-        output of the program fetched — ``h_last`` and ``picks`` included —
+        output of the program fetched — ``h_last`` (and a family's own, such as
+        the routing picks) included —
         plus the ``batch`` layout.  For audits and tests; counts nothing."""
         n_tok = sum(len(h) for h in histories)
-        batch = _lm.pack(histories, self.rung_for(n_tok), self.max_rows)
+        batch = self._family.pack(
+            histories, self.rung_for(n_tok), self.max_rows)
         out = jax.device_get(self._fns[len(batch["tokens"])](
             self._params, self._put(batch)))
         out["batch"] = batch
@@ -156,13 +164,13 @@ class PackedSequenceScorer:
             if disp is not None:
                 disp.rung = t
             with _tracing.stage("batch_assembly"):
-                batch = _lm.pack(rows, t, self.max_rows)
+                batch = self._family.pack(rows, t, self.max_rows)
             with _tracing.stage("h2d"):
                 dev = self._put(batch)
             with _tracing.stage("device_compute"):
                 out = self._fns[t](self._params, dev)
                 small = {name: out[name] for name in
-                         ("values", "indices", "expert_counts", "merge")
+                         ("values", "indices", "merge") + self._own.fetch
                          if name in out}
                 # completion INSIDE the stage, as the bucketed scorer does:
                 # device time must not smear into the readback
@@ -175,19 +183,13 @@ class PackedSequenceScorer:
         return np.concatenate(idx_parts), np.concatenate(val_parts)
 
     def _count(self, t, rows, n_tok, got, disp) -> None:
-        counts = got["expert_counts"]  # (sparse layers, experts)
-        live = counts.sum(axis=1) > 0
-        ratios = counts[live].max(axis=1) / counts[live].mean(axis=1)
         with self._lock:
             self.hits[t] += 1
             self.queries += len(rows)
             self.tokens += n_tok
             self.padded_tokens += t - n_tok
             self.causal_pairs += sum(len(h) * (len(h) + 1) // 2 for h in rows)
-            self.experts_touched += int((counts > 0).sum())
-            self.expert_assignments += int(counts.sum())
-            self.load_max_over_mean_sum += float(ratios.sum())
-            self.sparse_layer_dispatches += int(live.sum())
+            self._own.add(t, len(rows), n_tok, got)
             if "merge" in got:
                 passes = int(got["merge"][0])
                 self.merge_passes += passes
@@ -200,7 +202,7 @@ class PackedSequenceScorer:
         head = self._params["head"]
         with self._lock:
             return {
-                "family": "latent_moe_sequence",
+                "family": self._family.FAMILY,
                 "token_ladder": list(self.ladder),
                 "max_rows": self.max_rows,
                 "top_k": self.k,
@@ -212,8 +214,6 @@ class PackedSequenceScorer:
                     head.shape[0],
                 ) if self.backend == "fused" else None,
                 "resident_bytes": self.resident_bytes,
-                "sparse_layers": self.config.n_moe_layers,
-                "experts": self.config.n_routed_experts,
                 "compile_count": self.compile_count,
                 "warmup_executions": self.warmup_executions,
                 "bucket_hits": {str(t): n for t, n in self.hits.items()},
@@ -223,9 +223,5 @@ class PackedSequenceScorer:
                 "padded_tokens": self.padded_tokens,
                 "causal_pairs": self.causal_pairs,
                 "merge_passes": self.merge_passes,
-                "experts_touched": self.experts_touched,
-                "expert_assignments": self.expert_assignments,
-                "load_max_over_mean_sum": round(
-                    self.load_max_over_mean_sum, 4),
-                "sparse_layer_dispatches": self.sparse_layer_dispatches,
+                **self._own.stats(),
             }

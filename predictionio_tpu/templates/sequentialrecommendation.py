@@ -7,15 +7,20 @@ history is read live from the event store (same pattern as the e-commerce
 template's serving-time lookups) so recommendations track events newer than
 the model.
 
-Two algorithms share the template and its one history seam
+Three algorithms share the template and its one history seam
 (:class:`EventStoreHistory` unless the model or the algorithm carries
 another provider): ``sasrec``, the small trained transformer, served one
-query at a time from the host; and ``latentmoe``
-(:class:`LatentMoEAlgorithm`), a latent-attention sparse-expert stack at
-published widths (:mod:`predictionio_tpu.models.latent_moe`) that serves
-through ``deploy --batching``: the batcher's rows are packed into one
-dispatch of a resident, ahead-of-time compiled device program
-(:mod:`predictionio_tpu.serving.seqpath`).
+query at a time from the host; and two packed sequence families at
+published widths that serve through ``deploy --batching`` — the batcher's
+rows are packed into one dispatch of a resident, ahead-of-time compiled
+device program (:mod:`predictionio_tpu.serving.seqpath`, ONE scorer class
+for both): ``latentmoe`` (:class:`LatentMoEAlgorithm`), a latent-attention
+sparse-expert stack (:mod:`predictionio_tpu.models.latent_moe`), and
+``gdnhybrid`` (:class:`GDNHybridAlgorithm`), gated-delta-rule
+linear-attention layers interleaved with full-attention layers
+(:mod:`predictionio_tpu.models.gdn_hybrid`).  What a packed family needs of
+an algorithm is :class:`PackedSequenceAlgorithm`'s; a family adds its
+model module's name.
 """
 
 from __future__ import annotations
@@ -210,11 +215,11 @@ class SASRecAlgorithm(_ServesHistories, Algorithm):
 
 
 @dataclasses.dataclass
-class LatentMoEParams(Params):
+class PackedSequenceParams(Params):
     appName: str = "default"
     eventNames: tuple = ("view", "buy", "rate")
-    # the model's shape under the keys of its published config.json
-    # (models/latent_moe.LatentMoEConfig.from_hf); vocab_size is the catalog
+    # the model's shape under the keys of its published config.json (the
+    # family's Config.from_hf); vocab_size is the catalog
     modelConfig: Optional[dict] = None
     maxLen: int = 2048
     seed: int = 0
@@ -227,15 +232,20 @@ class LatentMoEParams(Params):
     persistMode: str = "auto"
 
 
-class LatentMoEAlgorithm(_ServesHistories, Algorithm):
-    """The latent-attention sparse-expert recommender on the batched
-    serving path.  There is no trainer for this family yet: ``train``
-    returns SEEDED, untrained weights, and only for a model small enough to
-    be a test fixture; it refuses a published width rather than hand back
-    noise under a real model's name (the benchmark's family overrides it
-    knowingly)."""
+LatentMoEParams = PackedSequenceParams
 
-    params_cls = LatentMoEParams
+
+class PackedSequenceAlgorithm(_ServesHistories, Algorithm):
+    """A packed sequence family on the batched serving path: everything
+    but the model.  ``family`` names the module that has it (``Config``
+    with ``from_hf`` and ``param_count``, ``Model``, ``init_params``).
+    There is no trainer for these families yet: ``train`` returns SEEDED,
+    untrained weights, and only for a model small enough to be a test
+    fixture; it refuses a published width rather than hand back noise under
+    a real model's name (the benchmark's families override it knowingly)."""
+
+    params_cls = PackedSequenceParams
+    family: str
     # the largest untrained model `train` hands out
     FIXTURE_PARAMS = 5_000_000
 
@@ -244,32 +254,32 @@ class LatentMoEAlgorithm(_ServesHistories, Algorithm):
         self._scorers: dict = {}
         self._scorer_lock = threading.Lock()
 
-    def _config(self, n_items: int):
-        from predictionio_tpu.models.latent_moe import LatentMoEConfig
+    def _family(self):
+        import importlib
 
+        return importlib.import_module(self.family)
+
+    def _config(self, n_items: int):
         hf = dict(self.params.modelConfig or {})
         hf.setdefault("vocab_size", n_items)
         if hf["vocab_size"] < n_items:
             raise ValueError(
                 f"{n_items} items do not fit a vocabulary of "
                 f"{hf['vocab_size']}")
-        return LatentMoEConfig.from_hf(hf, max_len=self.params.maxLen)
+        return self._family().Config.from_hf(hf, max_len=self.params.maxLen)
 
     def _seeded_model(self, pd: TrainingData):
-        from predictionio_tpu.models.latent_moe import (
-            LatentMoEModel, init_params,
-        )
-
+        family = self._family()
         cfg = self._config(pd.interactions.n_items)
-        return LatentMoEModel(
-            config=cfg, params=init_params(cfg, self.params.seed),
+        return family.Model(
+            config=cfg, params=family.init_params(cfg, self.params.seed),
             item_map=pd.interactions.item_map, histories=pd.histories)
 
     def train(self, ctx, pd: TrainingData):
         cfg = self._config(pd.interactions.n_items)
         if cfg.param_count() > self.FIXTURE_PARAMS:
             raise NotImplementedError(
-                f"no trainer for the latent-MoE family yet: a model of "
+                f"no trainer for the {self.family} family yet: a model of "
                 f"{cfg.param_count():,} parameters would be served untrained")
         return self._seeded_model(pd)
 
@@ -345,6 +355,18 @@ class LatentMoEAlgorithm(_ServesHistories, Algorithm):
         return self.batch_predict(model, [(0, query)])[0][1]
 
 
+class LatentMoEAlgorithm(PackedSequenceAlgorithm):
+    """The latent-attention sparse-expert recommender (``latentmoe``)."""
+
+    family = "predictionio_tpu.models.latent_moe"
+
+
+class GDNHybridAlgorithm(PackedSequenceAlgorithm):
+    """The gated-delta-rule / full-attention hybrid (``gdnhybrid``)."""
+
+    family = "predictionio_tpu.models.gdn_hybrid"
+
+
 class SequentialRecommendationEngine(EngineFactory):
     @classmethod
     def apply(cls) -> Engine:
@@ -354,6 +376,7 @@ class SequentialRecommendationEngine(EngineFactory):
             algorithm_cls_map={
                 "sasrec": SASRecAlgorithm,
                 "latentmoe": LatentMoEAlgorithm,
+                "gdnhybrid": GDNHybridAlgorithm,
             },
             serving_cls=FirstServing,
             query_cls=Query,

@@ -94,3 +94,72 @@ def test_sequence_head_compiles_at_its_tile(one_chip):
 def test_compiles_without_counters_at_any_k(one_chip, k):
     compiled = _compile(one_chip, jnp.float32, 16, k, with_stats=False)
     assert len(compiled.out_info) == 2
+
+
+# -- the hybrid sequence family's kernels at olmo-hybrid-7b-l16's widths ------
+
+GDN_HEADS, GDN_DK, GDN_DV, ATTN_D = 30, 96, 192, 128
+HYBRID_HEAD_ITEMS, HYBRID_RANK = 100_352, 3_840
+
+
+def _gdn_shapes(one_chip, t):
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return shape, [shape((GDN_HEADS, t, GDN_DK), bf),
+                   shape((GDN_HEADS, t, GDN_DK), bf),
+                   shape((GDN_HEADS, t, GDN_DV), bf),
+                   shape((GDN_HEADS, t), f32), shape((GDN_HEADS, t), f32),
+                   shape((t,), jnp.int32)]
+
+
+@pytest.mark.parametrize("t", (256, 8192))
+def test_gated_delta_scan_compiles_at_the_ladders_ends(one_chip, t):
+    # what interpret mode cannot refuse: a (1, 1) value spread over both
+    # axes, an f32 product at HIGHEST inside the kernel, a (64, 8) block
+    from predictionio_tpu.ops import gated_delta
+
+    _, args = _gdn_shapes(one_chip, t)
+    compiled = jax.jit(lambda *a: gated_delta.gdn_scan(
+        *a, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (GDN_HEADS, t, GDN_DV)
+
+
+def test_gated_delta_scan_compiles_with_the_state_carry(one_chip):
+    from predictionio_tpu.ops import gated_delta
+
+    shape, args = _gdn_shapes(one_chip, 2048)
+    rows = 64
+    args += [shape((rows, GDN_HEADS, GDN_DK, GDN_DV), jnp.float32),
+             shape((rows,), jnp.int32), shape((rows,), jnp.int32)]
+    compiled = jax.jit(lambda q, k, v, g, b, s, h0, rs, rl:
+                       gated_delta.gdn_scan(
+                           q, k, v, g, b, s, h0=h0, row_start=rs, row_last=rl,
+                           output_final_state=True, interpret=False)
+                       ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info[1].shape == (rows, GDN_HEADS, GDN_DK, GDN_DV)
+
+
+def test_packed_attention_compiles_at_the_top_rung(one_chip):
+    from predictionio_tpu.ops.flash_attention import packed_causal_attention
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    x = shape((GDN_HEADS, 8192, ATTN_D), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, s: packed_causal_attention(
+        q, k, v, s, interpret=False)).lower(
+            x, x, x, shape((8192,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_hybrid_head_compiles_at_its_tile(one_chip):
+    # rank 3,840 over 100,352 rows: the widest head the score kernel sweeps
+    compiled = _compile(one_chip, jnp.bfloat16, 64, 100, with_stats=True,
+                        n_items=score_kernel.pad_block_items(
+                            HYBRID_HEAD_ITEMS), rank=HYBRID_RANK,
+                        mask_row=True)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -261,6 +261,9 @@ class TestShardedBitIdentical:
 
     def test_stats_carry_sharding_block(self, pair):
         repl, shrd = pair
+        # a dispatch of its own: under `--dist load` this test may be the
+        # first of the class its worker runs
+        shrd.score_topk(np.arange(8, dtype=np.int32), 5)
         assert repl.stats()["sharding"] is None
         assert repl.stats()["serving_backend"] == "replicated"
         sh = shrd.stats()["sharding"]
