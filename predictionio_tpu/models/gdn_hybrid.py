@@ -256,7 +256,7 @@ def _heads_first(x, heads, dtype):
     return x.reshape(t, heads, -1).transpose(1, 0, 2).astype(dtype)
 
 
-def _linear_mixer(cfg, W, x, positions, seg_start, interpret):
+def _linear_mixer(cfg, W, x, positions, seg_start, n_real, interpret):
     t = x.shape[0]
     h, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
@@ -272,7 +272,7 @@ def _linear_mixer(cfg, W, x, positions, seg_start, interpret):
     o = _gd.gdn_scan(
         q.transpose(1, 0, 2).astype(cdt), k.transpose(1, 0, 2).astype(cdt),
         _heads_first(qkv[:, 2 * h * dk:], h, cdt), g.T, beta.T, seg_start,
-        interpret=interpret)
+        n_real=n_real, interpret=interpret)
     o = rms_norm(o.transpose(1, 0, 2), W["o_norm"], cfg.rms_norm_eps)
     gate = jax.nn.silu(_mm(x, W["gate"])).reshape(t, h, dv)
     return _mm((o * gate).reshape(t, h * dv), W["o"])
@@ -293,9 +293,11 @@ def _full_mixer(cfg, W, x, seg_start, interpret):
 
 
 def trunk(cfg: GDNHybridConfig, P: dict, tokens, positions, seg_start, *,
-          interpret: Optional[bool] = None):
+          n_real=None, interpret: Optional[bool] = None):
     """The block stack over a packed token axis: the residual stream
-    (T, hidden) f32 BEFORE the final norm."""
+    (T, hidden) f32 BEFORE the final norm.  ``n_real``: the axis is padding
+    from there on, and the linear layers' scan stops at that chunk (the
+    padded tokens' rows of the result are then no model's output)."""
     eps, period = cfg.rms_norm_eps, cfg.period
     stacked = {name: v for name, v in P.items() if name[0] == "S"}
 
@@ -305,7 +307,8 @@ def trunk(cfg: GDNHybridConfig, P: dict, tokens, positions, seg_start, *,
             W = {name[len(pre):]: v for name, v in layers.items()
                  if name.startswith(pre)}
             if kind == LINEAR:
-                y = _linear_mixer(cfg, W, x, positions, seg_start, interpret)
+                y = _linear_mixer(cfg, W, x, positions, seg_start, n_real,
+                                  interpret)
             else:
                 y = _full_mixer(cfg, W, x, seg_start, interpret)
             x = x + rms_norm(y, W["attn_norm"], eps)
@@ -328,7 +331,10 @@ def forward_packed(cfg: GDNHybridConfig, P: dict, tokens, positions,
     a padded token is a one-event history that nothing reads).  Returns
     ``values`` and ``indices`` (R, k), ``h_last`` (R, hidden) bf16 and, on
     the fused score backend, the merge counters."""
-    x = trunk(cfg, P, tokens, positions, seg_start, interpret=interpret)
+    # `pack` lays rows end to end from token 0 and a padded row repeats row
+    # 0, so the last real token is the largest of `last_idx`
+    x = trunk(cfg, P, tokens, positions, seg_start,
+              n_real=jnp.max(last_idx) + 1, interpret=interpret)
     return score_head(P, cfg.vocab_size, cfg.rms_norm_eps, x[last_idx], k,
                       interpret=interpret, score_backend=score_backend)
 
@@ -345,7 +351,8 @@ def forward_flat(cfg: GDNHybridConfig, P: dict, flat, t_pad: int, k: int,
 class DispatchCounters:
     """This family's own counters in the packed scorer: what the scan of
     the linear layers was asked (real tokens and rows, one state a row a
-    layer) and what it ran (chunks, padding and alignment included)."""
+    layer) and what it ran (the chunks up to the last real token: alignment
+    to chunks included, a rung's padded tail not)."""
 
     fetch = ()
 
@@ -359,7 +366,7 @@ class DispatchCounters:
         layers = self.config.n_linear_layers
         self.scan_tokens += layers * n_tokens
         self.scan_rows += layers * n_rows
-        self.scan_chunks += layers * _gd.scan_chunks(t_pad)
+        self.scan_chunks += layers * _gd.scan_chunks(t_pad, n_real=n_tokens)
 
     def stats(self) -> dict:
         return {

@@ -145,6 +145,100 @@ def test_packed_scan_equals_each_row_alone(lens, chunk):
     assert float(jnp.abs(got - want).max()) < SCAN_TOL
 
 
+# -- (b') the padded tail is not run --------------------------------------------
+
+REAL = (30, 1, 25)  # 56 tokens: three whole chunks of 16 and half a fourth
+
+
+def _with_tail(tail_chunks, chunk=16):
+    """REAL's rows, the part-filled last chunk's padding, then ``tail_chunks``
+    whole chunks of padding: one-token histories, as ``pack`` lays them."""
+    n_real = sum(REAL)
+    t = -(-n_real // chunk) * chunk + tail_chunks * chunk
+    args = _scan_inputs(11, 56 + 3 * chunk + 8)  # the same draws at every t
+    lens = REAL + (1,) * (t - n_real)
+    return [a[:, :t] for a in args], jnp.asarray(_seg_start(lens)), n_real, t
+
+
+@pytest.mark.parametrize("tail_chunks", [0, 1, 3])
+def test_split_scan_equals_the_recurrence_before_a_padded_tail(tail_chunks):
+    args, seg, n_real, t = _with_tail(tail_chunks)
+    want, _ = _one_by_one([a[:, :n_real] for a in args], REAL)
+    got = gd.gdn_scan(*args, seg, chunk=16, n_real=jnp.int32(n_real),
+                      interpret=True)
+    assert got.shape == (H, t, DV)
+    assert float(jnp.abs(got[:, :n_real] - want).max()) < SCAN_TOL
+    # the chunks past the last real token are not run: zeros, not garbage
+    assert bool(jnp.isfinite(got).all())
+    assert not bool(got[:, 64:].any())
+    assert gd.scan_chunks(t, 16, n_real=n_real) == 4
+    assert gd.scan_chunks(t, 16) == 4 + tail_chunks
+
+
+@pytest.mark.parametrize("tail_chunks", [1, 3])
+def test_real_rows_do_not_change_by_a_bit_with_the_padded_tail(tail_chunks):
+    short, seg0, n_real, _ = _with_tail(0)
+    alone = gd.gdn_scan(*short, seg0, chunk=16, interpret=True)
+    args, seg, _, _ = _with_tail(tail_chunks)
+    skipped = gd.gdn_scan(*args, seg, chunk=16, n_real=n_real, interpret=True)
+    scanned = gd.gdn_scan(*args, seg, chunk=16, interpret=True)
+    for got in (skipped, scanned):
+        np.testing.assert_array_equal(got[:, :n_real], alone[:, :n_real])
+    # a padded token that IS scanned is a history of its own: o = b (k.q) v
+    assert bool(jnp.isfinite(scanned).all()) and bool(scanned[:, 64:].any())
+
+
+def test_counters_count_the_chunks_that_ran():
+    own = gh.DispatchCounters(CFG)
+    layers = CFG.n_linear_layers
+    own.add(256, 1, 100, {})   # two of four chunks hold a real token
+    own.add(256, 2, 256, {})
+    own.add(512, 1, 257, {})   # five of eight
+    st = own.stats()
+    assert st["scan_chunks"] == layers * (2 + 4 + 5)
+    assert st["scan_tokens"] == layers * (100 + 256 + 257)
+
+
+@pytest.mark.parametrize("chunk", [64, 16])
+def test_the_prepass_inverts_the_chunks_triangle(chunk):
+    """``T (I + A) = I`` at f32 rounding on packed chunks with two resets
+    each and write strengths near 2 (``linear_allow_neg_eigval``), where
+    ``A``'s entries are largest; ``P`` is the masked, decayed ``Q K^T``.
+    ``A`` and ``P`` are formed here in float64 from their definitions.  The
+    residual, over the inverse's largest entry, reads 1.4e-6 at chunk 64 and
+    1.9e-7 at 16 (a 64-term f32 sum: 64 x 1.2e-7); one bf16 pass in the
+    doubling would read 1e-3."""
+    t = 2 * chunk
+    q, k, _, g, _ = _scan_inputs(5, t)
+    beta = jnp.full((H, t), 2.0 - 1e-3, jnp.float32)
+    cuts = (0, chunk // 3, chunk - 5, chunk, chunk + 7, t - chunk // 4)
+    lens = tuple(np.diff(cuts + (t,)))
+    seg = _seg_start(lens)
+    cols, g_rows, _ = gd._side_inputs(g, beta, jnp.asarray(seg), chunk)
+    inv, qk = gd._prepass(jnp.full((1,), 2, jnp.int32), q, k, cols, g_rows,
+                          chunk=chunk, interpret=True)
+    assert inv.shape == qk.shape == (H, t, chunk)
+    q64, k64, g64 = (np.asarray(a, np.float64) for a in (q, k, g))
+    worst = 0.0
+    for c in range(2):
+        at = slice(c * chunk, (c + 1) * chunk)
+        i, j = np.mgrid[:chunk, :chunk]
+        same = seg[at][:, None] == seg[at][None, :]
+        for h in range(H):
+            big = np.cumsum(g64[h, at])
+            decay = np.where(same & (j <= i), np.exp(big[:, None] - big), 0.0)
+            a = np.where(j < i, float(beta[0, 0]) * decay
+                         * (k64[h, at] @ k64[h, at].T), 0.0)
+            got = np.asarray(inv[h, at], np.float64)
+            assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+            worst = max(worst, np.abs(got @ (np.eye(chunk) + a)
+                                      - np.eye(chunk)).max()
+                        / max(1.0, np.abs(got).max()))
+            np.testing.assert_allclose(
+                qk[h, at], decay * (q64[h, at] @ k64[h, at].T), atol=1e-6)
+    assert worst < 1e-5, worst
+
+
 def test_convolution_stops_at_a_rows_first_event():
     lens = (5, 1, 2, 9, 1, 1)
     r = np.random.default_rng(3)

@@ -120,10 +120,16 @@ def test_gated_delta_scan_compiles_at_the_ladders_ends(one_chip, t):
     # axes, an f32 product at HIGHEST inside the kernel, a (64, 8) block
     from predictionio_tpu.ops import gated_delta
 
-    _, args = _gdn_shapes(one_chip, t)
-    compiled = jax.jit(lambda *a: gated_delta.gdn_scan(
-        *a, interpret=False)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    # and of the split scan: two heads side by side on the lanes (a concat
+    # and a slice at lane 64), block indices clamped by a prefetched count
+    shape, args = _gdn_shapes(one_chip, t)
+    compiled = jax.jit(lambda *a, n: gated_delta.gdn_scan(
+        *a, n_real=n, interpret=False)).lower(
+            *args, n=shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    # the pre-pass and the step, each under a name that holds "gdn_scan"
+    assert text.count("tpu_custom_call") >= 2
+    assert "pio.gdn_scan_prep" in text and "%pio.gdn_scan." in text
     assert compiled.out_info.shape == (GDN_HEADS, t, GDN_DV)
 
 
