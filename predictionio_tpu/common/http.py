@@ -176,7 +176,22 @@ class HttpService:
             def log_message(self, fmt, *args):  # silence default stderr spam
                 pass
 
+            def parse_request(self):
+                # request presence on the profiler's clock starts when the
+                # request line has arrived (an idle keep-alive socket is
+                # not a request).  pio_req., not pio.: obs/tracing.py
+                self._t_parse = time.perf_counter()
+                with _tracing.annotation("pio_req.parse"):
+                    return super().parse_request()
+
             def _handle(self, method: str):
+                # with pio_req.parse, "this request is in the server" up to
+                # the answer's last byte; it has the trace's id, if any
+                rid = self.headers.get(_tracing.TRACE_HEADER)
+                with _tracing.annotation("pio_req.handle", id=rid or ""):
+                    self._respond(method, rid)
+
+            def _respond(self, method: str, rid: Optional[str]):
                 parsed = urllib.parse.urlsplit(self.path)
                 params = dict(urllib.parse.parse_qsl(parsed.query))
                 length = int(self.headers.get("Content-Length") or 0)
@@ -218,9 +233,15 @@ class HttpService:
                 if tel is not None:
                     t_req = time.perf_counter()
                     trace = tel.tracer.begin(
-                        request_id=self.headers.get(_tracing.TRACE_HEADER),
+                        request_id=rid,
                         name=f"{method} {parsed.path}",
                     )
+                    if trace is not None:
+                        # request line arrived -> the trace is born: the
+                        # headers' parsing and the body's read, which the
+                        # trace's own wall starts after
+                        trace.annotate(parse_ms=round(
+                            (t_req - self._t_parse) * 1e3, 4))
                 req = Request(
                     method=method,
                     path=parsed.path,

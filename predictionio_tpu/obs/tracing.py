@@ -1,9 +1,11 @@
 """Request-scoped tracing: per-stage breakdown, sampling, bounded ring.
 
-A :class:`Trace` is born at HTTP accept (``common/http.py``), rides the
-request through the serving pipeline, and lands in a bounded in-memory
-ring exposed at ``GET /trace/recent.json``.  Stages recorded on the query
-path:
+A :class:`Trace` is born in ``common/http.py``'s ``_handle`` once the
+request's headers are parsed and its body is read (the time before that,
+from the request line's arrival on, rides on it as ``meta.parse_ms``), goes
+with the request through the serving pipeline, and lands in a bounded
+in-memory ring exposed at ``GET /trace/recent.json``.  Stages recorded on
+the query path:
 
 ``decode`` → ``queue_wait`` (MicroBatcher) → ``batch_assembly`` → ``h2d``
 → ``device_compute`` → ``d2h`` → ``postprocess`` → ``serialize``; whatever
@@ -14,6 +16,16 @@ The micro-batcher keeps one :class:`Dispatch` record per batch run whether
 or not a sampled request rides it; :func:`stage` charges the shared stages
 to that record too, and enters ``jax.profiler.TraceAnnotation("pio.<name>")``
 so a profiler session shows the host stages on the device ops' clock.
+
+One clock, one chain of identifiers: ``pio_req.parse`` and
+``pio_req.handle(id=<request id>)`` (``common/http.py``) say on the
+profiler's clock that a request is in the server; the request's
+:class:`Trace` has the same id and names the dispatch that carried it
+(``meta.dispatch_seq``); that dispatch's spans carry ``seq`` (and ``rung``);
+its device program is the ``XLA Modules`` event inside its
+``pio.device_compute`` span, whose enqueue is ``pio.launch``
+(:func:`launch`).  The ``pio_req.`` prefix keeps request presence out of
+readers that union the ``pio.`` stages.
 
 Propagation contract (documented in docs/observability.md):
 
@@ -252,6 +264,7 @@ def scope(
 
 
 _TraceAnnotation = None
+_NO_SPAN = contextlib.nullcontext()
 
 
 def annotation(name: str, **kv):
@@ -262,9 +275,27 @@ def annotation(name: str, **kv):
     if _TraceAnnotation is None:
         jax = sys.modules.get("jax")
         if jax is None:
-            return contextlib.nullcontext()
+            return _NO_SPAN
         _TraceAnnotation = jax.profiler.TraceAnnotation
     return _TraceAnnotation(name, **kv)
+
+
+def _ids(disp: Optional[Dispatch]) -> dict:
+    """What joins a dispatch's spans to its record: ``seq``, and ``rung``
+    once the scorer has set it."""
+    if disp is None:
+        return {}
+    if disp.rung is None:
+        return {"seq": disp.seq}
+    return {"seq": disp.seq, "rung": disp.rung}
+
+
+def launch():
+    """``pio.launch(seq=, rung=)``: a scorer's jitted call until it returns,
+    which is the enqueue; the wait for the device is the rest of the
+    ``pio.device_compute`` stage it lies in.  On the profiler's clock only:
+    it is charged to no trace and no record."""
+    return annotation("pio.launch", **_ids(getattr(_active, "dispatch", None)))
 
 
 @contextlib.contextmanager
@@ -281,10 +312,9 @@ def stage(name: str):
     if not traces and disp is None:
         yield
         return
-    kv = {} if disp is None else {"seq": disp.seq}
     t0 = time.perf_counter()
     try:
-        with annotation("pio." + name, **kv):
+        with annotation("pio." + name, **_ids(disp)):
             yield
     finally:
         t1 = time.perf_counter()
@@ -292,12 +322,6 @@ def stage(name: str):
             t.add_stage(name, t1 - t0)
         if disp is not None:
             disp.add_stage(name, t0, t1)
-
-
-def add_stage(name: str, seconds: float) -> None:
-    """Charge an externally-measured duration to every active trace."""
-    for t in getattr(_active, "traces", ()):
-        t.add_stage(name, seconds)
 
 
 def new_request_id() -> str:

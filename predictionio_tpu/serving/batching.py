@@ -97,6 +97,9 @@ class _Pending:
     trace: Any = None
     t_enq: float = 0.0
     done_at_enq: int = 0
+    # when _resolve set the event (traced pendings only): the hand-back to
+    # the handler thread is measured from it
+    t_set: float = 0.0
     # single-flight: the coalescing key this pending leads (None = not
     # coalescable) and the identical-query followers its result fans out to
     key: Any = None
@@ -283,11 +286,18 @@ class MicroBatcher:
         finally:
             if free:
                 self._busy.release()
-        if not inline and not p.event.wait(eff.remaining_s()):
-            # the pending stays queued, but its deadline has passed — the
-            # worker is GUARANTEED to drop it at dispatch (same monotonic
-            # clock), so the device never runs an abandoned query
-            raise DeadlineExceeded("batched query timed out")
+        if not inline:
+            if not p.event.wait(eff.remaining_s()):
+                # the pending stays queued, but its deadline has passed —
+                # the worker is GUARANTEED to drop it at dispatch (same
+                # monotonic clock), so the device never runs an abandoned
+                # query
+                raise DeadlineExceeded("batched query timed out")
+            if p.t_set:
+                # the hand-back: the worker set the event -> this thread
+                # runs again (the GIL's turn-taking; an inline run has none)
+                p.trace.annotate(handback_ms=round(
+                    (time.perf_counter() - p.t_set) * 1e3, 4))
         if p.error is not None:
             raise p.error
         return p.result
@@ -520,6 +530,8 @@ class MicroBatcher:
         for waiter in [p, *followers]:
             waiter.result = result
             waiter.error = error
+            if waiter.trace is not None:
+                waiter.t_set = time.perf_counter()
             waiter.event.set()
 
     def _expire_leader(self, p: _Pending) -> Optional[_Pending]:

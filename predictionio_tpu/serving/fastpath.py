@@ -1056,7 +1056,8 @@ class BucketedScorer:
                 t0 = time.perf_counter()
                 # (vals, idx), and the merge counters where the program
                 # was compiled with them (_compile)
-                outs = self._fns[b](*self._static_args, u_dev)
+                with _tracing.launch():
+                    outs = self._fns[b](*self._static_args, u_dev)
                 # force completion INSIDE the stage so async dispatch
                 # can't smear device time into the d2h readback below —
                 # and so the utilization accountant charges true device

@@ -168,7 +168,8 @@ class PackedSequenceScorer:
             with _tracing.stage("h2d"):
                 dev = self._put(batch)
             with _tracing.stage("device_compute"):
-                out = self._fns[t](self._params, dev)
+                with _tracing.launch():
+                    out = self._fns[t](self._params, dev)
                 small = {name: out[name] for name in
                          ("values", "indices", "merge") + self._own.fetch
                          if name in out}

@@ -246,6 +246,30 @@ def test_published_cut_counts_the_parameters_the_issue_states():
 # -- the scorer and the template ------------------------------------------------
 
 
+def test_the_packed_scorer_marks_its_launch_inside_device_compute(
+        weights, monkeypatch):
+    """ISSUE 37: `pio.launch(seq=, rung=)` is the jitted call's return (the
+    enqueue), nested in `pio.device_compute`, which carries the rung too."""
+    from predictionio_tpu.obs import tracing
+    from predictionio_tpu.serving.seqpath import PackedSequenceScorer
+
+    sc = PackedSequenceScorer(CFG, weights["f32"], max_k=K, ladder=(32, 64),
+                              max_rows=4)
+    seen, real = [], tracing.annotation
+    monkeypatch.setattr(
+        tracing, "annotation",
+        lambda name, **kv: seen.append((name, kv)) or real(name, **kv))
+    rec = tracing.Dispatch(5, False, 2, 0, t_run=0.0, collect_s=0.0,
+                           slow_after_s=2.0)
+    with tracing.scope((), dispatch=rec):
+        sc.score_topk(_histories(11, (5, 20)), 5)
+    ids = {"seq": 5, "rung": 32}
+    assert seen == [("pio.batch_assembly", ids), ("pio.h2d", ids),
+                    ("pio.device_compute", ids), ("pio.launch", ids),
+                    ("pio.d2h", ids)]
+    assert rec.rung == 32 and rec.stages["device_compute"] > 0
+
+
 def test_scorer_compiles_ahead_and_never_again(weights):
     from predictionio_tpu.serving.seqpath import PackedSequenceScorer
 

@@ -201,6 +201,90 @@ class TestTracer:
         assert obs_tracing.active_traces() == ()
 
 
+class TestRequestPresence:
+    """ISSUE 37: a request is on the profiler's clock from its request line
+    to its last byte, and the time before its trace is born rides on the
+    trace as ``meta.parse_ms`` without moving its wall or its stages."""
+
+    @pytest.fixture()
+    def service(self, monkeypatch):
+        import types
+
+        from predictionio_tpu.common import http as http_mod
+
+        class Ticks:
+            """perf_counter that moves 1 ms each time it is read."""
+
+            def __init__(self):
+                self.t = 50.0
+
+            def __call__(self):
+                self.t += 0.001
+                return self.t
+
+        shim = types.SimpleNamespace(perf_counter=Ticks(), time=time.time,
+                                     sleep=time.sleep)
+        monkeypatch.setattr(http_mod, "time", shim)
+        monkeypatch.setattr(obs_tracing, "time", shim)
+        seen = []
+        monkeypatch.setattr(
+            obs_tracing, "annotation",
+            lambda name, **kv: seen.append((name, kv)) or obs_tracing._NO_SPAN)
+        born = []
+        real_init = obs_tracing.Trace.__init__
+
+        def counted(self, *a, **kw):
+            born.append(a)
+            real_init(self, *a, **kw)
+
+        monkeypatch.setattr(obs_tracing.Trace, "__init__", counted)
+        svc = http_mod.HttpService("presence")
+        tel = obs.Telemetry("presence", sample_rate=0.0).install(svc)
+
+        @svc.route("POST", r"/echo\.json")
+        def _echo(req):
+            return http_mod.json_response(200, req.json())
+
+        port = svc.start("127.0.0.1", 0)
+        yield {"base": f"http://127.0.0.1:{port}", "tel": tel, "seen": seen,
+               "born": born}
+        svc.stop()
+
+    def test_a_sampled_request_carries_parse_ms_outside_its_wall(
+            self, service):
+        _post(service["base"] + "/echo.json", {"a": 1},
+              headers={obs.TRACE_HEADER: "presence-1"})
+        ring, end = [], time.monotonic() + 5.0
+        while not ring and time.monotonic() < end:
+            ring = service["tel"].tracer.recent()
+            time.sleep(0.01)
+        (d,) = ring
+        # clock reads of the request, 1 ms apart: parse start | t_req |
+        # trace born | send start | send end | finish.  The first is new;
+        # from the trace's birth on they are the reads there were
+        assert d["meta"] == {"parse_ms": 1.0}
+        assert d["wallMs"] == 3.0
+        assert d["stagesMs"] == {"serialize": 1.0, "other": 2.0}
+
+    def test_presence_spans_open_in_order_with_the_requests_id(
+            self, service):
+        _post(service["base"] + "/echo.json", {"a": 1},
+              headers={obs.TRACE_HEADER: "presence-2"})
+        assert service["seen"][:2] == [
+            ("pio_req.parse", {}), ("pio_req.handle", {"id": "presence-2"})]
+
+    def test_an_unsampled_request_allocates_no_trace(self, service):
+        for _ in range(3):
+            _post(service["base"] + "/echo.json", {"a": 1})
+        tracer = service["tel"].tracer
+        assert (tracer.seen, tracer.sampled) == (3, 0)
+        assert service["born"] == [] and len(tracer.ring) == 0
+        # presence is still marked (a flag test without a session), with no
+        # id to give
+        assert service["seen"].count(("pio_req.handle", {"id": ""})) == 3
+        assert all(not n.startswith("pio.") for n, _ in service["seen"])
+
+
 # -- Stats cardinality cap ----------------------------------------------------
 
 
