@@ -255,6 +255,9 @@ class BucketedScorer:
         self.hits: dict[int, int] = {b: 0 for b in self.buckets}
         self.queries = 0
         self.padded_rows = 0
+        # dispatches whose readback was requested before the wait, counted
+        # where it is requested (_queue_readback): equals stats()["calls"]
+        self.readbacks_queued = 0
         # the fused kernel's merge counters, summed over dispatches: passes
         # that inserted, blocks that merged anything (ops/score_kernel.py)
         self.merge_passes = 0
@@ -298,7 +301,9 @@ class BucketedScorer:
         self.warmup_executions = 0
         self._fns = {b: self._compile(b) for b in self.buckets}
         for b in self.buckets:
-            dummy_idx = self._put_repl(np.zeros(b, np.int32))
+            # the input in the form a dispatch hands over, so the call's
+            # handling of it is warm too
+            dummy_idx = self._call_input(np.zeros(b, np.int32))
             jax.block_until_ready(self._fns[b](*self._static_args, dummy_idx))
             self.warmup_executions += 1
 
@@ -316,13 +321,30 @@ class BucketedScorer:
 
         return jax.device_put(jnp.asarray(x), self._repl)
 
-    def _fetch(self, xs: tuple) -> tuple:
-        """Device→host for a program's REPLICATED outputs, multi-process
+    def _call_input(self, x: np.ndarray):
+        """A dispatch's host array as the compiled program takes it: the
+        array itself.  The call's own argument handling places it under
+        the program's input sharding, so it gets no ``device_put`` (a host
+        ↔ device round trip) of its own first.  Across processes one
+        host's array cannot feed remote shards: there it is placed."""
+        return self._put_repl(x) if self._pod_spans else x
+
+    def _queue_readback(self, outs: tuple) -> tuple:
+        """The arrays a dispatch reads back from a program's REPLICATED
+        outputs, each one's device→host copy requested NOW: called on the
+        not-yet-ready outputs the launch returned, it queues the copies
+        behind the program, so ONE wait (the ``device_get`` of what this
+        returns) ends the run, instead of a wake-up for the program and a
+        second round trip asked for after it.  Multi-process
         safe: any one addressable shard of a replicated array is the whole
-        value.  One ``device_get`` of the tuple, so the copies overlap."""
+        value."""
         if self._pod_spans:
-            xs = tuple(x.addressable_data(0) for x in xs)
-        return tuple(jax.device_get(xs))
+            outs = tuple(x.addressable_data(0) for x in outs)
+        for x in outs:
+            x.copy_to_host_async()
+        with self._lock:
+            self.readbacks_queued += 1
+        return outs
 
     def _init_replicated_placement(
         self, user_factors, item_factors, user_scale, item_scale
@@ -1051,23 +1073,32 @@ class BucketedScorer:
             if disp is not None:
                 disp.rung = b
             with _tracing.stage("h2d"):
-                u_dev = self._put_repl(padded)
+                # the padded rows ride the compiled call; only a pod that
+                # spans processes still places them here
+                u_in = self._call_input(padded)
             with _tracing.stage("device_compute"):
                 t0 = time.perf_counter()
                 # (vals, idx), and the merge counters where the program
                 # was compiled with them (_compile)
                 with _tracing.launch():
-                    outs = self._fns[b](*self._static_args, u_dev)
-                # force completion INSIDE the stage so async dispatch
-                # can't smear device time into the d2h readback below —
-                # and so the utilization accountant charges true device
-                # wall, not enqueue time. (The readback two lines down
-                # would block here anyway; this only moves the wait.)
-                jax.block_until_ready(outs)  # pio: ignore[hotpath-block-sync]
+                    outs = self._fns[b](*self._static_args, u_in)
+                # asked for at launch, not after the wake-up
+                back = self._queue_readback(outs)
+                # the ONE wait, INSIDE the stage: the get of the queued
+                # copies returns when the program has run and its outputs
+                # have landed, so async dispatch can't smear device time
+                # past the stage and the utilization accountant charges
+                # the run and its readback, not enqueue time.  (On the chip
+                # a wait for the program first and the get after it cost
+                # one more wake-up of this thread: PERF.md §6, PR 38.)
+                val_h, idx_h, *merge = jax.device_get(back)
                 wall = time.perf_counter() - t0
                 self.devprof.record(b, wall)
             with _tracing.stage("d2h"):
-                val_h, idx_h, *merge = self._fetch(outs)
+                # the readback's residue on the host: the rows asked for
+                # (padded tail rows are real top-k rows for user 0)
+                idx_rows = idx_h[: len(chunk), :k]
+                val_rows = val_h[: len(chunk), :k]
             with self._lock:
                 self.hits[b] += 1
                 self.queries += len(chunk)
@@ -1080,7 +1111,7 @@ class BucketedScorer:
                         disp.merge_passes += passes
                 if self._shard_acct is not None:
                     self._shard_acct.note(
-                        idx_h[: len(chunk), :k], b, wall,
+                        idx_rows, b, wall,
                         self._cost_bytes.get(b, 0.0),
                     )
                 if self.retrieval == "ivf":
@@ -1090,9 +1121,8 @@ class BucketedScorer:
                         self._probes[b] * self._ivf_layout.cap_pad
                     )
                     self._ivf_dispatch_rows += b
-            # padded tail rows are real top-k rows for user 0 — dropped here
-            idx_parts.append(idx_h[: len(chunk), :k])
-            val_parts.append(val_h[: len(chunk), :k])
+            idx_parts.append(idx_rows)
+            val_parts.append(val_rows)
         return np.concatenate(idx_parts), np.concatenate(val_parts)
 
     # -- hot set -------------------------------------------------------------
@@ -1248,6 +1278,7 @@ class BucketedScorer:
                 "compile_count": self.compile_count,
                 "bucket_hits": {str(b): h for b, h in hits.items()},
                 "calls": sum(hits.values()),
+                "readbacks_queued": self.readbacks_queued,
                 "queries": self.queries,
                 "padded_rows": self.padded_rows,
                 "merge_passes": self.merge_passes,

@@ -83,6 +83,9 @@ class PackedSequenceScorer:
         # (query, key) pairs attention must visit: sum of n(n+1)/2 over rows
         self.causal_pairs = 0
         self.merge_passes = 0
+        # dispatches whose readback was requested before the wait, counted
+        # where it is requested (_queue_readback): equals stats()["calls"]
+        self.readbacks_queued = 0
         self._own = self._family.DispatchCounters(config)
         self._fns = {t: self._compile(t) for t in self.ladder}
         self._warm()
@@ -112,9 +115,11 @@ class PackedSequenceScorer:
 
     def _warm(self) -> None:
         for t in self.ladder:
-            batch = self._put(self._family.pack(
+            # the host array itself, as a dispatch hands it over, so the
+            # call's handling of it is warm too
+            flat = self._family.flatten(self._family.pack(
                 [np.zeros(1, np.int32)], t, self.max_rows))
-            jax.block_until_ready(self._fns[t](self._params, batch))
+            jax.block_until_ready(self._fns[t](self._params, flat))
             self.warmup_executions += 1
 
     def _put(self, batch: dict):
@@ -166,22 +171,41 @@ class PackedSequenceScorer:
             with _tracing.stage("batch_assembly"):
                 batch = self._family.pack(rows, t, self.max_rows)
             with _tracing.stage("h2d"):
-                dev = self._put(batch)
+                # the flat host array rides the compiled call, whose own
+                # argument handling places it: no device_put (a host ↔
+                # device round trip) of its own
+                flat = self._family.flatten(batch)
             with _tracing.stage("device_compute"):
                 with _tracing.launch():
-                    out = self._fns[t](self._params, dev)
-                small = {name: out[name] for name in
-                         ("values", "indices", "merge") + self._own.fetch
-                         if name in out}
-                # completion INSIDE the stage, as the bucketed scorer does:
-                # device time must not smear into the readback
-                jax.block_until_ready(small)  # pio: ignore[hotpath-block-sync]
-            with _tracing.stage("d2h"):
+                    out = self._fns[t](self._params, flat)
+                # asked for at launch, not after the wake-up: the copies
+                # queue behind the program
+                small = self._queue_readback(out)
+                # the ONE wait, INSIDE the stage as the bucketed scorer's:
+                # the get returns when the program has run and its outputs
+                # have landed
                 got = jax.device_get(small)
+            with _tracing.stage("d2h"):
+                # the readback's residue on the host: the rows asked for
+                idx_rows = got["indices"][: len(rows), :k]
+                val_rows = got["values"][: len(rows), :k]
             self._count(t, rows, n_tok, got, disp)
-            idx_parts.append(got["indices"][: len(rows), :k])
-            val_parts.append(got["values"][: len(rows), :k])
+            idx_parts.append(idx_rows)
+            val_parts.append(val_rows)
         return np.concatenate(idx_parts), np.concatenate(val_parts)
+
+    def _queue_readback(self, out: dict) -> dict:
+        """The outputs a dispatch reads back (the leaderboard, the merge
+        counter, the family's ``fetch``), each one's device→host copy
+        requested NOW, on the not-yet-ready arrays the launch returned."""
+        small = {name: out[name] for name in
+                 ("values", "indices", "merge") + self._own.fetch
+                 if name in out}
+        for x in small.values():
+            x.copy_to_host_async()
+        with self._lock:
+            self.readbacks_queued += 1
+        return small
 
     def _count(self, t, rows, n_tok, got, disp) -> None:
         with self._lock:
@@ -219,6 +243,7 @@ class PackedSequenceScorer:
                 "warmup_executions": self.warmup_executions,
                 "bucket_hits": {str(t): n for t, n in self.hits.items()},
                 "calls": sum(self.hits.values()),
+                "readbacks_queued": self.readbacks_queued,
                 "queries": self.queries,
                 "tokens": self.tokens,
                 "padded_tokens": self.padded_tokens,

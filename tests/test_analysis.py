@@ -1102,6 +1102,38 @@ def test_repo_analyzes_clean(repo_report):
     assert repo_report.errors == 0, "\n".join(errs)
 
 
+def test_the_serving_path_waives_no_block_sync_any_more():
+    """ISSUE 38: each scorer's ONE wait for the device is the `device_get`
+    of the readback it queued at launch, inside its `device_compute` stage.
+    The two `hotpath-block-sync` waivers on the scorers' `block_until_ready`
+    went with that call; none was added elsewhere (`models/als.py` keeps the
+    trainer's two)."""
+    import inspect
+
+    from predictionio_tpu.serving import fastpath, seqpath
+
+    waiver = "pio: ignore[hotpath-block-sync]"
+    pkg = os.path.join(ROOT, "predictionio_tpu")
+    counts = {}
+    for dirpath, _, files in os.walk(pkg):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(dirpath, name)) as f:
+                    n = f.read().count(waiver)
+                if n:
+                    counts[os.path.relpath(
+                        os.path.join(dirpath, name), pkg)] = n
+    assert counts == {os.path.join("models", "als.py"): 2}
+    for fn in (fastpath.BucketedScorer._device_topk,
+               seqpath.PackedSequenceScorer.score_topk):
+        body = inspect.getsource(fn)
+        assert "block_until_ready(" not in body
+        # launch, the copy asked for, the get that waits, then the d2h stage
+        assert body.index("_tracing.launch()") < body.index(
+            "_queue_readback(") < body.index("jax.device_get(") < body.index(
+            '_tracing.stage("d2h")')
+
+
 def test_repo_knob_registry_is_fully_documented(repo_report):
     knobs = repo_report.extras["knobs"]
     undocumented = [e["name"] for e in knobs["entries"]

@@ -109,6 +109,11 @@ def held_then(mb, runs, first, waiting):
     for n, q in enumerate(waiting, start=1):
         submitted.append(submit_traced(mb, q))
         wait_until(lambda: mb.depth() == n, f"{q} queued")
+    if waiting:
+        # the worker stamps "first row taken" on the clock the held run is
+        # about to move: let it take the row before the gate opens
+        wait_until(lambda: mb._queue.qsize() < len(waiting),
+                   "the worker holds the first waiting row")
     runs.gate.set()
     for th, _ in submitted:
         th.join(WAIT_S)
@@ -1180,6 +1185,7 @@ def test_trace_dispatches_json_serves_one_record_per_dispatch(served):
     for r in recs:
         assert r["rung"] == 1 and r["rows"] == 1 and r["inline"]
         assert r["stagesMs"]["device_compute"] > 0
+        assert {"h2d", "device_compute", "d2h"} <= set(r["stagesMs"])
         assert sum(r["stagesMs"].values()) == pytest.approx(r["wallMs"],
                                                             abs=1e-3)
     # every traced request names a dispatch that is there, and its own

@@ -14,6 +14,7 @@ import types
 import numpy as np
 import pytest
 
+from predictionio_tpu.obs import tracing
 from predictionio_tpu.parallel.mesh import MeshContext
 from predictionio_tpu.serving import batching, fastpath
 from predictionio_tpu.serving.batching import MicroBatcher
@@ -364,3 +365,248 @@ class TestAdaptiveBatcher:
             assert sum(stats["batch_sizes"].values()) == stats["batches"]
         finally:
             mb.stop()
+
+
+# -- ISSUE 38: a dispatch's host envelope, the same in both scorers -------------
+
+
+class _Recorded:
+    """Stands in for one output array of a compiled program and writes down,
+    in order, what the scorer asks of it."""
+
+    def __init__(self, arr, name, log):
+        self._arr, self._name, self._log = arr, name, log
+
+    def copy_to_host_async(self):
+        self._log.append(("copy", self._name))
+        self._arr.copy_to_host_async()
+
+    def block_until_ready(self):
+        self._log.append(("wait", self._name))
+        self._arr.block_until_ready()
+        return self
+
+    def addressable_data(self, i):
+        self._log.append(("shard", self._name, i))
+        return _Recorded(self._arr.addressable_data(i), self._name, self._log)
+
+    def __array__(self, *a, **kw):
+        self._log.append(("get", self._name))
+        return np.asarray(self._arr)
+
+
+def _bucketed_boundary(ctx, factors):
+    import jax
+    import jax.numpy as jnp
+
+    U, V = factors
+    sc = BucketedScorer(ctx, U, V, max_k=5)
+    rng = np.random.default_rng(38)
+
+    def direct(users):
+        """The parent's envelope: a placed input, the program, one get."""
+        idx, val = [], []
+        for s in range(0, len(users), sc.buckets[-1]):
+            chunk = users[s:s + sc.buckets[-1]]
+            padded = np.zeros(bucket_for(len(chunk), sc.buckets), np.int32)
+            padded[:len(chunk)] = chunk
+            v, i, *_ = jax.device_get(sc._fns[len(padded)](
+                *sc._static_args,
+                jax.device_put(jnp.asarray(padded), sc._repl)))
+            idx.append(i[:len(chunk), :sc.k])
+            val.append(v[:len(chunk), :sc.k])
+        return np.concatenate(idx), np.concatenate(val)
+
+    def record(monkeypatch, rung, log, inputs):
+        real = sc._fns[rung]
+
+        def fn(*args):
+            inputs.append(args[-1])
+            outs = real(*args)
+            return tuple(_Recorded(a, n, log) for a, n in
+                         zip(outs, ("values", "indices", "merge")))
+
+        monkeypatch.setitem(sc._fns, rung, fn)
+
+    return types.SimpleNamespace(
+        scorer=sc, rungs=sc.buckets, direct=direct, record=record,
+        arg=lambda rung: rng.integers(0, U.shape[0], rung).astype(np.int32),
+        over_top=lambda: rng.integers(0, U.shape[0], 70).astype(np.int32),
+        # (vals, idx); the merge counters only where the fused kernel runs
+        fetched=("values", "indices"))
+
+
+def _packed_boundary(family):
+    from predictionio_tpu.serving.seqpath import PackedSequenceScorer
+
+    if family == "latent_moe":
+        from predictionio_tpu.models import latent_moe as model
+
+        cfg = model.LatentMoEConfig.from_hf(dict(
+            vocab_size=300, hidden_size=64, num_hidden_layers=2,
+            intermediate_size=96, moe_intermediate_size=32,
+            n_routed_experts=8, num_experts_per_tok=2, num_attention_heads=4,
+            q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16), max_len=64)
+    else:
+        from predictionio_tpu.models import gdn_hybrid as model
+
+        cfg = model.GDNHybridConfig.from_hf(dict(
+            vocab_size=300, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+            layer_types=["linear_attention"] * 3 + ["full_attention"],
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=8, linear_value_head_dim=16,
+            linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+            rms_norm_eps=1e-6, hidden_act="silu", attention_bias=False,
+            tie_word_embeddings=False, rope_parameters={"rope_theta": None},
+        ), max_len=64)
+    sc = PackedSequenceScorer(cfg, model.init_params(cfg, 38), max_k=5,
+                              ladder=(64, 128), max_rows=4)
+    rng = np.random.default_rng(38)
+
+    def hists(*lengths):
+        return [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+                for n in lengths]
+
+    def direct(rows):
+        """`forward`: the audits' direct dispatch on a placed input."""
+        idx, val = [], []
+        for lo, hi in sc._chunks(rows):
+            out = sc.forward(rows[lo:hi])
+            idx.append(out["indices"][:hi - lo, :sc.k])
+            val.append(out["values"][:hi - lo, :sc.k])
+        return np.concatenate(idx), np.concatenate(val)
+
+    def record(monkeypatch, rung, log, inputs):
+        real = sc._fns[rung]
+
+        def fn(params, flat):
+            inputs.append(flat)
+            return {n: _Recorded(a, n, log)
+                    for n, a in real(params, flat).items()}
+
+        monkeypatch.setitem(sc._fns, rung, fn)
+
+    return types.SimpleNamespace(
+        scorer=sc, rungs=sc.ladder, direct=direct, record=record,
+        arg=lambda rung: hists(*{64: (20, 30), 128: (60, 50)}[rung]),
+        over_top=lambda: hists(5, 20, 17, 3, 60, 64, 20),  # 3 dispatches
+        # the leaderboard and the family's own; h_last and the rest stay
+        fetched=("values", "indices") + sc._own.fetch)
+
+
+@pytest.fixture(scope="module", params=["bucketed", "latent_moe",
+                                        "gdn_hybrid"])
+def boundary(request, ctx, factors):
+    if request.param == "bucketed":
+        return _bucketed_boundary(ctx, factors)
+    return _packed_boundary(request.param)
+
+
+class TestDispatchEnvelope:
+    """The inputs ride the compiled call and the readback is queued behind
+    the program at launch; no program, shape or answer changes."""
+
+    def test_answers_are_the_direct_program_calls_bit_for_bit(self, boundary):
+        sc = boundary.scorer
+        for arg in [boundary.arg(r) for r in boundary.rungs] + [
+                boundary.over_top()]:
+            idx, val = sc.score_topk(arg, sc.k)
+            ref_idx, ref_val = boundary.direct(arg)
+            assert idx.dtype == ref_idx.dtype and val.dtype == ref_val.dtype
+            np.testing.assert_array_equal(idx, ref_idx)
+            np.testing.assert_array_equal(val, ref_val)
+
+    def test_the_host_copy_is_requested_before_the_wait_once_an_array(
+            self, boundary, monkeypatch):
+        sc = boundary.scorer
+        for rung in boundary.rungs:
+            log, inputs = [], []
+            boundary.record(monkeypatch, rung, log, inputs)
+            sc.score_topk(boundary.arg(rung), sc.k)
+            names = [n for n in boundary.fetched + ("merge",)
+                     if ("copy", n) in log]
+            assert set(names) >= set(boundary.fetched)
+            n = len(names)
+            # every fetched array is asked for its host copy first, once
+            # (`device_get` asks again as it starts: those come after), and
+            # nothing else is
+            assert sorted(log[:n]) == sorted(("copy", x) for x in names)
+            # the ONE wait is the get of those arrays: nobody waits for the
+            # program apart, so no second wake-up follows the first
+            assert not [e for e in log if e[0] == "wait"]
+            assert sorted(e for e in log if e[0] == "get") == sorted(
+                ("get", x) for x in names)
+            assert min(i for i, e in enumerate(log) if e[0] == "get") >= n
+            # the input rode the call: a host array, no placement of its own
+            assert [type(x) for x in inputs] == [np.ndarray]
+
+    def test_every_call_counts_a_queued_readback(self, boundary):
+        sc = boundary.scorer
+        before = sc.stats()
+        sc.score_topk(boundary.over_top(), sc.k)
+        after = sc.stats()
+        assert after["calls"] - before["calls"] >= 2
+        assert after["readbacks_queued"] == after["calls"]
+
+    def test_a_record_keeps_its_three_stages_in_order(self, boundary,
+                                                      monkeypatch):
+        """What crosses the boundary when has moved, the stages have not: a
+        record still holds `h2d`, `device_compute`, `d2h`, one after the
+        other, the launch still lies inside `pio.device_compute`, and the
+        turnaround counter's two instants are still that stage's ends."""
+        sc, rung = boundary.scorer, boundary.rungs[-1]
+        seen, spans, real = [], [], tracing.annotation
+        monkeypatch.setattr(
+            tracing, "annotation",
+            lambda name, **kv: seen.append(name) or real(name, **kv))
+
+        class Rec(tracing.Dispatch):
+            def add_stage(self, name, t0, t1):
+                spans.append((name, t0, t1))
+                super().add_stage(name, t0, t1)
+
+        rec = Rec(9, False, 1, 0, t_run=time.perf_counter(), collect_s=0.0,
+                  slow_after_s=2.0)
+        with tracing.scope((), dispatch=rec):
+            sc.score_topk(boundary.arg(rung), 3)
+        assert seen[-4:] == ["pio.h2d", "pio.device_compute", "pio.launch",
+                             "pio.d2h"]
+        h2d, dc, d2h = spans[-3:]
+        assert [s[0] for s in (h2d, dc, d2h)] == ["h2d", "device_compute",
+                                                  "d2h"]
+        assert h2d[1] <= h2d[2] <= dc[1] < dc[2] <= d2h[1] <= d2h[2]
+        assert (rec.dc_start, rec.dc_end) == (dc[1], dc[2])
+        assert rec.rung == rung
+        assert set(rec.stages) == set(tracing.Dispatch.STAGES)
+
+    def test_across_processes_the_placement_and_the_shard_stay(
+            self, ctx, factors, monkeypatch):
+        """A pod that spans processes cannot feed remote shards from one
+        host's array, nor read a global one: the flag the scorer already has
+        keeps `place` on the way in and `addressable_data(0)` on the way out."""
+        b = _bucketed_boundary(ctx, factors)
+        sc = b.scorer
+        placed = []
+
+        def place(x, *spec):
+            placed.append(x)
+            return ctx.replicate(x)
+
+        sc._shard_ctx = types.SimpleNamespace(place=place)
+        sc._pod_spans = True
+        log, inputs = [], []
+        b.record(monkeypatch, 8, log, inputs)
+        users = b.arg(8)
+        idx, val = sc.score_topk(users, sc.k)
+        assert len(placed) == 1 and type(placed[0]) is np.ndarray
+        assert type(inputs[0]) is not np.ndarray  # the placed array went in
+        assert log[:4] == [("shard", "values", 0), ("shard", "indices", 0),
+                           ("copy", "values"), ("copy", "indices")]
+        assert {e[0] for e in log[4:]} == {"copy", "get"}  # device_get's own
+        sc._pod_spans = False
+        ref_idx, ref_val = b.direct(users)
+        np.testing.assert_array_equal(idx, ref_idx)
+        np.testing.assert_array_equal(val, ref_val)
+        assert sc.stats()["readbacks_queued"] == sc.stats()["calls"] == 1

@@ -113,7 +113,7 @@ def main() -> int:
             t2 = time.perf_counter()
             jax.block_until_ready(outs)
             t3 = time.perf_counter()
-            scorer._fetch(outs)
+            jax.device_get(outs)
             t4 = time.perf_counter()
             rows.append({"phase": phase, "i": i, "t0": t0,
                          "h2d_ms": (t1 - t0) * 1e3,
