@@ -424,12 +424,16 @@ def packed_causal_attention(
     the first token of token ``t``'s history (histories contiguous, in
     order; a padded token is a history of its own), so token ``t`` attends
     to ``seg_start[t] <= s <= t``.  ``T`` must be a multiple of the block
-    (256, or ``T`` itself when shorter).  The layout of
-    ``ops/latent_attention.py``: grid ``(heads, q_blocks)``, a head's whole
-    K and V in VMEM while its query blocks sweep, the loop over key blocks
-    INSIDE the kernel from the block that holds the start of the query
-    block's first history to the diagonal — blocks above the diagonal or
-    wholly in other histories cost neither a grid step nor a DMA.
+    (256, or ``T`` itself when shorter).
+
+    The layout of THIS call and of ``ops/latent_attention.py`` — not of
+    packed attention in general: :func:`packed_grouped_attention` below
+    streams its key blocks — is grid ``(heads, q_blocks)``, a head's whole
+    K and V in VMEM while its query blocks sweep (``T x d`` twice a head,
+    4 MB at 8,192 tokens of 128: VMEM bounds ``T`` here), the loop over key
+    blocks INSIDE the kernel from the block that holds the start of the
+    query block's first history to the diagonal — blocks above the diagonal
+    or wholly in other histories cost neither a grid step nor a DMA.
     """
     heads, t, d = q.shape
     block = block or min(PACKED_BLOCK, t)
@@ -474,3 +478,162 @@ def packed_causal_attention(
             ),
             interpret=interpret,
         )(lo, q, start_lanes, k, v)
+
+
+# -- packed histories, grouped heads, an optional window (serving) ------------
+
+WINDOW_SCOPE = "pio.window_attention"
+GLOBAL_SCOPE = "pio.global_attention"
+
+
+def sweep_blocks(seg_start: jax.Array, block: int,
+                 window: Optional[int] = None):
+    """Per query block of a packed axis, the first key block its sweep
+    visits (it ends at the diagonal): ``lo``, the block that holds the
+    lowest key any of its queries may see — ``max(seg_start[t], t - window
+    + 1)`` of its FIRST query, as neither term ever decreases along the
+    axis — and ``to_start``, the block of that query's history's first
+    token: where a sweep that knew no window would begin.  Both (T / block,)
+    int32; equal without a window."""
+    first = seg_start[::block].astype(jnp.int32)
+    to_start = first // block
+    if window is None:
+        return to_start, to_start
+    q0 = jnp.arange(first.shape[0], dtype=jnp.int32) * block
+    return jnp.maximum(first, q0 - (window - 1)) // block, to_start
+
+
+def sweep_steps(t: int, block: int, window: Optional[int] = None) -> int:
+    """Key blocks one query block can need at most: the grid's inner axis."""
+    n_q = t // block
+    if window is None:
+        return n_q
+    return min(n_q, -(-(window - 1) // block) + 1)
+
+
+def _grouped_kernel(lo_ref, q_ref, low_ref, k_ref, v_ref, o_ref, acc_ref,
+                    m_ref, l_ref, *, scale: float, block: int, group: int):
+    qi, j = pl.program_id(1), pl.program_id(2)
+    kb = lo_ref[qi] + j  # the key block of this step
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(kb <= qi)  # past the diagonal: the same block again, no work
+    def _step():
+        k, v = k_ref[...], v_ref[...]
+        q_pos = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        k_pos = kb * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        # one mask for the group's heads: they share keys and positions
+        mask = (k_pos <= q_pos) & (k_pos >= low_ref[...][:, :1])
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a row with no key in this block keeps m at NEG_INF: exp(0)
+            # must not count its masked entries
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            m_ref[g] = m_new
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def packed_grouped_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, seg_start: jax.Array, *,
+    window: Optional[int] = None, scale: Optional[float] = None,
+    block: Optional[int] = None, interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Grouped-query ``softmax(q.k * scale).v`` over histories PACKED into
+    one token axis, causal within a history and, with ``window``, blind
+    beyond the ``window`` most recent keys (forward only).
+
+    ``q`` (Hq, T, d); ``k``/``v`` (Hkv, T, d), ``Hq`` a multiple of ``Hkv``:
+    query head ``h`` reads key/value head ``h // (Hq / Hkv)``.
+    ``seg_start`` as :func:`packed_causal_attention`'s; token ``t`` attends
+    to ``max(seg_start[t], t - window + 1) <= s <= t``.  ``T`` must be a
+    multiple of the block (256, or ``T`` itself when shorter).
+
+    Grid ``(kv heads, query blocks, key steps)``: a step brings ONE key and
+    one value block into VMEM (never a head's whole K and V: ``T`` is
+    bounded by HBM) and all ``Hq / Hkv`` query heads of the group use it, so
+    K and V cross HBM once per kv head and query block, not once per query
+    head.  A query block's steps start at :func:`sweep_blocks`'s ``lo`` —
+    the block that holds the lowest key the mask lets it see — and end at
+    the diagonal: with a window of 4,096 and blocks of 256 that is at most
+    17 key blocks however long the history, and :func:`sweep_steps` is the
+    grid's inner extent.  A step past the diagonal names the diagonal's
+    block again (no DMA) and does nothing.  The op is named
+    ``pio.window_attention`` with a window and ``pio.global_attention``
+    without.
+    """
+    hq, t, d = q.shape
+    hkv = k.shape[0]
+    if hq % hkv or k.shape != (hkv, t, d) or v.shape != k.shape:
+        raise ValueError(
+            f"q {q.shape} / k {k.shape} / v {v.shape}: the query heads must "
+            "be a multiple of the key/value heads, on one token axis")
+    group = hq // hkv
+    block = block or min(PACKED_BLOCK, t)
+    if t % block:
+        raise ValueError(f"{t} tokens are not a multiple of the block {block}")
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: at least the token itself")
+    scale = float(scale if scale is not None else 1.0 / (d ** 0.5))
+    interpret = pallas_mode.resolve(
+        "window_attention" if window is not None else "global_attention",
+        interpret)
+    lo, _ = sweep_blocks(seg_start, block, window)
+    low = seg_start.astype(jnp.int32)
+    if window is not None:
+        low = jnp.maximum(low, jnp.arange(t, dtype=jnp.int32) - (window - 1))
+    low_lanes = jnp.broadcast_to(low[:, None], (t, _LANES))
+
+    def group_blocks(h, qi, j, lo):
+        return (h, 0, qi, 0)
+
+    def key_block(h, qi, j, lo):
+        return (h, jnp.minimum(lo[qi] + j, qi), 0)
+
+    with jax.named_scope(WINDOW_SCOPE if window is not None
+                         else GLOBAL_SCOPE):
+        o = pl.pallas_call(
+            functools.partial(_grouped_kernel, scale=scale, block=block,
+                              group=group),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(hkv, t // block, sweep_steps(t, block, window)),
+                in_specs=[
+                    pl.BlockSpec((None, group, block, d), group_blocks),
+                    pl.BlockSpec((block, _LANES),
+                                 lambda h, qi, j, lo: (qi, 0)),
+                    pl.BlockSpec((None, block, d), key_block),
+                    pl.BlockSpec((None, block, d), key_block),
+                ],
+                out_specs=pl.BlockSpec((None, group, block, d), group_blocks),
+                scratch_shapes=[
+                    pltpu.VMEM((group, block, d), jnp.float32),
+                    pltpu.VMEM((group, block, 1), jnp.float32),
+                    pltpu.VMEM((group, block, 1), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((hkv, group, t, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(lo, q.reshape(hkv, group, t, d), low_lanes, k, v)
+    return o.reshape(hq, t, d)

@@ -14,9 +14,23 @@ products are in the HLO's metadata but cannot be found by name in a trace:
   (token, expert) assignments are sorted by expert, each expert's rows go
   through its SwiGLU as one group of a grouped matmul, and the results are
   gathered back and combined with the routing weights.  Every assignment
-  is computed: there is no capacity and no dropped token, whatever the
-  skew.  Padded tokens (``valid == False``) are sorted past the last expert
-  and belong to no group, so they touch no expert's weights.
+  to an expert whose weights are HERE is computed: there is no capacity and
+  no dropped token, whatever the skew.  Padded tokens (``valid == False``)
+  are sorted past the last expert and belong to no group, so they touch no
+  expert's weights.
+
+The layer is told which experts it holds: ``w1 / w3 / w2`` are the
+contiguous slice ``[first, first + n_held)`` of the ``n_experts`` the router
+scores (one rank's share under expert parallelism; ``first = 0`` and
+``n_held = n_experts``, the default, is a layer that holds them all).
+Routing is always over all ``n_experts``.  An assignment to an expert held
+elsewhere is sorted past the last group exactly as a padded token is: it
+touches no weight and adds nothing here — what it would add is the other
+ranks' part of the sum, and nothing in this module stands in for them or
+for their exchange.  The rows gathered for the products are then bounded
+by what can be local (:func:`local_row_bound`), not by ``T * top_k``; a
+dispatch whose local assignments exceed the bound runs the products again
+over the next rows, so none is ever dropped.
 
 The grouped matmul is the Pallas TPU kernel JAX ships
 (``jax.experimental.pallas.ops.tpu.megablox.gmm``): one work item per
@@ -63,8 +77,25 @@ def route_sigmoid_topk(
         return picked.astype(jnp.int32), w * scale, sigma
 
 
+# the most one expert's (K, N) weight tile may take of VMEM (it is held
+# twice, for the next work item's prefetch)
+WEIGHT_TILE_BYTES = 4 * 1024 * 1024
+
+
 def _row_tile(m: int) -> int:
     return ROW_TILE if m % ROW_TILE == 0 else m
+
+
+def _col_tile(k: int, n: int, itemsize: int) -> int:
+    """The widest column tile whose ``(k, tile)`` weight tile fits
+    ``WEIGHT_TILE_BYTES``: ``n`` itself where the whole matrix does (every
+    shape before the 3,072 x 3,072 experts), else the largest multiple of
+    128 lanes that divides ``n`` and fits."""
+    if k * n * itemsize <= WEIGHT_TILE_BYTES:
+        return n
+    fits = [c for c in range(128, n, 128)
+            if n % c == 0 and k * c * itemsize <= WEIGHT_TILE_BYTES]
+    return max(fits, default=n)
 
 
 def grouped_matmul(
@@ -84,30 +115,58 @@ def grouped_matmul(
     interpret = pallas_mode.resolve("moe_grouped_matmul", interpret)
     m, k = lhs.shape
     n = rhs.shape[2]
-    # one expert's whole (K, N) weight tile per work item: each touched
-    # expert's weights cross HBM once per row tile that holds its rows
+    # one expert's whole (K, N) weight tile per work item (column tiles of
+    # it where the whole does not fit VMEM): each touched expert's weights
+    # cross HBM once per row tile that holds its rows
     return gmm(
         lhs, rhs, group_sizes.astype(jnp.int32),
         preferred_element_type=jnp.float32,
-        tiling=(_row_tile(m), k, n), interpret=interpret,
+        tiling=(_row_tile(m), k, _col_tile(k, n, rhs.dtype.itemsize)),
+        interpret=interpret,
     )
+
+
+def local_row_bound(n_assignments: int, n_held: int, n_experts: int) -> int:
+    """Rows one pass of the held experts' products gathers: TWICE the held
+    experts' even share of the dispatch's ``T * top_k`` assignments, in
+    whole row tiles, and never more than all of them.  A pass is exact
+    whatever the bound; a dispatch with more local assignments takes
+    another pass."""
+    if n_held >= n_experts:
+        return n_assignments
+    share = -(-n_assignments * n_held // n_experts)
+    return min(n_assignments, -(-2 * share // ROW_TILE) * ROW_TILE)
 
 
 def expert_products(
     x: jax.Array, picked: jax.Array, weights: jax.Array,
     w1: jax.Array, w3: jax.Array, w2: jax.Array,
-    valid: Optional[jax.Array] = None, *, interpret: Optional[bool] = None,
+    valid: Optional[jax.Array] = None, *, first: int = 0,
+    n_experts: Optional[int] = None, max_local_rows: Optional[int] = None,
+    interpret: Optional[bool] = None,
 ):
-    """``sum_j weights[t, j] * SwiGLU_{picked[t, j]}(x[t])`` for every token.
+    """``sum_j weights[t, j] * SwiGLU_{picked[t, j]}(x[t])`` for every token,
+    over the picks whose expert is held here.
 
-    ``x`` (T, D); ``picked``/``weights`` (T, k); ``w1``/``w3`` (E, D, F) and
-    ``w2`` (E, F, D) in the compute dtype of ``x``.  Returns ``y`` (T, D) f32
-    (zero rows for padded tokens) and ``counts`` (E,) int32, the valid
-    assignments each expert received — the dispatch's load, and which
-    experts' weights it touched.
+    ``x`` (T, D); ``picked``/``weights`` (T, k), ``picked`` in the router's
+    ``[0, n_experts)``; ``w1``/``w3`` (n_held, D, F) and ``w2`` (n_held, F,
+    D) in the compute dtype of ``x``: the router's experts ``[first, first
+    + n_held)``.  ``n_experts`` None: all are held (``first`` 0).  Returns
+    ``y`` (T, D) f32 (zero rows for padded tokens and for tokens none of
+    whose picks is held) and ``counts`` (n_held,) int32, the valid
+    assignments each HELD expert received — the dispatch's load here, and
+    which of the held experts' weights it touched.  ``max_local_rows``
+    overrides :func:`local_row_bound` (tests).
     """
+    n_held = w1.shape[0]
+    if first or (n_experts is not None and n_experts != n_held):
+        return _held_products(
+            x, picked, weights, w1, w3, w2, valid, first=first,
+            bound=max_local_rows or local_row_bound(
+                picked.size, n_held, n_experts or first + n_held),
+            interpret=interpret)
     t, _ = x.shape
-    n_experts = w1.shape[0]
+    n_experts = n_held
     k = picked.shape[1]
     with jax.named_scope(EXPERTS_SCOPE):
         flat = picked.reshape(-1)
@@ -127,4 +186,54 @@ def expert_products(
         if valid is not None:
             # a padded token's rows lie past the last group: never written
             y = jnp.where(valid[:, None], y, 0.0)
+        return y, counts
+
+
+def _held_products(x, picked, weights, w1, w3, w2, valid, *, first: int,
+                   bound: int, interpret):
+    """:func:`expert_products` for a layer that holds the router's experts
+    ``[first, first + n_held)`` only.  Assignments are sorted held-first by
+    expert; ``bound`` rows at a time go through the grouped products (one
+    pass unless the local assignments exceed it), each pass's group sizes
+    the part of every expert's run that falls in its rows."""
+    t, d = x.shape
+    n_held, k = w1.shape[0], picked.shape[1]
+    with jax.named_scope(EXPERTS_SCOPE):
+        local = picked - first
+        held = (local >= 0) & (local < n_held)
+        if valid is not None:
+            held &= valid[:, None]
+        flat = jnp.where(held, local, n_held).reshape(-1)
+        counts = jnp.zeros((n_held + 1,), jnp.int32).at[flat].add(1)[:n_held]
+        n_local = jnp.sum(counts)
+        order = jnp.argsort(flat, stable=True)  # held first, by expert
+        back = jnp.argsort(order).reshape(t, k)  # where each assignment went
+        order = jnp.pad(order, (0, -order.shape[0] % bound))
+        ends = jnp.cumsum(counts)
+        w = weights.astype(jnp.float32)
+
+        def one_pass(c, y):
+            at = c * bound
+            xs = x[jax.lax.dynamic_slice(order, (at,), (bound,)) // k]
+            sizes = (jnp.clip(ends, at, at + bound)
+                     - jnp.clip(ends - counts, at, at + bound))
+            # the scope again, INSIDE the loop's body: a Pallas call takes
+            # the innermost name, which would otherwise be the loop's `body`
+            with jax.named_scope(EXPERTS_SCOPE):
+                gate = grouped_matmul(xs, w1, sizes, interpret=interpret)
+                up = grouped_matmul(xs, w3, sizes, interpret=interpret)
+                h = (jax.nn.silu(gate) * up).astype(x.dtype)
+                ys = grouped_matmul(h, w2, sizes, interpret=interpret)
+            for j in range(k):  # a (T, D) gather a pick, never (T * k, D)
+                pos = back[:, j] - at
+                here = held[:, j] & (pos >= 0) & (pos < bound)
+                # rows past the last group are never written: select, do
+                # not multiply
+                y = y + jnp.where(
+                    here[:, None],
+                    ys[jnp.clip(pos, 0, bound - 1)] * w[:, j:j + 1], 0.0)
+            return y
+
+        y = jax.lax.fori_loop(0, -(-n_local // bound), one_pass,
+                              jnp.zeros((t, d), jnp.float32))
         return y, counts

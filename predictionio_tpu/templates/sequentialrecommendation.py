@@ -7,18 +7,21 @@ history is read live from the event store (same pattern as the e-commerce
 template's serving-time lookups) so recommendations track events newer than
 the model.
 
-Three algorithms share the template and its one history seam
+Four algorithms share the template and its one history seam
 (:class:`EventStoreHistory` unless the model or the algorithm carries
 another provider): ``sasrec``, the small trained transformer, served one
-query at a time from the host; and two packed sequence families at
+query at a time from the host; and three packed sequence families at
 published widths that serve through ``deploy --batching`` — the batcher's
 rows are packed into one dispatch of a resident, ahead-of-time compiled
 device program (:mod:`predictionio_tpu.serving.seqpath`, ONE scorer class
-for both): ``latentmoe`` (:class:`LatentMoEAlgorithm`), a latent-attention
-sparse-expert stack (:mod:`predictionio_tpu.models.latent_moe`), and
+for all): ``latentmoe`` (:class:`LatentMoEAlgorithm`), a latent-attention
+sparse-expert stack (:mod:`predictionio_tpu.models.latent_moe`),
 ``gdnhybrid`` (:class:`GDNHybridAlgorithm`), gated-delta-rule
 linear-attention layers interleaved with full-attention layers
-(:mod:`predictionio_tpu.models.gdn_hybrid`).  What a packed family needs of
+(:mod:`predictionio_tpu.models.gdn_hybrid`), and ``windowmoe``
+(:class:`WindowMoEAlgorithm`), window and global grouped-query attention
+over sparse experts of which a model may hold a slice
+(:mod:`predictionio_tpu.models.window_moe`).  What a packed family needs of
 an algorithm is :class:`PackedSequenceAlgorithm`'s; a family adds its
 model module's name.
 """
@@ -367,6 +370,13 @@ class GDNHybridAlgorithm(PackedSequenceAlgorithm):
     family = "predictionio_tpu.models.gdn_hybrid"
 
 
+class WindowMoEAlgorithm(PackedSequenceAlgorithm):
+    """The window/global-attention sparse-expert recommender
+    (``windowmoe``)."""
+
+    family = "predictionio_tpu.models.window_moe"
+
+
 class SequentialRecommendationEngine(EngineFactory):
     @classmethod
     def apply(cls) -> Engine:
@@ -377,6 +387,7 @@ class SequentialRecommendationEngine(EngineFactory):
                 "sasrec": SASRecAlgorithm,
                 "latentmoe": LatentMoEAlgorithm,
                 "gdnhybrid": GDNHybridAlgorithm,
+                "windowmoe": WindowMoEAlgorithm,
             },
             serving_cls=FirstServing,
             query_cls=Query,
