@@ -108,6 +108,18 @@ def bridge_batcher(
                 [("", (), _num(s.get("joined_rows")))],
             ),
             _fam(
+                "pio_batcher_ahead_batches_total", "counter",
+                "Dispatches whose program was launched before the previous "
+                "dispatch's device_compute returned (launch-ahead).",
+                [("", (), _num(s.get("ahead_batches")))],
+            ),
+            _fam(
+                "pio_batcher_ahead_missed_total", "counter",
+                "Times a row waited and a launch instant was known, but "
+                "the run in flight ended before the launch was made.",
+                [("", (), _num(s.get("ahead_missed")))],
+            ),
+            _fam(
                 "pio_batcher_rounded_up_batches_total", "counter",
                 "Dispatches that ran short of their rung: rows between two "
                 "rungs run as one, padded by the scorer.",

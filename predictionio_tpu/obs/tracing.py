@@ -148,12 +148,19 @@ class Trace:
 class Dispatch:
     """One batch run of the micro-batcher: a fixed-size record.
 
-    Written only by the thread that holds the batcher for the run (no
-    lock); the stage keys exist from the start, so a reader that
-    serializes a run still in flight never sees the dict change size.
-    ``collect`` and ``resolve`` are set by the batcher, the rest by
-    :func:`stage`; ``postprocess`` also takes whatever part of the run no
-    stage covered, so the stages tile the record's wall.
+    Written only by the thread that runs it (no lock; two runs may overlap,
+    each on its own thread with its own record); the stage keys exist from
+    the start, so a reader that serializes a run still in flight never sees
+    the dict change size.  ``collect`` and ``resolve`` are set by the
+    batcher, the rest by :func:`stage`; ``postprocess`` also takes whatever
+    part of the run no stage covered, so the stages tile the record's wall.
+
+    The scorer tells the batcher when the run's device program is enqueued
+    through the record: it sets ``rung`` and ``lag`` (and ``more``, while
+    launches of the same run are still to come) before :func:`launch`, which stamps
+    ``t_launch`` / ``t_enqueued`` and calls ``on_launch``.  A run launched
+    while another was in flight has that run's record as ``behind`` until
+    its own end.
     """
 
     STAGES = (
@@ -166,6 +173,8 @@ class Dispatch:
         "rows", "rung", "merge_passes", "carried", "padded", "depth_end",
         "stages",
         "dc_start", "dc_end", "wall_s", "error", "slow_after_s",
+        "more", "lag", "t_launch", "t_enqueued", "behind", "ahead_s",
+        "on_launch",
     )
 
     def __init__(self, seq: int, inline: bool, rows: int, carried: int,
@@ -193,9 +202,29 @@ class Dispatch:
         self.dc_end: Optional[float] = None
         self.wall_s: Optional[float] = None
         self.error: Optional[str] = None
+        # the scorer has further launches to make in this run (rows past
+        # the top rung): no estimate of the run's end can be made from
+        # this one, so it is not stamped
+        self.more = False
+        # how long after a program's end the host hears of it, as the
+        # scorer measured it at warm-up (0: it did not say)
+        self.lag = 0.0
+        # the last launch of the run: the jitted call entered / returned
+        # (the program is enqueued).  None until then, and for good where
+        # nothing launches through :func:`launch`
+        self.t_launch: Optional[float] = None
+        self.t_enqueued: Optional[float] = None
+        # the run in flight this one was cut and launched behind (dropped
+        # at its end, or the records would chain for ever); set at the
+        # end: how long before that run's device_compute returned its
+        # program was launched (positive: ahead of it); the batcher's
+        # wake-up of whoever waits to launch behind THIS one
+        self.behind: Optional["Dispatch"] = None
+        self.ahead_s: Optional[float] = None
+        self.on_launch = None
 
     def add_stage(self, name: str, t0: float, t1: float) -> None:
-        # one writer: the thread that holds the batcher for this run
+        # one writer: the thread that runs this dispatch
         self.stages[name] = self.stages.get(name, 0.0) + (t1 - t0)  # pio: ignore[race-unguarded-rmw]
         if name == "device_compute":
             if self.dc_start is None:
@@ -218,6 +247,15 @@ class Dispatch:
             "carriedRows": self.carried,
             "paddedRows": self.padded,
             "depthAtEnd": self.depth_end,
+            # in flight: it was launched behind a run; after: ahead of it
+            "launchedAhead": (
+                self.behind is not None if self.ahead_s is None
+                else self.ahead_s > 0
+            ),
+            "aheadMs": (
+                None if self.ahead_s is None
+                else round(self.ahead_s * 1e3, 4)
+            ),
             "slowAfterMs": round(self.slow_after_s * 1e3, 4),
             "wallMs": (
                 None if self.wall_s is None
@@ -290,12 +328,25 @@ def _ids(disp: Optional[Dispatch]) -> dict:
     return {"seq": disp.seq, "rung": disp.rung}
 
 
+@contextlib.contextmanager
 def launch():
     """``pio.launch(seq=, rung=)``: a scorer's jitted call until it returns,
     which is the enqueue; the wait for the device is the rest of the
     ``pio.device_compute`` stage it lies in.  On the profiler's clock only:
-    it is charged to no trace and no record."""
-    return annotation("pio.launch", **_ids(getattr(_active, "dispatch", None)))
+    it is charged to no trace and no stage.  The active dispatch record is
+    told both instants (unless the scorer said ``more`` launches follow),
+    and whoever waits to launch behind this run is woken."""
+    disp = getattr(_active, "dispatch", None)
+    last = disp is not None and not disp.more
+    if last:
+        disp.t_enqueued = None
+        disp.t_launch = time.perf_counter()
+    with annotation("pio.launch", **_ids(disp)):
+        yield
+    if last:
+        disp.t_enqueued = time.perf_counter()
+        if disp.on_launch is not None:
+            disp.on_launch()
 
 
 @contextlib.contextmanager

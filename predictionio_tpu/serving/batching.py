@@ -18,11 +18,29 @@ There is NO accumulation window: a free device is never held.
   executes inline on its own handler thread — zero added latency over
   the unbatched path.  Batches form exactly when they can help: while a
   run is in flight, arrivals queue up and dispatch together.
-* THE WORKER WAITS FOR THE RUN IN FLIGHT, never for a clock.  With a
+* A WORKER WAITS FOR THE RUN IN FLIGHT, never for a clock.  With a
   first row in hand it takes ``_busy`` — which blocks exactly while a run
   is in flight, the only time waiting is free — drains what queued up
   meanwhile and runs.  With the device free that is immediate: a row's
   wait is the rest of the run in flight, the hand-off, its own run.
+* LAUNCH-AHEAD: THE NEXT RUN IS LAUNCHED BEFORE THE ONE IN FLIGHT ENDS.
+  JAX's dispatch is asynchronous and the device runs enqueued programs in
+  order, so with rows waiting the next run is collected, cut and launched
+  by the OTHER of two workers shortly before the run in flight is due —
+  (when its program could start) + (what ``device_compute`` has been
+  taking at the rung its scorer named) − (what a worker has been taking
+  from the go to the enqueue), each the least of its newest ``RUNS_KEPT``
+  readings, aimed at the program's END by the lag the scorer measured
+  between an end and the host hearing of it
+  (:meth:`MicroBatcher._behind_in`) — and the host's handling of
+  the earlier run (wake-up, readback, ``postprocess``, ``resolve``)
+  overlaps the later one's device time instead of preceding its launch.
+  The cut stays as late as it can; at most ONE run is queued behind the
+  one in flight (:class:`_Flight`); a rung with no estimate yet, or a run
+  whose scorer launches nothing through ``obs.tracing.launch()``, is
+  waited out as before, and a lone request on a free device still runs
+  inline.  Whether two programs' temporaries fit the device together is
+  the scorer's to know (``serving/launch_gate.py``).
 * A NEWCOMER JOINS THE ROWS THAT WAIT.  One that finds the device free
   but older rows waiting (the instant of a hand-off) queues behind them
   and leaves in their dispatch (``joined_rows``): no dispatch runs while
@@ -52,13 +70,16 @@ inherit a 504 they didn't earn.
 
 ONE RECORD PER DISPATCH, always on: every batch run gets a sequence
 number and an :class:`obs.tracing.Dispatch` (who ran it, rows, rung, rows
-the cut carried or the rung padded, the wall of each stage), kept in a
+the cut carried or the rung padded, whether it was launched ahead and by
+how much, the wall of each stage), kept in a
 bounded ring that ``GET /trace/dispatches.json`` serves and summed into
 :meth:`stats`.  A run
 that holds the batcher past ``max(SLOW_FLOOR_S, SLOW_MULT x EWMA(run))``
 has every thread's stack written to the server's log by
 ``faulthandler``'s watchdog (a C thread: it fires even if the stalled
-thread never releases the GIL) and its record kept in a second ring.
+thread never releases the GIL; ONE timer a process, kept armed for
+whichever run in flight is due first) and its record kept in a second
+ring.
 """
 
 from __future__ import annotations
@@ -73,6 +94,7 @@ import threading
 import time
 import traceback
 from dataclasses import dataclass, field
+from time import monotonic as _monotonic
 from typing import Any, Callable, Optional
 
 from predictionio_tpu.common.resilience import Deadline, DeadlineExceeded
@@ -104,6 +126,55 @@ class _Pending:
     # coalescable) and the identical-query followers its result fans out to
     key: Any = None
     followers: list = field(default_factory=list)
+
+
+class _Flight:
+    """The right to launch a run: the lock ``_busy`` was, made for two.
+
+    Lock-shaped (``acquire`` / ``release`` / ``locked``), and a lock for
+    everyone but a worker: an arrival's non-blocking try succeeds only
+    with no run in flight, and every run releases once at its end, on
+    whatever thread.  A BLOCKING acquire also succeeds behind ONE run in
+    flight, at the instant ``behind_in`` names for it (seconds from now and
+    that run's record, or None while nothing can be said): it then returns
+    the record instead of True, and two runs are in flight until either
+    ends.  Never three: one program queued behind the one running.
+    ``turn`` is notified at every release, and by the batcher when a run
+    in flight has launched.
+    """
+
+    def __init__(self, behind_in: Callable[[], Optional[tuple]]):
+        self.turn = threading.Condition()
+        self._behind_in = behind_in
+        self._runs = 0
+
+    def locked(self) -> bool:
+        return self._runs > 0
+
+    def acquire(self, blocking: bool = True, timeout: float = -1):
+        with self.turn:
+            end = None if timeout < 0 else _monotonic() + timeout
+            while self._runs:
+                if not blocking:
+                    return False
+                plan = self._behind_in() if self._runs == 1 else None
+                if plan is not None and plan[0] <= 0:
+                    self._runs = 2
+                    return plan[1]
+                wait = None if plan is None else plan[0]
+                if end is not None:
+                    left = end - _monotonic()
+                    if left <= 0:
+                        return False
+                    wait = left if wait is None else min(wait, left)
+                self.turn.wait(wait)
+            self._runs = 1
+            return True
+
+    def release(self) -> None:
+        with self.turn:
+            self._runs -= 1
+            self.turn.notify_all()
 
 
 class MicroBatcher:
@@ -149,8 +220,14 @@ class MicroBatcher:
         # what a run has been taking (the slow-run threshold reads it); 0
         # until the first run returns
         self._ewma_run = 0.0
-        # held for the duration of every batch run (worker or inline)
-        self._busy = threading.Lock()
+        # the right to launch: held by every batch run (worker or inline)
+        # for its duration, by two at once only where the second was
+        # launched ahead (_Flight); _turn is its condition
+        self._busy = _Flight(self._behind_in)
+        self._turn = self._busy.turn
+        # one worker collects (first row, the wait for _busy, drain, cut)
+        # while the other may still be in its run
+        self._collecting = threading.Lock()
         # single-flight: key → leader pending currently in flight.  The
         # lock guards the map AND every leader's followers list; delivery
         # pops the key first, so a follower can never attach to a pending
@@ -166,22 +243,27 @@ class MicroBatcher:
         self._n_expired = 0  # pendings dropped un-executed (deadline lapsed)
         self._size_hist: collections.Counter = collections.Counter()
         self._wait_s_total = 0.0
-        # dispatch records.  Written only by the thread that holds _busy
-        # for the run; the sums below move under _stats_lock with the rest
+        # dispatch records.  A record is written by the thread that runs
+        # it; everything below that two overlapping runs share moves under
+        # _stats_lock
         self._seq = 0  # dispatches started == seq of the newest
-        self._done = 0  # seq of the newest dispatch whose run returned
+        self._done = 0  # the highest seq whose run has returned
         self._ring: collections.deque = collections.deque(maxlen=self.RING)
         self._slow_ring: collections.deque = collections.deque(
             maxlen=self.SLOW_RING
         )
-        self._current: Optional[_tracing.Dispatch] = None  # holds _busy now
+        # the runs in flight, oldest first: one, or two where the second
+        # was launched ahead
+        self._flying: list[_tracing.Dispatch] = []
+        # of the newest run (by seq) that has ended: what the next one,
+        # started on a free device, measures its turnaround and gap from
+        self._prev_seq = 0
         self._prev_dc_end: Optional[float] = None
         self._prev_left_work = False
         self._carried_rows = 0
         # what the cut decides from (see _cut): the newest runs at each
         # rung as (seq, seconds), and the newest gaps between a run's end
-        # and the next one's start with rows waiting.  Written under _busy
-        # AND _stats_lock; _cut reads under _busy, stats() under the lock
+        # and the next one's start with rows waiting (under _stats_lock)
         self._rung_runs = {
             b: collections.deque(maxlen=self.RUNS_KEPT) for b in self.buckets
         }
@@ -189,6 +271,18 @@ class MicroBatcher:
             maxlen=self.RUNS_KEPT
         )
         self._prev_run_end: Optional[float] = None
+        # what launch-ahead decides from (see _behind_in): per rung AS THE
+        # SCORER NAMED IT (a token count for the packed families, which the
+        # row-count rungs above mix), the newest (seq, seconds) its program
+        # took on the device; and the newest times a worker took from the
+        # go to the enqueue
+        self._launch_runs: dict[int, collections.deque] = {}
+        self._leads: collections.deque = collections.deque(
+            maxlen=self.RUNS_KEPT
+        )
+        self._planned = False  # the collecting worker saw a launch instant
+        self._n_ahead = 0
+        self._n_ahead_missed = 0
         self._n_rounded_up = 0
         self._padded_rows = 0
         self._run_s_sum = 0.0
@@ -197,10 +291,14 @@ class MicroBatcher:
         self._turnaround_s_sum = 0.0
         self._turnaround_n = 0
         self._n_slow = 0
-        self._worker = threading.Thread(
-            target=self._loop, name="query-microbatcher", daemon=True
-        )
-        self._worker.start()
+        self._workers = [
+            threading.Thread(
+                target=self._loop, name="query-microbatcher", daemon=True
+            )
+            for _ in range(2)
+        ]
+        for w in self._workers:
+            w.start()
 
     def submit(
         self,
@@ -304,9 +402,11 @@ class MicroBatcher:
 
     def stop(self) -> None:
         self._stop.set()
-        self._worker.join(timeout=5)
+        for w in self._workers:
+            w.join(timeout=5)
         # wake anything still waiting so handlers fail fast, not on
-        # timeout (the worker has put back what it had in hand)
+        # timeout (a worker has put back what it had in hand; runs in
+        # flight deliver to their own waiters when they return)
         with self._arr_lock:
             pending = list(self._carry)
             self._carry.clear()
@@ -329,11 +429,11 @@ class MicroBatcher:
         """Per-batch latency/size/occupancy counters (``GET /`` stats)."""
         with self._stats_lock:
             n_b, n_q = self._n_batches, self._n_queries
-            cur = self._current
             # a run past its threshold counts while it still holds the
             # batcher: a stall shows here before (or without) its end
-            stalled = cur is not None and (
-                time.perf_counter() - cur.t_run > cur.slow_after_s
+            now = time.perf_counter()
+            stalled = sum(
+                now - r.t_run > r.slow_after_s for r in self._flying
             )
             return {
                 "batches": n_b,
@@ -363,34 +463,51 @@ class MicroBatcher:
                     if (t := self._rung_s(r)) is not None
                 },
                 "run_gap_ms": round(min(self._run_gaps, default=0.0) * 1e3, 4),
+                # launch-ahead: dispatches whose program was launched
+                # before the previous device_compute returned; times a
+                # row waited and a launch instant was known but the run
+                # in flight ended first; and what the instant is made of:
+                # per scorer rung a program there, and a worker's lead
+                "ahead_batches": self._n_ahead,
+                "ahead_missed": self._n_ahead_missed,
+                "launch_run_ms": {
+                    str(r): round(t * 1e3, 4)
+                    for r in sorted(self._launch_runs)
+                    if (t := self._launch_s(r)) is not None
+                },
+                "launch_lead_ms": round(
+                    min(self._leads, default=0.0) * 1e3, 4),
                 "run_ms_sum": round(self._run_s_sum * 1e3, 4),
                 "run_ms_max": round(self._run_s_max * 1e3, 4),
                 "run_ms_max_seq": self._run_max_seq,
                 "turnaround_ms_sum": round(self._turnaround_s_sum * 1e3, 4),
                 "turnaround_n": self._turnaround_n,
-                "slow_dispatches": self._n_slow + (1 if stalled else 0),
+                "slow_dispatches": self._n_slow + stalled,
             }
 
     def dispatches(self, limit: Optional[int] = None) -> dict:
         """The dispatch rings, newest first (``GET /trace/dispatches.json``).
 
-        ``inFlight`` is the run holding the batcher right now, with the
-        stages it has finished and, read here on the caller's thread, the
-        stack its thread sits in.
+        ``inFlight`` is the oldest run in flight right now (the one whose
+        program the device has), ``inFlightAhead`` the run launched behind
+        it, if any: each with the stages it has finished and, read here on
+        the caller's thread, the stack its thread sits in.
         """
         recent, slow = list(self._ring), list(self._slow_ring)
         if limit:
             recent = recent[-limit:]
-        in_flight = None
-        rec = self._current
-        if rec is not None:
-            in_flight = rec.to_dict()
-            in_flight["heldMs"] = round(
+        with self._stats_lock:
+            flying = list(self._flying)
+        frames = sys._current_frames() if flying else {}
+        in_flight = [None, None]
+        for i, rec in enumerate(flying[:2]):
+            view = in_flight[i] = rec.to_dict()
+            view["heldMs"] = round(
                 (time.perf_counter() - rec.t_run) * 1e3, 4
             )
-            frame = sys._current_frames().get(rec.thread_id)
+            frame = frames.get(rec.thread_id)
             if frame is not None:
-                in_flight["stack"] = [
+                view["stack"] = [
                     line.rstrip() for line in traceback.format_stack(frame)
                 ]
         return {
@@ -399,7 +516,8 @@ class MicroBatcher:
             "started": self._seq,
             "dispatches": [r.to_dict() for r in reversed(recent)],
             "slow": [r.to_dict() for r in reversed(slow)],
-            "inFlight": in_flight,
+            "inFlight": in_flight[0],
+            "inFlightAhead": in_flight[1],
         }
 
     # -- worker -------------------------------------------------------------
@@ -434,10 +552,56 @@ class MicroBatcher:
         it once — which is also how an estimate whose only sample was a
         pause is replaced.
         """
-        runs = self._rung_runs[rung]
+        return self._least(self._rung_runs[rung])
+
+    def _least(self, runs) -> Optional[float]:
         if not runs or runs[-1][0] <= self._seq - self.RING:
             return None
         return min(dt for _, dt in runs)
+
+    def _launch_s(self, rung) -> Optional[float]:
+        """What a program at ``rung``, as the SCORER names it, takes on the
+        device: the least of the newest ``RUNS_KEPT`` readings, as
+        :meth:`_rung_s` and for its reasons; None for a rung not run
+        within the last ``RING`` dispatches (it is then waited out, which
+        teaches it)."""
+        return self._least(self._launch_runs.get(rung))
+
+    def _behind_in(self) -> Optional[tuple]:
+        """(seconds until a run may be launched behind the ONE in flight,
+        that run's record), or None while no instant can be named: its
+        program is not enqueued yet (or its scorer has more launches to
+        make, or launches nothing through ``obs.tracing.launch()``), or
+        its rung has no estimate.
+
+        The instant is as late as it can be, so that the cut is: (when the
+        program in flight could start) + (what a program at its rung
+        takes) − (what a worker has been taking from here to its own
+        enqueue).  That aims the enqueue at the END of the program in
+        flight.  The host never sees an end: it hears of it ``lag`` later
+        (the scorer's measurement, on the record).  So a program queued
+        behind the run before it could start a lag before that run's
+        RETURN; one enqueued on a free device is counted from its enqueue
+        (its readings have the lag taken out).  A run that ends earlier costs
+        nothing against waiting it out; one that ends later (a pause)
+        leaves the next one queued on the device behind it, already cut:
+        bounded by one run.  Called by :class:`_Flight` under its
+        condition, on the collecting worker's thread.
+        """
+        with self._stats_lock:
+            if len(self._flying) != 1:
+                return None
+            cur = self._flying[0]
+            start = cur.t_enqueued
+            run_s = None if start is None else self._launch_s(cur.rung)
+            if run_s is None:
+                return None
+            before = cur.behind
+            if before is not None and before.dc_end is not None:
+                start = max(start, before.dc_end - cur.lag)
+            lead = min(self._leads, default=0.0)
+        self._planned = True
+        return start + run_s - lead - time.perf_counter(), cur
 
     def _cut(self, n: int) -> int:
         """How many of ``n`` rows in hand to run now; the rest is carried.
@@ -456,57 +620,74 @@ class MicroBatcher:
         if self.buckets[i] == n or i == 0:
             return n
         lo, hi = self.buckets[i - 1], self.buckets[i]
-        t_lo, t_hi = self._rung_s(lo), self._rung_s(hi)
+        with self._stats_lock:  # a run in flight may end meanwhile
+            t_lo, t_hi = self._rung_s(lo), self._rung_s(hi)
+            t_rest = self._rung_s(self._rung_of(n - lo))
+            gap = min(self._run_gaps, default=0.0)
         if t_hi is None:
             return n
         if t_lo is None:
             return lo
         # the rest's rung is at most lo: until it has run, t(lo) bounds it
-        t_rest = self._rung_s(self._rung_of(n - lo))
         if t_rest is None:
             t_rest = t_lo
-        gap = min(self._run_gaps, default=0.0)
         together = n * t_hi
         apart = lo * t_lo + (n - lo) * (t_lo + gap + t_rest)
         return n if together <= apart else lo
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            first = self._next(idle_s=0.1)
-            if first is None:
-                continue
-            t_first = time.perf_counter()
-            batch = [first]
-            # a run in flight (inline: the worker's own have returned) is
-            # all the worker waits for, and arrivals pile up behind it;
-            # with the device free this is immediate.  The timeout is
-            # stop()'s, which fails the rows put back.  On the profiler's
-            # clock: first row taken -> _busy held
-            with _tracing.annotation("pio.collect"):
-                while not self._busy.acquire(timeout=0.1):
-                    if self._stop.is_set():
-                        self._carry.extendleft(reversed(batch))
-                        return
-            try:
-                # against the arrivals' decision: a row is either drained
-                # here or put once this run is in flight
-                with self._arr_lock:
-                    while len(batch) < self.max_batch:
-                        nxt = self._next()
-                        if nxt is None:
-                            break
-                        batch.append(nxt)
-                    # run them all, rounded up to the next rung, or cut at
-                    # the rung below: the tail then leads the next batch
-                    size = self._cut(len(batch))
-                    carried = len(batch) - size
-                    self._carry.extendleft(reversed(batch[size:]))
-                    batch = batch[:size]
-                    self._waiting -= size
-                waited = time.perf_counter() - t_first
-                self._execute(batch, waited, carried=carried)
-            finally:
-                self._busy.release()
+            with self._collecting:
+                got = self._collect()
+            if got is not None:
+                try:
+                    self._execute(*got)
+                finally:
+                    self._busy.release()
+
+    def _collect(self) -> Optional[tuple]:
+        """Take the next batch in hand and the right to launch it:
+        :meth:`_execute`'s arguments, or None (nothing arrived, or
+        ``stop()``).  One worker at a time (``_collecting``)."""
+        first = self._next(idle_s=0.1)
+        if first is None:
+            return None
+        t_first = time.perf_counter()
+        batch = [first]
+        self._planned = False
+        # a run in flight is all a worker waits for, and arrivals pile up
+        # behind it; with the device free this is immediate, and behind ONE
+        # run in flight it ends at the launch instant (_behind_in).  The
+        # timeout is stop()'s, which fails the rows put back.  On the
+        # profiler's clock: first row taken -> _busy held
+        with _tracing.annotation("pio.collect"):
+            while not (got := self._busy.acquire(timeout=0.1)):
+                if self._stop.is_set():
+                    self._carry.extendleft(reversed(batch))
+                    return None
+        t_go = time.perf_counter()
+        behind = None if got is True else got
+        if behind is None and self._planned:
+            # an instant was known, and the run ended before it came
+            with self._stats_lock:
+                self._n_ahead_missed += 1
+        # against the arrivals' decision: a row is either drained here or
+        # put once this run is in flight
+        with self._arr_lock:
+            while len(batch) < self.max_batch:
+                nxt = self._next()
+                if nxt is None:
+                    break
+                batch.append(nxt)
+            # run them all, rounded up to the next rung, or cut at the
+            # rung below: the tail then leads the next batch
+            size = self._cut(len(batch))
+            carried = len(batch) - size
+            self._carry.extendleft(reversed(batch[size:]))
+            batch = batch[:size]
+            self._waiting -= size
+        waited = time.perf_counter() - t_first
+        return batch, waited, False, carried, behind, t_go
 
     def _resolve(
         self,
@@ -572,23 +753,32 @@ class MicroBatcher:
             self._n_expired += 1 + len(dead)
         return promoted
 
-    def _arm_watchdog(self, threshold: float) -> bool:
-        """Have every thread's stack written once if this run is still
-        going after ``threshold`` seconds.  faulthandler's watchdog is a C
-        thread, so it fires even when the stalled thread holds the GIL; it
-        is one per process (a second batcher's run re-arms it) and lists
-        the newest 100 threads."""
+    def _watch(self) -> bool:
+        """Have every thread's stack written once if a run in flight is
+        still going when its threshold passes: (re-)arm the watchdog for
+        whichever is due first, or cancel it with none left to watch.
+        faulthandler's is a C thread, so it fires even when the stalled
+        thread holds the GIL; it is ONE timer a process (a second
+        batcher's run re-arms it), which is why two overlapping runs share
+        it here, under ``_stats_lock``; and it lists the newest 100
+        threads.  A run already past its threshold has had its dump."""
+        now = time.perf_counter()
+        due = [d for r in self._flying
+               if (d := r.t_run + r.slow_after_s - now) > 0]
         try:
-            faulthandler.dump_traceback_later(
-                threshold, file=self.SLOW_DUMP_FILE or sys.stderr
-            )
+            faulthandler.cancel_dump_traceback_later()
+            if due:
+                faulthandler.dump_traceback_later(
+                    min(due), file=self.SLOW_DUMP_FILE or sys.stderr
+                )
         except (AttributeError, OSError, ValueError):
             return False  # the log has no file descriptor: count, no dump
         return True
 
     def _execute(
         self, batch: list, waited: float, inline: bool = False,
-        carried: int = 0,
+        carried: int = 0, behind: Optional[_tracing.Dispatch] = None,
+        t_go: Optional[float] = None,
     ) -> None:
         """Run one batch and deliver results/errors to every waiter.
 
@@ -596,8 +786,10 @@ class MicroBatcher:
         already raised (or are about to), so executing them would spend a
         device pass on a result nobody will read.
 
-        The caller holds ``_busy``: the dispatch record and the counters
-        derived from it have this thread as their only writer.
+        The caller holds ``_busy`` for this run, alone or (``behind``: the
+        record of the run in flight it was launched behind, at ``t_go``)
+        with that run.  The record has this thread as its only writer;
+        what two overlapping runs share moves under ``_stats_lock``.
         """
         t_in = time.perf_counter()
         live, expired = [], []
@@ -620,17 +812,29 @@ class MicroBatcher:
         if not batch:
             return
         t_run = time.perf_counter()
-        seq = self._seq = self._seq + 1
         # collect: first row taken -> the run starts (the wait for _busy,
         # the drain and the cut)
         threshold = max(self.SLOW_FLOOR_S, self.SLOW_MULT * self._ewma_run)
         # the rung these rows round up to (after the deadline drop)
         rung = self._rung_of(len(batch))
-        rec = _tracing.Dispatch(
-            seq, inline, len(batch), carried, t_run,
-            collect_s=waited + (t_run - t_in), slow_after_s=threshold,
-            padded=rung - len(batch),
-        )
+        with self._stats_lock:
+            # one thread at a time is between taking _busy and its launch
+            # (a second may take it only behind a run that HAS launched),
+            # so seq follows the order of the launches
+            seq = self._seq = self._seq + 1
+            rec = _tracing.Dispatch(
+                seq, inline, len(batch), carried, t_run,
+                collect_s=waited + (t_run - t_in), slow_after_s=threshold,
+                padded=rung - len(batch),
+            )
+            rec.behind = behind
+            rec.on_launch = self._launched
+            self._flying.append(rec)
+            armed = self._watch()
+            # what a run on a free device follows, as of NOW: a run launched
+            # behind this one may end, and move them, before this one does
+            prev_dc_end, prev_run_end = self._prev_dc_end, self._prev_run_end
+            prev_left_work = self._prev_left_work
         traces = [p.trace for p in batch if p.trace is not None]
         for p in batch:
             if p.trace is not None:
@@ -640,7 +844,7 @@ class MicroBatcher:
                 # flight-recorder context: how this request's batch formed,
                 # which dispatch ran it and how many it waited through (1
                 # inline, 2 behind the run in flight, 3 when the cut
-                # carried it)
+                # carried it or the run in flight had one queued behind it)
                 p.trace.annotate(
                     batch=len(batch),
                     dispatch="inline" if inline else "window",
@@ -648,8 +852,6 @@ class MicroBatcher:
                     passes=seq - p.done_at_enq,
                     **({"coalesce": "leader"} if p.key is not None else {}),
                 )
-        self._current = rec
-        armed = self._arm_watchdog(threshold)
         results: Optional[list] = None
         run_error: Optional[BaseException] = None
         try:
@@ -667,9 +869,6 @@ class MicroBatcher:
             run_error = e
             rec.error = type(e).__name__
         t_end = time.perf_counter()
-        if armed:
-            faulthandler.cancel_dump_traceback_later()
-        self._done = seq
         run_dt = t_end - t_run
         # postprocess takes what no stage of the run covered (the rest of
         # batch_predict, serving.serve), so `other` on a request is a true
@@ -679,6 +878,10 @@ class MicroBatcher:
         rec.stages["postprocess"] += rest
         for t in traces:
             t.add_stage("postprocess", rest)
+        with self._stats_lock:
+            # before the waiters wake: what they ask next was enqueued
+            # after this run returned
+            self._done = max(self._done, seq)
         with _tracing.annotation("pio.resolve", seq=seq):
             for i, p in enumerate(batch):
                 if run_error is not None:
@@ -688,11 +891,26 @@ class MicroBatcher:
         t_done = time.perf_counter()
         rec.stages["resolve"] = t_done - t_end
         rec.wall_s = rec.stages["collect"] + (t_done - t_run)
-        # rows waiting as this run ends: queued, carried, or (behind an
-        # inline run) already in the worker's hand
+        # rows waiting as this run ends: queued, carried, or already in a
+        # worker's hand
         rec.depth_end = self.depth()
         slow = run_dt > threshold
+        # launched behind a run in flight: how long before that run's
+        # device_compute returned, which is the time its program sat queued
+        # on the device and no part of what a run at its rung takes.  What
+        # the PROGRAM took: the host heard of its end a lag after it, and
+        # it started a lag before the host heard of the other's, or (on a
+        # free device, or enqueued later than that) a lag after its own
+        # enqueue as the host counts
+        launch_t = rec.dc_start if rec.t_launch is None else rec.t_launch
+        started = None if rec.t_enqueued is None else rec.t_enqueued + rec.lag
+        if behind is not None and None not in (behind.dc_end, launch_t):
+            rec.ahead_s = behind.dc_end - launch_t
+            if started is not None:
+                started = max(started, behind.dc_end)
+        queued = max(0.0, rec.ahead_s or 0.0)
         with self._stats_lock:
+            rec.behind = None  # _behind_in reads it of a run in flight
             self._ewma_run += self.ALPHA * (run_dt - self._ewma_run)
             self._n_batches += 1
             self._n_queries += len(batch)
@@ -703,32 +921,49 @@ class MicroBatcher:
             self._carried_rows += carried
             self._n_rounded_up += rec.padded > 0
             self._padded_rows += rec.padded
-            # the cut's estimates; a failed run says nothing of the rung
+            self._n_ahead += (rec.ahead_s or 0.0) > 0
+            # the estimates; a failed run says nothing of a rung
             if run_error is None:
-                self._rung_runs[rung].append((seq, run_dt))
-            if self._prev_left_work and self._prev_run_end is not None:
-                self._run_gaps.append(t_run - self._prev_run_end)
+                self._rung_runs[rung].append((seq, run_dt - queued))
+                if started is not None and rec.dc_end is not None:
+                    self._launch_runs.setdefault(
+                        rec.rung, collections.deque(maxlen=self.RUNS_KEPT),
+                    ).append((seq, rec.dc_end - started))
+                    if t_go is not None:
+                        self._leads.append(rec.t_enqueued - t_go)
             self._run_s_sum += run_dt
             if run_dt > self._run_s_max:
                 self._run_s_max, self._run_max_seq = run_dt, seq
             # turnaround: the device program of the previous dispatch
-            # returned -> this one's is launched, counted only when the
-            # previous run left rows queued or carried — the time the
-            # device waited for the host with work at hand
-            if (
-                self._prev_left_work
-                and self._prev_dc_end is not None
-                and rec.dc_start is not None
-            ):
-                self._turnaround_s_sum += rec.dc_start - self._prev_dc_end
-                self._turnaround_n += 1
+            # returned -> this one's is launched: the time the device
+            # waited for the host with work at hand.  Behind a run in
+            # flight the work was at hand by definition, and a program
+            # launched before that run returned kept the device waiting 0;
+            # on a free device it counts when the run before left rows
+            # queued or carried.  The gap between two runs likewise
+            if behind is not None:
+                self._run_gaps.append(0.0)
+                if rec.ahead_s is not None:
+                    self._turnaround_s_sum += max(0.0, -rec.ahead_s)
+                    self._turnaround_n += 1
+            elif prev_left_work:
+                if prev_run_end is not None:
+                    self._run_gaps.append(t_run - prev_run_end)
+                if None not in (prev_dc_end, rec.dc_start):
+                    self._turnaround_s_sum += rec.dc_start - prev_dc_end
+                    self._turnaround_n += 1
+            # two runs in flight may end in either order: the newest by
+            # seq is the one the next run on a free device follows
+            if seq > self._prev_seq:
+                self._prev_seq = seq
+                self._prev_dc_end = rec.dc_end
+                self._prev_run_end = t_end
+                self._prev_left_work = rec.depth_end > 0
             # with the count, so that stats() never sees the run both as
             # in flight past its threshold and as counted
             self._n_slow += slow
-            self._current = None
-        self._prev_dc_end = rec.dc_end
-        self._prev_run_end = t_end
-        self._prev_left_work = rec.depth_end > 0
+            self._flying.remove(rec)
+            self._watch()
         self._ring.append(rec)
         if slow:
             self._slow_ring.append(rec)
@@ -740,3 +975,10 @@ class MicroBatcher:
                 "; every thread's stack was written when the threshold "
                 "passed" if armed else "",
             )
+
+    def _launched(self) -> None:
+        """A run in flight has enqueued its program (``Dispatch.on_launch``,
+        on its thread): a worker waiting to launch behind it can name its
+        instant now."""
+        with self._turn:
+            self._turn.notify_all()

@@ -88,6 +88,9 @@ from predictionio_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, MeshContext, pad_to_multiple, shard_map,
 )
 from predictionio_tpu.serving import sharding as _sharding
+from predictionio_tpu.serving.launch_gate import (
+    LaunchGate, measure_lag, program_bytes,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -300,12 +303,27 @@ class BucketedScorer:
         # can never surface its first-dispatch cost under traffic
         self.warmup_executions = 0
         self._fns = {b: self._compile(b) for b in self.buckets}
+        # _device_topk is entered by two threads at once (the batcher's
+        # launch-ahead): two programs enqueued only where both fit, and
+        # the accountant charged no second of the device twice
+        self._gate = LaunchGate(
+            ctx.mesh.devices.flat[0],
+            {b: program_bytes(f) for b, f in self._fns.items()})
+        self._last_return = 0.0
         for b in self.buckets:
             # the input in the form a dispatch hands over, so the call's
             # handling of it is warm too
             dummy_idx = self._call_input(np.zeros(b, np.int32))
             jax.block_until_ready(self._fns[b](*self._static_args, dummy_idx))
             self.warmup_executions += 1
+        # the host hears of a program's end this much after it (the batcher
+        # aims its launch-ahead by it): the lowest rung's program on the
+        # warm-up's input, twice in a row on the idle device
+        b = self.buckets[0]
+        dummy_idx = self._call_input(np.zeros(b, np.int32))
+        self.launch_lag_s = measure_lag(
+            lambda: self._fetched(self._fns[b](*self._static_args, dummy_idx)),
+            jax.device_get)
 
     def _put_repl(self, x: np.ndarray):
         """Replicate a host array on the serving mesh, multi-process safe.
@@ -338,12 +356,16 @@ class BucketedScorer:
         second round trip asked for after it.  Multi-process
         safe: any one addressable shard of a replicated array is the whole
         value."""
+        outs = self._fetched(outs)
+        with self._lock:
+            self.readbacks_queued += 1
+        return outs
+
+    def _fetched(self, outs: tuple) -> tuple:
         if self._pod_spans:
             outs = tuple(x.addressable_data(0) for x in outs)
         for x in outs:
             x.copy_to_host_async()
-        with self._lock:
-            self.readbacks_queued += 1
         return outs
 
     def _init_replicated_placement(
@@ -1057,9 +1079,11 @@ class BucketedScorer:
         return idx_out, val_out
 
     def _device_topk(
-        self, users: np.ndarray, k: int
+        self, users: np.ndarray, k: int, more: bool = False
     ) -> tuple[np.ndarray, np.ndarray]:
-        """The bucketed device path (pre-hot-set ``score_topk`` body)."""
+        """The bucketed device path (pre-hot-set ``score_topk`` body).
+        ``more``: the caller has further launches to make in the same
+        batch run (the hot-set refresh, ahead of the rows asked for)."""
         top = self.buckets[-1]
         idx_parts, val_parts = [], []
         for s in range(0, len(users), top):
@@ -1071,12 +1095,15 @@ class BucketedScorer:
                 t.annotate(bucket=b)
             disp = _tracing.active_dispatch()
             if disp is not None:
-                disp.rung = b
+                # what the batcher times this launch by, and whether the
+                # run's end can be told from it
+                disp.rung, disp.lag = b, self.launch_lag_s
+                disp.more = more or s + top < len(users)
             with _tracing.stage("h2d"):
                 # the padded rows ride the compiled call; only a pod that
                 # spans processes still places them here
                 u_in = self._call_input(padded)
-            with _tracing.stage("device_compute"):
+            with _tracing.stage("device_compute"), self._gate.flight(b):
                 t0 = time.perf_counter()
                 # (vals, idx), and the merge counters where the program
                 # was compiled with them (_compile)
@@ -1092,7 +1119,13 @@ class BucketedScorer:
                 # a wait for the program first and the get after it cost
                 # one more wake-up of this thread: PERF.md §6, PR 38.)
                 val_h, idx_h, *merge = jax.device_get(back)
-                wall = time.perf_counter() - t0
+                t1 = time.perf_counter()
+                # launched behind a program in flight, this one sat queued
+                # until that one returned: the accountant is charged what
+                # no earlier dispatch's wait covered
+                with self._lock:
+                    wall = t1 - max(t0, self._last_return)
+                    self._last_return = t1
                 self.devprof.record(b, wall)
             with _tracing.stage("d2h"):
                 # the readback's residue on the host: the rows asked for
@@ -1158,7 +1191,7 @@ class BucketedScorer:
         if len(cand) == 0:
             return
         cand = np.sort(cand).astype(np.int32)
-        idx, vals = self._device_topk(cand, self.k)
+        idx, vals = self._device_topk(cand, self.k, more=True)
         with self._lock:
             self._hot_rows = {int(u): i for i, u in enumerate(cand)}
             self._hot_table_idx = idx
@@ -1279,6 +1312,10 @@ class BucketedScorer:
                 "bucket_hits": {str(b): h for b, h in hits.items()},
                 "calls": sum(hits.values()),
                 "readbacks_queued": self.readbacks_queued,
+                # launches that waited for the program in flight because
+                # the two would not fit the device together
+                "held_launches": self._gate.held,
+                "launch_lag_ms": round(self.launch_lag_s * 1e3, 4),
                 "queries": self.queries,
                 "padded_rows": self.padded_rows,
                 "merge_passes": self.merge_passes,

@@ -39,6 +39,9 @@ import numpy as np
 from predictionio_tpu.obs import tracing as _tracing
 from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.topk import resolve_backend
+from predictionio_tpu.serving.launch_gate import (
+    LaunchGate, measure_lag, program_bytes,
+)
 
 # token counts a dispatch pads to; the top rung also bounds one dispatch
 TOKEN_LADDER = (256, 512, 1024, 2048, 4096, 8192)
@@ -88,7 +91,20 @@ class PackedSequenceScorer:
         self.readbacks_queued = 0
         self._own = self._family.DispatchCounters(config)
         self._fns = {t: self._compile(t) for t in self.ladder}
+        # score_topk is entered by two threads at once (the batcher's
+        # launch-ahead): two programs enqueued only where both fit
+        self._gate = LaunchGate(
+            self._device, {t: program_bytes(f) for t, f in self._fns.items()})
         self._warm()
+        # the host hears of a program's end this much after it (the batcher
+        # aims its launch-ahead by it): the lowest rung's program on the
+        # warm-up's input, twice in a row on the idle device
+        flat = self._family.flatten(self._family.pack(
+            [np.zeros(1, np.int32)], self.ladder[0], self.max_rows))
+        self.launch_lag_s = measure_lag(
+            lambda: self._fetched(self._fns[self.ladder[0]](
+                self._params, flat)),
+            jax.device_get)
 
     # -- compile + warm --------------------------------------------------
     def _program(self, t: int):
@@ -167,7 +183,11 @@ class PackedSequenceScorer:
                 tr.annotate(bucket=t)
             disp = _tracing.active_dispatch()
             if disp is not None:
-                disp.rung = t
+                # what the batcher times this launch by, and whether the
+                # run's end can be told from it: not while rows past the
+                # top rung are still to launch
+                disp.rung, disp.more = t, hi < len(histories)
+                disp.lag = self.launch_lag_s
             with _tracing.stage("batch_assembly"):
                 batch = self._family.pack(rows, t, self.max_rows)
             with _tracing.stage("h2d"):
@@ -175,7 +195,7 @@ class PackedSequenceScorer:
                 # argument handling places it: no device_put (a host ↔
                 # device round trip) of its own
                 flat = self._family.flatten(batch)
-            with _tracing.stage("device_compute"):
+            with _tracing.stage("device_compute"), self._gate.flight(t):
                 with _tracing.launch():
                     out = self._fns[t](self._params, flat)
                 # asked for at launch, not after the wake-up: the copies
@@ -183,7 +203,8 @@ class PackedSequenceScorer:
                 small = self._queue_readback(out)
                 # the ONE wait, INSIDE the stage as the bucketed scorer's:
                 # the get returns when the program has run and its outputs
-                # have landed
+                # have landed.  Launched behind a program in flight, the
+                # stage also holds the time this one sat queued
                 got = jax.device_get(small)
             with _tracing.stage("d2h"):
                 # the readback's residue on the host: the rows asked for
@@ -198,13 +219,17 @@ class PackedSequenceScorer:
         """The outputs a dispatch reads back (the leaderboard, the merge
         counter, the family's ``fetch``), each one's device→host copy
         requested NOW, on the not-yet-ready arrays the launch returned."""
+        small = self._fetched(out)
+        with self._lock:
+            self.readbacks_queued += 1
+        return small
+
+    def _fetched(self, out: dict) -> dict:
         small = {name: out[name] for name in
                  ("values", "indices", "merge") + self._own.fetch
                  if name in out}
         for x in small.values():
             x.copy_to_host_async()
-        with self._lock:
-            self.readbacks_queued += 1
         return small
 
     def _count(self, t, rows, n_tok, got, disp) -> None:
@@ -244,6 +269,10 @@ class PackedSequenceScorer:
                 "bucket_hits": {str(t): n for t, n in self.hits.items()},
                 "calls": sum(self.hits.values()),
                 "readbacks_queued": self.readbacks_queued,
+                # launches that waited for the program in flight because
+                # the two would not fit the device together
+                "held_launches": self._gate.held,
+                "launch_lag_ms": round(self.launch_lag_s * 1e3, 4),
                 "queries": self.queries,
                 "tokens": self.tokens,
                 "padded_tokens": self.padded_tokens,

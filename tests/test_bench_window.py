@@ -302,7 +302,11 @@ def test_the_cell_is_declared_as_the_issue_says(bench):
     assert not {m for m in mine if m.startswith(("gdn.", "mla.", "score.",
                                                  "attn."))}
     names = [m["name"] for m in bench["per_layer"]]
-    assert tuple(names[-5:]) == NEW  # new entries at the end of their list
+    # new entries went to the end of their list; what later PRs added
+    # follows them (PR 40: `batch.ahead_share`, in every cell)
+    at = names.index(NEW[0])
+    assert tuple(names[at:at + 5]) == NEW
+    assert names[at + 5:] == ["batch.ahead_share"]
     for m in bench["per_layer"]:
         if m["name"] in NEW:
             assert m["workloads"] == [CELL] and m["moves"] == "serve.p50_ms"

@@ -550,6 +550,92 @@ class TestDispatchEnvelope:
         assert after["calls"] - before["calls"] >= 2
         assert after["readbacks_queued"] == after["calls"]
 
+    def test_two_threads_at_once_get_what_each_gets_alone_counted_exactly(
+            self, boundary):
+        """ISSUE 40: the batcher launches a run while the one before it is
+        still in flight, so ``score_topk`` is entered by two threads at
+        once.  Each gets what it gets alone, bit for bit, and the counters
+        (under the scorer's own lock) lose no update."""
+        import sys
+
+        sc = boundary.scorer
+        args = [boundary.arg(r) for r in boundary.rungs] + [
+            boundary.over_top()]
+        alone = [sc.score_topk(a, sc.k) for a in args]
+        counted = ("calls", "queries", "readbacks_queued", "bucket_hits",
+                   "tokens", "padded_tokens", "padded_rows")
+        c0 = sc.stats()
+        for a in args:
+            sc.score_topk(a, sc.k)
+        c1 = sc.stats()
+        once = {k: ({r: c1[k][r] - c0[k][r] for r in c1[k]}
+                    if isinstance(c1[k], dict) else c1[k] - c0[k])
+                for k in counted if k in c1}
+        assert once["calls"] >= len(args) + 1  # over_top is several
+        rounds, got, errors = 12, {0: [], 1: []}, []
+        start = threading.Barrier(2)
+
+        def caller(t):
+            try:
+                start.wait(10)
+                for _ in range(rounds):
+                    # the two threads walk the rungs in opposite order, so
+                    # programs of different rungs are in flight together
+                    for i in (range(len(args)) if t == 0
+                              else reversed(range(len(args)))):
+                        got[t].append((i, sc.score_topk(args[i], sc.k)))
+            except BaseException as e:  # shown by the assert below
+                errors.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=caller, args=(t,), daemon=True)
+                       for t in (0, 1)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(120)
+                assert not th.is_alive()
+        finally:
+            sys.setswitchinterval(old)
+        assert errors == []
+        for t in (0, 1):
+            assert len(got[t]) == rounds * len(args)
+            for i, (idx, val) in got[t]:
+                np.testing.assert_array_equal(idx, alone[i][0])
+                np.testing.assert_array_equal(val, alone[i][1])
+        c2 = sc.stats()
+        for k, per_pass in once.items():
+            if isinstance(per_pass, dict):
+                assert {r: c2[k][r] - c1[k][r] for r in c2[k]} == {
+                    r: 2 * rounds * n for r, n in per_pass.items()}, k
+            else:
+                assert c2[k] - c1[k] == 2 * rounds * per_pass, k
+        assert c2["readbacks_queued"] == c2["calls"]
+        assert c2["held_launches"] == 0  # a CPU names no memory limit
+        assert c2["compile_count"] == c0["compile_count"]
+
+    def test_only_the_last_launch_of_a_run_tells_the_batcher(self, boundary):
+        """ISSUE 40: the batcher times the run in flight from the launch
+        the scorer stamps on its record.  Rows past the top rung are several
+        launches in one run: only the last may say when the run ends."""
+        sc = boundary.scorer
+        woken = []
+        rec = tracing.Dispatch(3, False, 1, 0, t_run=time.perf_counter(),
+                               collect_s=0.0, slow_after_s=2.0)
+        rec.on_launch = lambda: woken.append(
+            (rec.rung, rec.more, rec.t_launch, rec.t_enqueued))
+        calls = sc.stats()["calls"]
+        with tracing.scope((), dispatch=rec):
+            sc.score_topk(boundary.over_top(), sc.k)
+        assert sc.stats()["calls"] - calls >= 2
+        ((rung, more, t_launch, t_enqueued),) = woken
+        assert rung == rec.rung and more is False
+        # stamped around the LAST jitted call, inside its device_compute
+        assert rec.dc_start < t_launch <= t_enqueued < rec.dc_end
+        assert (rec.t_launch, rec.t_enqueued) == (t_launch, t_enqueued)
+
     def test_a_record_keeps_its_three_stages_in_order(self, boundary,
                                                       monkeypatch):
         """What crosses the boundary when has moved, the stages have not: a

@@ -17,6 +17,7 @@ import json
 import os
 import sys
 import urllib.error
+import urllib.request
 
 BENCH = os.path.join(os.getcwd(), "benchmark")
 sys.path[:0] = [BENCH, os.getcwd()]  # run.py, and the program
@@ -36,9 +37,14 @@ family = importlib.import_module("pio_bench.engines." + bench_run.load_json(
 stop = family.Deployment.stop
 
 
+def _get(url):  # not every family's engine module has one of its own
+    with urllib.request.urlopen(url, timeout=60) as r:
+        return json.loads(r.read().decode())
+
+
 def keep_then_stop(self):
     try:
-        doc = family._get(self.base + "/trace/dispatches.json")
+        doc = _get(self.base + "/trace/dispatches.json")
         doc["root"] = self.root()
         with open(out, "w") as f:
             json.dump(doc, f)
