@@ -488,9 +488,11 @@ def scan_chunks(t: int, chunk: Optional[int] = None,
 
 def causal_conv(x: jax.Array, w: jax.Array, positions: jax.Array, *,
                 tail: Optional[jax.Array] = None,
-                row_of: Optional[jax.Array] = None):
+                row_of: Optional[jax.Array] = None,
+                bias: Optional[jax.Array] = None, scope: str = CONV_SCOPE):
     """Depthwise causal convolution of width ``W`` over each packed history:
-    ``y_t = sum_j w[j] x_(t-W+1+j)``, zeros before a history's first event.
+    ``y_t = sum_j w[j] x_(t-W+1+j)`` (``+ bias`` (channels,) where given),
+    zeros before a history's first event.
 
     ``x`` (T, channels), ``w`` (W, channels), ``positions`` (T,) int32 (index
     within the history).  With ``tail`` (R, W-1, channels) — the last
@@ -502,8 +504,10 @@ def causal_conv(x: jax.Array, w: jax.Array, positions: jax.Array, *,
     width = w.shape[0]
     xf = x.astype(jnp.float32)
     wf = w.astype(jnp.float32)
-    with jax.named_scope(CONV_SCOPE):
+    with jax.named_scope(scope):
         y = xf * wf[width - 1]
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
         for back in range(1, width):
             shifted = jnp.pad(xf, ((back, 0), (0, 0)))[:xf.shape[0]]
             inside = (positions >= back)[:, None]

@@ -7,10 +7,10 @@ history is read live from the event store (same pattern as the e-commerce
 template's serving-time lookups) so recommendations track events newer than
 the model.
 
-Four algorithms share the template and its one history seam
+Five algorithms share the template and its one history seam
 (:class:`EventStoreHistory` unless the model or the algorithm carries
 another provider): ``sasrec``, the small trained transformer, served one
-query at a time from the host; and three packed sequence families at
+query at a time from the host; and four packed sequence families at
 published widths that serve through ``deploy --batching`` — the batcher's
 rows are packed into one dispatch of a resident, ahead-of-time compiled
 device program (:mod:`predictionio_tpu.serving.seqpath`, ONE scorer class
@@ -18,10 +18,13 @@ for all): ``latentmoe`` (:class:`LatentMoEAlgorithm`), a latent-attention
 sparse-expert stack (:mod:`predictionio_tpu.models.latent_moe`),
 ``gdnhybrid`` (:class:`GDNHybridAlgorithm`), gated-delta-rule
 linear-attention layers interleaved with full-attention layers
-(:mod:`predictionio_tpu.models.gdn_hybrid`), and ``windowmoe``
+(:mod:`predictionio_tpu.models.gdn_hybrid`), ``windowmoe``
 (:class:`WindowMoEAlgorithm`), window and global grouped-query attention
 over sparse experts of which a model may hold a slice
-(:mod:`predictionio_tpu.models.window_moe`).  What a packed family needs of
+(:mod:`predictionio_tpu.models.window_moe`), and ``ssmparallel``
+(:class:`SSMParallelAlgorithm`), a state-space mixer and grouped-query
+attention side by side in every layer under muP multipliers
+(:mod:`predictionio_tpu.models.ssm_parallel`).  What a packed family needs of
 an algorithm is :class:`PackedSequenceAlgorithm`'s; a family adds its
 model module's name.
 """
@@ -377,6 +380,13 @@ class WindowMoEAlgorithm(PackedSequenceAlgorithm):
     family = "predictionio_tpu.models.window_moe"
 
 
+class SSMParallelAlgorithm(PackedSequenceAlgorithm):
+    """The parallel state-space / attention recommender
+    (``ssmparallel``)."""
+
+    family = "predictionio_tpu.models.ssm_parallel"
+
+
 class SequentialRecommendationEngine(EngineFactory):
     @classmethod
     def apply(cls) -> Engine:
@@ -388,6 +398,7 @@ class SequentialRecommendationEngine(EngineFactory):
                 "latentmoe": LatentMoEAlgorithm,
                 "gdnhybrid": GDNHybridAlgorithm,
                 "windowmoe": WindowMoEAlgorithm,
+                "ssmparallel": SSMParallelAlgorithm,
             },
             serving_cls=FirstServing,
             query_cls=Query,

@@ -169,3 +169,82 @@ def test_hybrid_head_compiles_at_its_tile(one_chip):
                             HYBRID_HEAD_ITEMS), rank=HYBRID_RANK,
                         mask_row=True)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the parallel state-space / attention family's kernels at
+# falcon-h1-34b-l6's widths ---------------------------------------------------
+
+SSD_HEADS, SSD_GROUPS, SSD_P, SSD_N = 32, 2, 128, 256
+SSD_HEAD_ITEMS, SSD_RANK = 261_120, 5_120
+
+
+def _ssd_shapes(one_chip, t):
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return shape, [shape((t, SSD_HEADS * SSD_P), bf),
+                   shape((t, SSD_GROUPS * SSD_N), bf),
+                   shape((t, SSD_GROUPS * SSD_N), bf),
+                   shape((t, SSD_HEADS), f32), shape((SSD_HEADS,), f32),
+                   shape((SSD_HEADS,), f32), shape((t,), jnp.int32)]
+
+
+@pytest.mark.parametrize("t", (256, 8192))
+def test_state_space_scan_compiles_at_the_ladders_ends(one_chip, t):
+    # what interpret mode cannot refuse: sixteen heads side by side on the
+    # lanes (slices and a concat at multiples of 128), a product contracted
+    # over both operands' FIRST axis, a 2 MB f32 state in VMEM, block indices
+    # clamped by a prefetched count
+    from predictionio_tpu.ops import ssd_scan
+
+    shape, args = _ssd_shapes(one_chip, t)
+    compiled = jax.jit(lambda *a, n: ssd_scan.ssd_scan(
+        *a, n_groups=SSD_GROUPS, n_real=n, interpret=False)).lower(
+            *args, n=shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "%pio.ssd_scan." in text
+    assert compiled.out_info.shape == (t, SSD_HEADS * SSD_P)
+
+
+def test_state_space_scan_compiles_with_the_state_carry(one_chip):
+    from predictionio_tpu.ops import ssd_scan
+
+    shape, args = _ssd_shapes(one_chip, 2048)
+    rows = 16
+    args += [shape((rows, SSD_HEADS, SSD_P, SSD_N), jnp.float32),
+             shape((rows,), jnp.int32), shape((rows,), jnp.int32)]
+    compiled = jax.jit(lambda x, b, c, dt, a, d, s, h0, rs, rl:
+                       ssd_scan.ssd_scan(
+                           x, b, c, dt, a, d, s, n_groups=SSD_GROUPS, h0=h0,
+                           row_start=rs, row_last=rl,
+                           output_final_state=True, interpret=False)
+                       ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info[1].shape == (rows, SSD_HEADS, SSD_P, SSD_N)
+
+
+def test_grouped_attention_compiles_at_twenty_over_four_heads(one_chip):
+    from predictionio_tpu.ops.flash_attention import packed_grouped_attention
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    q = shape((20, 8192, 128), jnp.bfloat16)
+    kv = shape((4, 8192, 128), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, s: packed_grouped_attention(
+        q, k, v, s, interpret=False)).lower(
+            q, kv, kv, shape((8192,), jnp.int32)).compile()
+    assert "%pio.global_attention." in compiled.as_text()
+
+
+def test_widest_head_compiles_at_the_tile_the_rule_picks(one_chip):
+    # rank 5,120 over 261,120 rows, 2.67 GB a sweep: `tile_geometry` is a
+    # function of shapes and takes it unedited
+    assert score_kernel.pad_block_items(SSD_HEAD_ITEMS) == SSD_HEAD_ITEMS
+    rows, block = score_kernel.tile_geometry(
+        64, SSD_RANK, jnp.bfloat16, SSD_HEAD_ITEMS)
+    assert rows == 64 and SSD_HEAD_ITEMS % block == 0
+    compiled = _compile(one_chip, jnp.bfloat16, 64, 100, with_stats=True,
+                        n_items=SSD_HEAD_ITEMS, rank=SSD_RANK, mask_row=True)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,0 +1,68 @@
+"""The parallel state-space / attention family's first contact with the chip:
+do the chunked state-space scan, the grouped-query kernel at 20 / 4 heads
+inside a scanned layer and the head at rank 5,120 run as the off-chip
+compile said, and what does ONE dispatch cost per token rung, by op?  Seeded
+weights at the published widths, `PackedSequenceScorer`, per rung two bare
+dispatches — one history that fills the rung, and rows of ~200 events that
+fill it — each profiled on its own.  Writes
+`chiprun_out/ssd_first_look.json`."""
+import json, os, shutil, sys, time
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+OUT = os.path.join(ROOT, "chiprun_out")
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
+import jax, numpy as np
+from predictionio_tpu.parallel import mesh as mesh_mod
+mesh_mod.MeshContext.create()
+from predictionio_tpu.models import ssm_parallel as sp
+from predictionio_tpu.serving.seqpath import PackedSequenceScorer
+from pio_bench.engines import ssm_parallel_sequence as family
+from pio_bench import xplane_named
+
+cfgj = json.load(open(os.path.join(ROOT, "benchmark", "configs", "falcon-h1-34b-l6.json")))
+hf = family.model_config(cfgj)
+serving = cfgj["serving"]
+cfg = sp.SSMParallelConfig.from_hf(hf, max_len=serving["max_len"])
+out = {}
+t0 = time.perf_counter(); P = sp.init_params(cfg, 3900000001); jax.block_until_ready(P)
+out["init_s"] = time.perf_counter() - t0
+t0 = time.perf_counter()
+sc = PackedSequenceScorer(cfg, P, max_k=cfgj["max_k"], ladder=serving["token_ladder"], max_rows=serving["max_rows"])
+out["compile_warm_s"] = time.perf_counter() - t0
+out["resident_bytes"] = sc.resident_bytes
+print(out, flush=True)
+rng = np.random.default_rng(0)
+hist = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+NAMES = ("ssd_scan", "global_attention", "score_topk")
+os.makedirs(OUT, exist_ok=True)
+rungs = {}
+for t in sc.ladder:
+    for label, hs in (("one_row", [hist(t)]), ("rows_of_200", [hist(200) for _ in range(min(64, max(1, t // 200)))])):
+        sc.score_topk(hs, 20)
+        walls = []
+        for _ in range(3):
+            t0 = time.perf_counter(); sc.score_topk(hs, 20); walls.append((time.perf_counter() - t0) * 1e3)
+        tdir = os.path.join(OUT, f"ssd_trace.{t}.{label}")
+        jax.profiler.start_trace(tdir)
+        for _ in range(2):
+            sc.score_topk(hs, 20)
+        jax.profiler.stop_trace()
+        named = xplane_named.load_named(tdir)
+        mods = [d for n, d in named["modules"] if "pio_seq_forward" in n]
+        row = {"host_wall_ms": sorted(walls)[1], "device_ms": 1e3 * sum(mods) / max(1, len(mods)), "runs": len(mods)}
+        for needle in NAMES:
+            hits = [d for n, d in named["ops"] if needle in n]
+            row[needle + "_ms"] = 1e3 * sum(hits) / max(1, len(mods))
+            row[needle + "_ops"] = len(hits) // max(1, len(mods))
+        other = sorted(((n, d) for n, d in named["ops"] if not any(x in n for x in NAMES)), key=lambda x: -x[1])
+        agg = {}
+        for n, d in other:
+            agg[n] = agg.get(n, 0.0) + d
+        row["other_top"] = [[n[:60], round(1e3 * d / max(1, len(mods)), 3)] for n, d in sorted(agg.items(), key=lambda x: -x[1])[:8]]
+        shutil.rmtree(tdir, ignore_errors=True)
+        rungs[f"{t}.{label}"] = row
+        print(t, label, {k: (round(v, 3) if isinstance(v, float) else v) for k, v in row.items() if k != "other_top"}, flush=True)
+out["rungs"] = rungs
+out["mem"] = jax.devices()[0].memory_stats()
+out["stats"] = sc.stats()
+json.dump(out, open(os.path.join(OUT, "ssd_first_look.json"), "w"), indent=1, default=str)
+print("peak", out["mem"].get("peak_bytes_in_use"), "stats", {k: out["stats"][k] for k in ("block_items", "resident_bytes", "held_launches", "launch_lag_ms", "scan_tokens", "scan_rows", "scan_chunks", "scan_chunk", "causal_pairs")})
