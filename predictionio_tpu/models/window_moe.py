@@ -69,11 +69,14 @@ from predictionio_tpu.models.latent_moe import (
 from predictionio_tpu.ops import flash_attention as _fa
 from predictionio_tpu.ops import moe as _moe
 from predictionio_tpu.ops import score_kernel as _score_kernel
+from predictionio_tpu.ops import token_tiles as _tiles
 
 # what `PackedSequenceScorer.stats()["family"]` says of this module's models
 FAMILY = "window_moe_sequence"
 # the host side of a dispatch is the other packed families', shared
 pack, flatten = _lm.pack, _lm.flatten
+# the tile the position-wise sublayers run in, on the rungs `runs_in_tiles`
+DENSE_TILE = _tiles.DENSE_TILE
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
 
 
@@ -263,7 +266,31 @@ def rope_half(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(cfg, P, p, kind, x, positions, seg_start, interpret):
+def runs_in_tiles(t_pad: int, tile: int = DENSE_TILE) -> bool:
+    """Whether the program of a rung of ``t_pad`` tokens runs its
+    position-wise parts in tiles: it holds MORE than two, whole.  A dispatch
+    goes to the smallest rung that holds it, so on a doubling ladder it
+    fills more than half of its rung: a rung of two tiles always needs
+    both, and tiles would cost it their loops (read on the chip: +1 to
+    +7 % at 1,024 tokens) and save nothing.  A shorter rung's program is
+    composed sublayer by sublayer, as it was before there were tiles."""
+    return t_pad > 2 * tile and t_pad % tile == 0
+
+
+# A layer is position-wise parts around two cross-token operations, the
+# attention kernel and the routed experts' products.  Each part is written
+# once, below; `trunk` puts them together sublayer by sublayer on a short
+# rung (`_layer`: the order, and so the program, there was before tiles) and
+# as three segments over the token tiles that hold a real token on a long
+# one (`_layer_in_tiles`, which also keeps all but the gate's columns of the
+# projection from crossing the kernel).  That the two agree on every real
+# row is `tests/test_window_moe.py`'s to hold, at a tile of 16.
+
+
+def _qkvg(cfg, P, p, kind, x, positions):
+    """Before the kernel: q, k and v heads first, (heads, T, head_dim) in
+    the compute dtype, and the projection itself (T, qkvg_width) f32, whose
+    last columns the output gate reads."""
     t = x.shape[0]
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
@@ -276,62 +303,170 @@ def _attention(cfg, P, p, kind, x, positions, seg_start, interpret):
     k = rms_norm(qkvg[:, q_end:k_end].reshape(t, hkv, hd), P[p + "k_norm"],
                  cfg.rms_norm_eps)
     v = qkvg[:, k_end:v_end].reshape(t, hkv, hd)
-    window = None
     if kind == WINDOW:
-        window = cfg.sliding_window
         q = rope_half(q, positions, cfg.rope_theta)
         k = rope_half(k, positions, cfg.rope_theta)
     heads_first = lambda z: z.transpose(1, 0, 2).astype(cdt)
-    o = _fa.packed_grouped_attention(
-        heads_first(q), heads_first(k), heads_first(v), seg_start,
-        window=window, scale=1.0 / math.sqrt(hd), interpret=interpret)
-    o = o.transpose(1, 0, 2).reshape(t, hq * hd).astype(jnp.float32)
-    y = _mm(o * jax.nn.sigmoid(qkvg[:, v_end:]), P[p + "o"])
+    return heads_first(q), heads_first(k), heads_first(v), qkvg
+
+
+def _attend(cfg, kind, q, k, v, seg_start, interpret):
+    return _fa.packed_grouped_attention(
+        q, k, v, seg_start,
+        window=cfg.sliding_window if kind == WINDOW else None,
+        scale=1.0 / math.sqrt(cfg.head_dim), interpret=interpret)
+
+
+def _attention_out(cfg, P, p, o, qkvg):
+    """Behind the kernel: ``o`` (heads, T, head_dim) under the sigmoid of
+    the gate's pre-activation — the last ``heads * head_dim`` columns of
+    ``qkvg``, which may be those columns alone — through ``W_o`` and the
+    output norm."""
+    width = o.shape[0] * o.shape[2]
+    o = o.transpose(1, 0, 2).reshape(o.shape[1], width).astype(jnp.float32)
+    y = _mm(o * jax.nn.sigmoid(qkvg[:, qkvg.shape[1] - width:]), P[p + "o"])
     return rms_norm(y, P[p + "post_attn_norm"], cfg.rms_norm_eps)
 
 
-def _sparse_ffn(cfg, P, p, m, valid, interpret):
+def _attention(cfg, P, p, kind, x, positions, seg_start, interpret):
+    q, k, v, qkvg = _qkvg(cfg, P, p, kind, x, positions)
+    return _attention_out(
+        cfg, P, p, _attend(cfg, kind, q, k, v, seg_start, interpret), qkvg)
+
+
+def _route(cfg, P, p, m):
+    """The router's picks and weights for the normed ``m``, and ``m`` in
+    the experts' dtype."""
     picked, weights, _ = _moe.route_sigmoid_topk(
         m, P[p + "gate"], P[p + "gate_bias"],
         top_k=cfg.num_experts_per_tok, scale=cfg.route_scale,
         normalize=cfg.route_norm)
-    mb = m.astype(P[p + "e_w1"].dtype)
-    y, counts = _moe.expert_products(
-        mb, picked, weights, P[p + "e_w1"], P[p + "e_w3"], P[p + "e_w2"],
-        valid, first=cfg.first_expert_held, n_experts=cfg.num_experts,
+    return picked, weights, m.astype(P[p + "e_w1"].dtype)
+
+
+def _held_products(cfg, mb, picked, weights, w1, w3, w2, valid, interpret):
+    """The held experts' part for their tokens.  Never in tiles: the held
+    experts' weights cross HBM once a dispatch, not once a tile, and it
+    sorts padded tokens past the last expert itself."""
+    return _moe.expert_products(
+        mb, picked, weights, w1, w3, w2, valid,
+        first=cfg.first_expert_held, n_experts=cfg.num_experts,
         interpret=interpret)
+
+
+def _shared_and_held(cfg, P, p, y, mb, picked):
+    """The shared expert added, and per token whether any pick is held."""
     if cfg.num_shared_experts:
         y = y + _swiglu(mb, P[p + "s_w1"], P[p + "s_w3"], P[p + "s_w2"])
     local = picked - cfg.first_expert_held
-    held = ((local >= 0) & (local < cfg.n_held)).any(axis=1)
+    return y, ((local >= 0) & (local < cfg.n_held)).any(axis=1)
+
+
+def _sparse_ffn(cfg, P, p, m, valid, interpret):
+    picked, weights, mb = _route(cfg, P, p, m)
+    y, counts = _held_products(
+        cfg, mb, picked, weights, P[p + "e_w1"], P[p + "e_w3"],
+        P[p + "e_w2"], valid, interpret)
+    y, held = _shared_and_held(cfg, P, p, y, mb, picked)
     return y, picked, counts, jnp.sum(valid & ~held, dtype=jnp.int32)
 
 
+def _layer(cfg, P, p, i, kind, x, positions, seg_start, valid, interpret):
+    """One layer on the stream, sublayer by sublayer: the stream, and for a
+    sparse layer its picks, the held experts' counts and the valid tokens
+    without a held pick (else None)."""
+    eps = cfg.rms_norm_eps
+    x = x + _attention(cfg, P, p, kind, x, positions, seg_start, interpret)
+    m = rms_norm(x, P[p + "pre_mlp_norm"], eps)
+    if i < cfg.num_dense_layers:
+        f, routed = _swiglu(m, P[p + "w1"], P[p + "w3"], P[p + "w2"]), None
+    else:
+        f, *routed = _sparse_ffn(cfg, P, p, m, valid, interpret)
+    return x + rms_norm(f, P[p + "post_mlp_norm"], eps), routed
+
+
+def _layer_in_tiles(cfg, P, p, i, kind, x, positions, valid, tiles, attend,
+                    products):
+    """:func:`_layer`, its position-wise parts run by ``tiles``
+    (``token_tiles.real_tiles``) as one segment before the attention kernel,
+    one between it and the routed experts' products (a dense layer's ends
+    the layer) and one behind those: no sublayer's (T, intermediate) array
+    is written whole, and of the projection only the gate's columns cross
+    the kernel.  ``attend`` / ``products``: :func:`_attend` of the layer's
+    kind and :func:`_held_products` with the dispatch's own bound."""
+    eps = cfg.rms_norm_eps
+    hq, hd = cfg.num_attention_heads, cfg.head_dim
+
+    def before(x, positions):
+        q, k, v, qkvg = _qkvg(cfg, P, p, kind, x, positions)
+        return q, k, v, qkvg[:, -hq * hd:]
+
+    def between(o, gate, x):
+        x = x + _attention_out(cfg, P, p, o, gate)
+        m = rms_norm(x, P[p + "pre_mlp_norm"], eps)
+        if i < cfg.num_dense_layers:
+            f = _swiglu(m, P[p + "w1"], P[p + "w3"], P[p + "w2"])
+            return x + rms_norm(f, P[p + "post_mlp_norm"], eps)
+        return (x, *_route(cfg, P, p, m))
+
+    def behind(y, mb, picked, x):
+        f, held = _shared_and_held(cfg, P, p, y, mb, picked)
+        return x + rms_norm(f, P[p + "post_mlp_norm"], eps), held
+
+    q, k, v, gate = tiles(before, x, positions, out_axes=(1, 1, 1, 0))
+    o = attend(q, k, v)
+    if i < cfg.num_dense_layers:
+        return tiles(between, o, gate, x, in_axes=(1, 0, 0)), None
+    x, picked, weights, mb = tiles(between, o, gate, x, in_axes=(1, 0, 0))
+    y, counts = products(mb, picked, weights, P[p + "e_w1"], P[p + "e_w3"],
+                         P[p + "e_w2"])
+    x, held = tiles(behind, y, mb, picked, x)
+    return x, (picked, counts, jnp.sum(valid & ~held, dtype=jnp.int32))
+
+
 def trunk(cfg: WindowMoEConfig, P: dict, tokens, positions, seg_start,
-          valid, *, interpret: Optional[bool] = None):
+          valid, *, interpret: Optional[bool] = None,
+          dense_tile: int = DENSE_TILE):
     """The block stack over a packed token axis.  Returns the residual
     stream (T, hidden) f32 BEFORE the final norm, the picks of every sparse
     layer (L_moe, T, top_k), the valid assignments per HELD expert (L_moe,
     n_held) and, per sparse layer, the valid tokens none of whose picks is
-    held (L_moe,)."""
-    eps = cfg.rms_norm_eps
+    held (L_moe,).
+
+    On a rung that :func:`runs_in_tiles` of ``dense_tile`` tokens (a
+    test's: the program's is the default), what is position-wise (every
+    product, norm and gate but the routed experts') runs only the tiles
+    that hold a real token (:func:`_layer_in_tiles`): the padded tokens'
+    rows of the stream past the last such tile are zeros, no model's
+    output."""
+    in_tiles = runs_in_tiles(tokens.shape[0], dense_tile)
+    if in_tiles:
+        # `pack` lays rows end to end from token 0: the real tokens lead
+        tiles = _tiles.real_tiles(jnp.sum(valid, dtype=jnp.int32), dense_tile)
+        # jitted, so that the layers of a kind call ONE traced and lowered
+        # kernel function (traced and lowered a layer each, the kernels are
+        # most of a program's set-up time)
+        attend = {kind: jax.jit(functools.partial(
+            _attend, cfg, kind, seg_start=seg_start, interpret=interpret))
+            for kind in set(cfg.layer_types)}
+        products = jax.jit(functools.partial(
+            _held_products, cfg, valid=valid, interpret=interpret))
     x = P["embed"][tokens].astype(jnp.float32)
     if cfg.mup_enabled:
         x = x * math.sqrt(cfg.hidden_size)
     picks, counts, unheld = [], [], []
     for i, kind in enumerate(cfg.layer_types):
-        p = f"L{i}."
-        x = x + _attention(cfg, P, p, kind, x, positions, seg_start,
-                           interpret)
-        m = rms_norm(x, P[p + "pre_mlp_norm"], eps)
-        if i < cfg.num_dense_layers:
-            f = _swiglu(m, P[p + "w1"], P[p + "w3"], P[p + "w2"])
+        if in_tiles:
+            x, routed = _layer_in_tiles(cfg, P, f"L{i}.", i, kind, x,
+                                        positions, valid, tiles,
+                                        attend[kind], products)
         else:
-            f, picked, c, u = _sparse_ffn(cfg, P, p, m, valid, interpret)
-            picks.append(picked)
-            counts.append(c)
-            unheld.append(u)
-        x = x + rms_norm(f, P[p + "post_mlp_norm"], eps)
+            x, routed = _layer(cfg, P, f"L{i}.", i, kind, x, positions,
+                               seg_start, valid, interpret)
+        if routed:
+            picks.append(routed[0])
+            counts.append(routed[1])
+            unheld.append(routed[2])
     k = cfg.num_experts_per_tok
     return (x,
             jnp.stack(picks) if picks
@@ -365,10 +500,12 @@ def attention_counts(cfg: WindowMoEConfig, positions, seg_start, valid):
 def forward_packed(cfg: WindowMoEConfig, P: dict, tokens, positions,
                    seg_start, valid, last_idx, k: int, *,
                    interpret: Optional[bool] = None,
-                   score_backend: Optional[str] = None) -> dict:
+                   score_backend: Optional[str] = None,
+                   dense_tile: int = DENSE_TILE) -> dict:
     """One dispatch: the packed token axis through the trunk, each row's
     last position through the final norm, and its top-``k`` items taken on
-    the device.  Arguments as ``latent_moe.forward_packed``.  Returns
+    the device.  Arguments as ``latent_moe.forward_packed``; ``dense_tile``
+    as :func:`trunk`'s (a test's: the program's is the default).  Returns
     ``values`` and ``indices`` (R, k), ``h_last`` (R, hidden) bf16 and
     ``x_last`` (R, hidden) f32 — the residual stream h_last is the norm of,
     for audits: bf16 hides what five layers add to an embedding 55 times
@@ -376,7 +513,8 @@ def forward_packed(cfg: WindowMoEConfig, P: dict, tokens, positions,
     ``tokens_unheld``, ``attn_counts`` and, on the fused score backend, the
     merge counters."""
     x, picks, counts, unheld = trunk(
-        cfg, P, tokens, positions, seg_start, valid, interpret=interpret)
+        cfg, P, tokens, positions, seg_start, valid, interpret=interpret,
+        dense_tile=dense_tile)
     x_last = x[last_idx]
     res = score_head(P, cfg.vocab_size, cfg.rms_norm_eps, x_last, k,
                      interpret=interpret, score_backend=score_backend)
@@ -406,7 +544,10 @@ class DispatchCounters:
     assignments took more than one pass of ``ops/moe.local_row_bound``
     rows.  Over the attention layers: the (query, key) pairs the window and
     the causal mask show, and the key blocks the window layers' sweep ran
-    beside those a sweep to each history's start would run."""
+    beside those a sweep to each history's start would run.  Over the
+    position-wise sublayers: the token tiles they ran (``dense_tiles``, the
+    program's trip count) beside those their rungs hold; a rung whose
+    program runs whole counts as one tile, run."""
 
     # outputs of the program fetched with every dispatch's answer
     fetch = ("expert_counts", "tokens_unheld", "attn_counts")
@@ -424,6 +565,8 @@ class DispatchCounters:
         self.global_pairs = 0
         self.window_kv_blocks = 0
         self.window_kv_blocks_unskipped = 0
+        self.dense_tiles = 0
+        self.dense_tiles_rung = 0
 
     def add(self, t_pad: int, n_rows: int, n_tokens: int, got: dict) -> None:
         cfg = self.config
@@ -445,6 +588,11 @@ class DispatchCounters:
         self.global_pairs += (cfg.num_hidden_layers - n_w) * g_pairs
         self.window_kv_blocks += n_w * ran
         self.window_kv_blocks_unskipped += n_w * unskipped
+        in_tiles = runs_in_tiles(t_pad)
+        self.dense_tiles += (
+            _tiles.dense_tiles(t_pad, n_tokens) if in_tiles else 1)
+        self.dense_tiles_rung += (
+            _tiles.dense_tiles(t_pad, t_pad) if in_tiles else 1)
 
     def stats(self) -> dict:
         cfg = self.config
@@ -467,6 +615,9 @@ class DispatchCounters:
             "global_pairs": self.global_pairs,
             "window_kv_blocks": self.window_kv_blocks,
             "window_kv_blocks_unskipped": self.window_kv_blocks_unskipped,
+            "dense_tile": DENSE_TILE,
+            "dense_tiles": self.dense_tiles,
+            "dense_tiles_rung": self.dense_tiles_rung,
         }
 
 

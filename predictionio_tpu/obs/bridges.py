@@ -257,6 +257,22 @@ def bridge_fastpath(
                     [("", (), _num(hot.get("resident")))],
                 ),
             ])
+        if "dense_tiles" in s:  # a packed sequence family that runs in tiles
+            fams.extend([
+                _fam(
+                    "pio_fastpath_dense_tiles_total", "counter",
+                    "Token tiles the sequence programs' position-wise "
+                    "sublayers ran (only tiles that hold a real token).",
+                    [("", (), _num(s.get("dense_tiles")))],
+                ),
+                _fam(
+                    "pio_fastpath_dense_tiles_rung_total", "counter",
+                    "Token tiles of the rungs those dispatches ran in; "
+                    "the ratio is the share of a rung the dense sublayers "
+                    "pay for.",
+                    [("", (), _num(s.get("dense_tiles_rung")))],
+                ),
+            ])
         kern = s.get("kernel")
         if isinstance(kern, dict):
             fams.extend([

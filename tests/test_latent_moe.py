@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fingerprints
+
 from predictionio_tpu.models import latent_moe as lm
 from predictionio_tpu.models.latent_moe_reference import (
     _rope, reference_forward,
@@ -442,3 +444,27 @@ def test_the_batchers_cut_follows_the_algorithms_only_when_they_agree(
     algos = [SimpleNamespace(batch_row_ladder=lad) if lad else object()
              for lad in ladders]
     assert _batch_buckets(algos, (1, 8, 64)) == want
+
+
+# -- this family's program does not run in token tiles (PR 42) -------------------
+
+
+@pytest.mark.parametrize("t", fingerprints.RUNGS["latent_moe"])
+def test_every_rungs_program_is_the_parents_jaxpr_for_jaxpr(t):
+    """The window family runs its dense sublayers in token tiles from 2,048
+    tokens on (``ops/token_tiles``) and imports this module's ``_mm``,
+    ``_swiglu`` and ``rms_norm``; this one is left as it was — a dispatch
+    here is the experts' weights from HBM — at EVERY rung: PR 41's text, by
+    its fingerprint."""
+    assert fingerprints.fingerprint("latent_moe", CFG, t) == \
+        fingerprints.PARENT[f"latent_moe.{t}"]
+
+
+def test_the_scorer_reports_no_tiles_for_a_family_that_runs_none(weights):
+    from predictionio_tpu.serving.seqpath import PackedSequenceScorer
+
+    sc = PackedSequenceScorer(CFG, weights["f32"], max_k=K, ladder=(64,),
+                              max_rows=4)
+    sc.score_topk(_histories(10, (5, 20)), 5)
+    assert not {"dense_tile", "dense_tiles", "dense_tiles_rung"} & set(
+        sc.stats())

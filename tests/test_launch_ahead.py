@@ -252,9 +252,13 @@ def test_a_launched_ahead_run_teaches_the_time_from_the_previous_return(rig):
     assert s["launch_run_ms"]["1"] == pytest.approx(PROGRAM_S * 1e3, abs=10)
     # and the cut's per-rung estimate (a RUN at one row: assembly, program,
     # wake-up, answers) leaves the queued time out as well: no run read
-    # longer for having been launched early
-    assert s["rung_run_ms"]["1"] == pytest.approx(
-        (2 * HOST_S + PROGRAM_S + WAKE_S) * 1e3 - 5, abs=15)
+    # longer for having been launched early.  The upper side is that claim
+    # and stays where it was (a program queued for its whole wait reads
+    # 195); the lower is only "no shorter than the program": a launched-
+    # ahead run is timed from the previous return, which a loaded CPU
+    # delivers late, so the least of five read 97.6 and 99.8 under xdist
+    run_ms = (2 * HOST_S + PROGRAM_S + WAKE_S) * 1e3 - 5
+    assert PROGRAM_S * 1e3 < s["rung_run_ms"]["1"] <= run_ms + 15
 
 
 # -- (b) no estimate, or nothing waiting: as before ------------------------------
@@ -344,13 +348,16 @@ def test_never_more_than_one_run_is_queued_behind_the_one_in_flight(rig):
     ran = [q for batch in scorer.batches for q in batch if q in queued]
     assert ran == put_order
     # back to back: between two programs of a chain the device idled less
-    # than the host's share of a cycle, which it waited out before
+    # than the host's share of a cycle — the wake-up, the answers' building
+    # and the next batch's assembly — which it waited out before.  (Not
+    # HOST_S alone: these are host-clock gaps between threads, and with six
+    # xdist workers busy a wake-up alone comes 10 ms late now and then.)
     ahead = {tuple(b) for b, r in zip(
         scorer.batches, sorted(records(mb).values(), key=lambda r: r["seq"]))
         if r["launchedAhead"]}
     chained = sorted(g for g, r in zip(device.gaps_ms(), device.ran[1:])
                      if r[0] in ahead)
-    assert chained[len(chained) // 2] < HOST_S * 1e3
+    assert chained[len(chained) // 2] < (WAKE_S + 2 * HOST_S) * 1e3
 
 
 # -- (d) what the batcher promised before still holds -----------------------------

@@ -31,6 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fingerprints
+import token_tile_cases as cases
+
 from predictionio_tpu.models import latent_moe as lm
 from predictionio_tpu.models import window_moe as wm
 from predictionio_tpu.models import window_moe_reference as ref_mod
@@ -643,6 +646,13 @@ def test_template_serves_windowmoe_through_the_batcher(served):
     assert after["compile_count"] == 2 and after["calls"] == 3
     assert after["sparse_layer_dispatches"] == 3 * 4
     assert after["window_pairs"] > 0 and after["routed_assignments"] > 0
+    # the dense sublayers' tiles: in `GET /` and as `pio_fastpath_*` (both
+    # rungs here hold no more than two tiles of 512, so each dispatch ran one)
+    assert after["dense_tiles"] == after["dense_tiles_rung"] == 3
+    with urllib.request.urlopen(base + "/metrics", timeout=30) as r:
+        text = r.read().decode()
+    assert "pio_fastpath_dense_tiles_total 3" in text
+    assert "pio_fastpath_dense_tiles_rung_total 3" in text
     recs = _http(base + "/trace/dispatches.json")["dispatches"]
     assert recs[-1]["rung"] in (64, 128)
 
@@ -662,3 +672,88 @@ def test_train_refuses_a_published_width_and_shares_the_algorithm():
         "n_items": 100, "item_map": None})(), "histories": None})()
     with pytest.raises(NotImplementedError, match="no trainer"):
         algo.train(None, pd)
+
+
+# -- the dense sublayers in token tiles (ops/token_tiles) ------------------------
+
+
+@pytest.fixture(scope="module")
+def tile_programs():
+    return cases.programs(wm, CFG)
+
+
+@pytest.mark.parametrize("lens", cases.LENS, ids=str)
+def test_in_token_tiles_every_real_row_is_the_whole_rungs(
+        weights, tile_programs, lens):
+    whole, tiled = cases.check_real_rows(
+        wm, CFG, weights["f32"], lens, tile_programs, exact=("expert_counts", "tokens_unheld", "attn_counts"))
+    # the routing of every REAL token too (a padded token's is no output)
+    n_tok = sum(lens)
+    np.testing.assert_array_equal(tiled["picks"][:, :n_tok],
+                                  whole["picks"][:, :n_tok])
+
+
+@pytest.mark.parametrize("lens", cases.LENS, ids=str)
+def test_the_trunk_runs_the_tiles_that_hold_a_real_token_and_no_other(
+        weights, lens):
+    b = wm.pack(cases.histories(CFG, 22, lens), cases.T, cases.ROWS)
+    x = wm.trunk(CFG, weights["f32"], b["tokens"], b["positions"],
+                 b["seg_start"], jnp.asarray(b["valid"]),
+                 dense_tile=cases.TILE)[0]
+    cases.check_trip_count(x, sum(lens))
+
+
+@pytest.mark.parametrize("t", fingerprints.RUNGS["window_moe"])
+def test_a_rung_of_two_tiles_or_fewer_is_the_parents_program_jaxpr_for_jaxpr(
+        t):
+    """The 256-, 512- and 1,024-token programs at the program's own tile:
+    the parent's (PR 41's) text, by its fingerprint; from 2,048 tokens the
+    loops are there."""
+    assert fingerprints.fingerprint("window_moe", CFG, t) == fingerprints.PARENT[
+        f"window_moe.{t}"]
+    low, high = (fingerprints.program_jaxpr("window_moe", CFG, n).count(
+        "dynamic_update_slice") for n in (t, 2048))
+    assert high > low
+
+
+@pytest.mark.parametrize("t_pad, n_tok, ran, rung", [
+    (256, 100, 1, 1), (512, 512, 1, 1), (1024, 513, 1, 1),  # run whole
+    (2048, 1025, 3, 4), (2048, 2048, 4, 4), (4096, 2049, 5, 8),
+    (16384, 8193, 17, 32), (16384, 16384, 32, 32),
+])
+def test_the_familys_counters_add_the_tiles_the_program_ran(
+        t_pad, n_tok, ran, rung):
+    """``runs_in_tiles`` decides for the program (``trunk``) and for the
+    counter alike; a rung run whole counts as one tile, run."""
+    assert wm.runs_in_tiles(t_pad) == (rung > 1)
+    own = wm.DispatchCounters(CFG)
+    got = {"expert_counts": np.ones((CFG.n_moe_layers, CFG.n_held), np.int32),
+           "tokens_unheld": np.zeros(CFG.n_moe_layers, np.int32),
+           "attn_counts": np.zeros(4, np.int32)}
+    for _ in range(2):
+        own.add(t_pad, 1, n_tok, got)
+    st = own.stats()
+    assert st["dense_tile"] == 512
+    assert (st["dense_tiles"], st["dense_tiles_rung"]) == (2 * ran, 2 * rung)
+
+
+def test_through_the_scorer_a_rung_in_tiles_answers_as_a_rung_run_whole(
+        weights):
+    """At the program's own tile, through the ONE scorer class: 1,200 tokens
+    in a rung of 2,048 run three tiles of four, and every row is answered as
+    the 1,024 rung's program, which runs whole, answers it."""
+    from predictionio_tpu.serving.seqpath import PackedSequenceScorer
+
+    hists = _histories(23, (60,) * 20)
+    build = lambda rung: PackedSequenceScorer(
+        CFG, weights["f32"], max_k=K, ladder=(rung,), max_rows=32)
+    tiled, whole = build(2048), build(1024)
+    idx, vals = tiled.score_topk(hists, 5)
+    st = tiled.stats()
+    assert (st["calls"], st["dense_tiles"], st["dense_tiles_rung"]) == (1, 3, 4)
+    want = [whole.score_topk(hists[lo:lo + 10], 5) for lo in (0, 10)]
+    st = whole.stats()
+    assert (st["calls"], st["dense_tiles"], st["dense_tiles_rung"]) == (2, 2, 2)
+    np.testing.assert_array_equal(idx, np.concatenate([w[0] for w in want]))
+    np.testing.assert_allclose(vals, np.concatenate([w[1] for w in want]),
+                               rtol=1e-4, atol=1e-6)

@@ -1,7 +1,10 @@
 """First chip look at the gated-delta-rule hybrid: set-up times, device time
 per token rung, the scan and attention kernels alone (chunk 64 against 128),
 op names in a trace, peak memory.  Measures the checkout this file lies in
-and writes `chiprun_out/gdn_first_look.json`."""
+and writes `chiprun_out/gdn_first_look.json`.  `--rungs` (PR 42): per
+token rung the bare program on rows of at most 2,048 events that FILL it
+(`n = R`) and on HALF the rung and one event (`n = R/2 + 1`: what a rung's
+padded tail costs), then stop; `--out FILE` says where that goes."""
 import glob, json, os, sys, time
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 OUT = os.path.join(ROOT, "chiprun_out")
@@ -30,6 +33,23 @@ def timed(fn, n=7):
     for _ in range(n):
         t0 = time.perf_counter(); jax.block_until_ready(fn()); ts.append((time.perf_counter() - t0) * 1e3)
     return sorted(ts)[n // 2]
+def rows(n):  # n tokens as rows of at most max_len events
+    return [hist(min(2048, n - at)) for at in range(0, n, 2048)]
+if "--rungs" in sys.argv:
+    per_rung = {}
+    for t in sc.ladder:
+        for label, n in (("full", t), ("half_plus_one", t // 2 + 1)):
+            dev = sc._put(gh.pack(rows(n), t, sc.max_rows))
+            per_rung[f"{t}.{label}"] = {"tokens": n, "device_ms": timed(lambda: sc._fns[t](sc._params, dev)["values"])}
+            print(t, label, per_rung[f"{t}.{label}"], flush=True)
+    out["per_rung"] = per_rung
+    out["memory"] = {k_: v_ for k_, v_ in (jax.devices()[0].memory_stats() or {}).items() if "bytes" in k_}
+    out["stats"] = sc.stats()
+    result = (sys.argv[sys.argv.index("--out") + 1] if "--out" in sys.argv
+              else os.path.join(OUT, "gdn_first_look.rungs.json"))
+    os.makedirs(os.path.dirname(os.path.abspath(result)), exist_ok=True)
+    json.dump(out, open(result, "w"), indent=1, default=str)
+    sys.exit(0)
 times = {}
 for label, hh in [("1x130", [hist(130)]), ("1x250", [hist(250)]), ("2x250", [hist(250)] * 2), ("1x1000", [hist(1000)]), ("1x2048", [hist(2048)]),
                   ("8x250", [hist(250)] * 8), ("16x250", [hist(250)] * 16), ("32x250", [hist(250)] * 32), ("4x2048", [hist(2048)] * 4)]:
