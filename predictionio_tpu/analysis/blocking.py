@@ -1,7 +1,8 @@
 """Blocking-call detector for the serving dispatch hot loop.
 
 The micro-batcher worker (``serving/batching.py``), the fastpath
-scorer (``serving/fastpath.py``), the shard fan-out/merge layer
+scorers (``serving/fastpath.py``, ``serving/seqpath.py``) and the dispatch
+protocol they share (``serving/rungs.py``), the shard fan-out/merge layer
 (``serving/sharding.py``), and the IVF probe-selection/pruned-scan
 helpers (``ops/ivf.py``) sit between every query and the TPU: one
 ``time.sleep``, ``fsync``, JSON round-trip, or synchronous network
@@ -45,8 +46,8 @@ R_BLOCKING = rule(
 # dispatch modules: every function is hot unless exempted.
 # tenancy.py admission and pipeline.py stage execution run under every
 # multi-tenant / composed-pipeline query — as hot as the batcher
-_HOT_MODULES = ("batching.py", "fastpath.py", "sharding.py",
-                "tenancy.py", "pipeline.py")
+_HOT_MODULES = ("batching.py", "fastpath.py", "seqpath.py", "rungs.py",
+                "sharding.py", "tenancy.py", "pipeline.py")
 # ops modules on the serving dispatch path: probe selection and the
 # pruned scan in ivf.py run under every cache-miss query
 _HOT_OPS_MODULES = ("ivf.py",)

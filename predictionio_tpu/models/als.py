@@ -100,7 +100,8 @@ class ALSConfig:
     # sweep toggling the env var between configs takes effect.
     solver: Optional[str] = None
     # Training-kernel backend ("fused" | "reference" | "auto"): the
-    # dispatch seam for ops/train_kernel.py, mirroring PIO_SCORE_KERNEL.
+    # dispatch seam for ops/train_kernel.py, as ops/topk.resolve_backend
+    # is the score kernel's.
     # "auto" takes the Pallas path only on real TPU; PIO_NATIVE=0 forces
     # "reference" at resolution time.  None → the PIO_TRAIN_KERNEL env
     # knob (default "auto"), resolved at construction time.

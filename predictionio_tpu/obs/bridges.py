@@ -229,34 +229,6 @@ def bridge_fastpath(
                     ],
                 )
             )
-        hot = s.get("hotset")
-        if isinstance(hot, dict):
-            fams.extend([
-                _fam(
-                    "pio_hotset_lookups_total", "counter",
-                    "Fastpath rows answered from the materialized hot-set "
-                    "table (hit) vs the bucketed device path (miss).",
-                    [
-                        ("", (("outcome", "hit"),), _num(hot.get("hits"))),
-                        ("", (("outcome", "miss"),), _num(hot.get("misses"))),
-                    ],
-                ),
-                _fam(
-                    "pio_hotset_refreshes_total", "counter",
-                    "Hot-set re-rank + table materialization passes.",
-                    [("", (), _num(hot.get("refreshes")))],
-                ),
-                _fam(
-                    "pio_hotset_size", "gauge",
-                    "Configured hot-set working-set bound.",
-                    [("", (), _num(hot.get("size")))],
-                ),
-                _fam(
-                    "pio_hotset_resident", "gauge",
-                    "Users currently materialized in the hot-set table.",
-                    [("", (), _num(hot.get("resident")))],
-                ),
-            ])
         if "dense_tiles" in s:  # a packed sequence family that runs in tiles
             fams.extend([
                 _fam(

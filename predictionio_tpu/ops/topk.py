@@ -10,9 +10,8 @@ dispatches between two backends behind a single seam:
   and a masked running top-k in one kernel, factors staying in VMEM
   between stages.  Off-TPU the same kernel runs in interpret mode.
 
-Selection: the ``backend=`` argument wins, else ``PIO_SCORE_KERNEL``
-(``fused`` | ``reference`` | ``auto``, default ``auto``).  ``auto`` picks
-the fused kernel ONLY on TPU — it never silently selects the TPU kernel
+Selection: the ``backend=`` argument (``fused`` | ``reference`` | ``auto``,
+default ``auto``).  ``auto`` picks the fused kernel ONLY on TPU — it never silently selects the TPU kernel
 on CPU, where interpret mode would lose badly; forcing ``fused`` off-TPU
 is explicit opt-in (that is how the CPU equivalence tests run the real
 kernel).  ``PIO_NATIVE=0`` (the repo-wide native kill switch) forces
@@ -45,17 +44,15 @@ SCORE_SCOPE = "pio.score_topk"
 def resolve_backend(requested: Optional[str] = None) -> str:
     """Resolve the score-path backend: ``"fused"`` or ``"reference"``.
 
-    ``requested`` overrides ``PIO_SCORE_KERNEL``; ``auto`` (the default)
+    ``requested`` is a caller's ``backend=``; ``auto`` (the default)
     takes the fused kernel only on TPU.  ``PIO_NATIVE=0`` forces the
     reference path — the same kill switch that disables every other
     native kernel in the repo.
     """
-    req = (
-        requested or os.environ.get("PIO_SCORE_KERNEL") or "auto"
-    ).strip().lower()
+    req = (requested or "auto").strip().lower()
     if req not in BACKENDS:
         raise ValueError(
-            f"PIO_SCORE_KERNEL must be one of {BACKENDS}, got {req!r}"
+            f"backend= must be one of {BACKENDS}, got {req!r}"
         )
     if os.environ.get("PIO_NATIVE", "1") == "0":
         return "reference"

@@ -53,24 +53,18 @@ while the probed set covers each row's union of likely clusters
 items).  IVF composes with the replicated placement only; a sharded plan
 takes precedence and retrieval degrades to exact with a warning.
 
-HOT-SET PATH (``PIO_HOTSET_SIZE``, off by default): ALS scores are static
-between reloads — a hot user's top-k is the SAME answer every time until
-the next generation deploys.  The scorer keeps decayed per-user request
-counts; every ``PIO_HOTSET_REFRESH_QUERIES`` scored rows it re-ranks the
-top ``PIO_HOTSET_SIZE`` users and materializes their full top-k table in
-top-rung device passes through the already-compiled b=max program (zero
-new compiles — the AOT contract holds).  Queries for hot users are then
-answered from the table with zero device work; only cold users ride the
-bucketed device path.  Decaying the counts at each re-rank lets the
-working set track traffic drift.
+HOW a rung's program is compiled, warmed, launched and read back — and what
+the micro-batcher is told about it — is :class:`serving.rungs.RungPrograms`,
+shared with the packed sequence scorer; the placements, the padding, the
+chunking over the top rung and the counters are here.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
-import time
 from typing import Optional
 
 import jax
@@ -88,9 +82,7 @@ from predictionio_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, MeshContext, pad_to_multiple, shard_map,
 )
 from predictionio_tpu.serving import sharding as _sharding
-from predictionio_tpu.serving.launch_gate import (
-    LaunchGate, measure_lag, program_bytes,
-)
+from predictionio_tpu.serving.rungs import RungPrograms
 
 logger = logging.getLogger(__name__)
 
@@ -172,8 +164,6 @@ class BucketedScorer:
         item_factors: np.ndarray,
         max_k: int = 100,
         buckets=BUCKETS,
-        hot_size: Optional[int] = None,
-        hot_refresh_queries: Optional[int] = None,
         factor_dtype: str = "f32",
         user_scale: Optional[np.ndarray] = None,
         item_scale: Optional[np.ndarray] = None,
@@ -187,7 +177,7 @@ class BucketedScorer:
         self.n_users = user_factors.shape[0]
         self.n_items = item_factors.shape[0]
         # score-kernel backend for THIS scorer generation, resolved once at
-        # construction (PIO_SCORE_KERNEL; auto → fused only on TPU)
+        # construction (auto → fused only on TPU)
         self.backend = resolve_backend(backend)
         self.factor_dtype = factor_dtype
         if factor_dtype == "int8" and (user_scale is None or item_scale is None):
@@ -254,39 +244,12 @@ class BucketedScorer:
         self._ivf_scanned_rows = 0
         self._ivf_dispatch_rows = 0
         self._lock = threading.Lock()
-        self.compile_count = 0
-        self.hits: dict[int, int] = {b: 0 for b in self.buckets}
         self.queries = 0
         self.padded_rows = 0
-        # dispatches whose readback was requested before the wait, counted
-        # where it is requested (_queue_readback): equals stats()["calls"]
-        self.readbacks_queued = 0
         # the fused kernel's merge counters, summed over dispatches: passes
         # that inserted, blocks that merged anything (ops/score_kernel.py)
         self.merge_passes = 0
         self.merge_blocks = 0
-        # hot-set working set (off unless PIO_HOTSET_SIZE > 0): decayed
-        # per-user request counts drive a periodic re-rank that materializes
-        # the hot users' top-k once per refresh instead of once per query
-        if hot_size is None:
-            hot_size = int(os.environ.get("PIO_HOTSET_SIZE", "0") or 0)
-        if hot_refresh_queries is None:
-            hot_refresh_queries = int(
-                os.environ.get("PIO_HOTSET_REFRESH_QUERIES", "2048") or 2048
-            )
-        self.hot_size = max(0, min(int(hot_size), self.n_users))
-        self.hot_refresh_queries = max(1, int(hot_refresh_queries))
-        self._hot_counts = (
-            np.zeros(self.n_users, np.float32) if self.hot_size else None
-        )
-        self._hot_since_refresh = 0
-        # user_idx → row in the materialized (hot_size, k) answer table
-        self._hot_rows: dict[int, int] = {}
-        self._hot_table_idx: Optional[np.ndarray] = None
-        self._hot_table_val: Optional[np.ndarray] = None
-        self.hot_hits = 0
-        self.hot_misses = 0
-        self.hot_refreshes = 0
         # device-utilization accountant: each bucket is cost-annotated at
         # compile time below, each dispatch records its device wall, and
         # the query server's bridge exports the windowed pio_device_*
@@ -298,32 +261,18 @@ class BucketedScorer:
         # per-bucket annotated HBM bytes, kept host-side so the sharded
         # merge-time attribution doesn't re-enter the accountant per call
         self._cost_bytes: dict[int, float] = {}
-        # AOT warmup: every rung compiled before the first request, then
-        # executed once — a lazily-materialized kernel (Pallas included)
-        # can never surface its first-dispatch cost under traffic
-        self.warmup_executions = 0
-        self._fns = {b: self._compile(b) for b in self.buckets}
-        # _device_topk is entered by two threads at once (the batcher's
-        # launch-ahead): two programs enqueued only where both fit, and
-        # the accountant charged no second of the device twice
-        self._gate = LaunchGate(
-            ctx.mesh.devices.flat[0],
-            {b: program_bytes(f) for b, f in self._fns.items()})
+        # score_topk is entered by two threads at once (the batcher's
+        # launch-ahead): the accountant is charged no second of the device
+        # twice
         self._last_return = 0.0
-        for b in self.buckets:
-            # the input in the form a dispatch hands over, so the call's
-            # handling of it is warm too
-            dummy_idx = self._call_input(np.zeros(b, np.int32))
-            jax.block_until_ready(self._fns[b](*self._static_args, dummy_idx))
-            self.warmup_executions += 1
-        # the host hears of a program's end this much after it (the batcher
-        # aims its launch-ahead by it): the lowest rung's program on the
-        # warm-up's input, twice in a row on the idle device
-        b = self.buckets[0]
-        dummy_idx = self._call_input(np.zeros(b, np.int32))
-        self.launch_lag_s = measure_lag(
-            lambda: self._fetched(self._fns[b](*self._static_args, dummy_idx)),
-            jax.device_get)
+        # AOT: every rung compiled and run once before the first request
+        self._rungs = RungPrograms(
+            ctx.mesh.devices.flat[0], self.buckets, self._compile,
+            warm_args=lambda b: self._call_args(np.zeros(b, np.int32)),
+            fetch=self._fetch)
+        self._fns = self._rungs.fns
+
+    compile_count = property(lambda self: self._rungs.compile_count)
 
     def _put_repl(self, x: np.ndarray):
         """Replicate a host array on the serving mesh, multi-process safe.
@@ -339,33 +288,24 @@ class BucketedScorer:
 
         return jax.device_put(jnp.asarray(x), self._repl)
 
-    def _call_input(self, x: np.ndarray):
-        """A dispatch's host array as the compiled program takes it: the
-        array itself.  The call's own argument handling places it under
-        the program's input sharding, so it gets no ``device_put`` (a host
-        ↔ device round trip) of its own first.  Across processes one
-        host's array cannot feed remote shards: there it is placed."""
-        return self._put_repl(x) if self._pod_spans else x
+    def _call_args(self, padded: np.ndarray) -> tuple:
+        """A rung's arguments: the factors as they are resident NOW (a
+        delta may have patched them) and the padded rows as the host array
+        itself.  The call's own argument handling places it under the
+        program's input sharding, so it gets no ``device_put`` (a host ↔
+        device round trip) of its own first.  Across processes one host's
+        array cannot feed remote shards: there it is placed."""
+        with _tracing.stage("h2d"):
+            rows = self._put_repl(padded) if self._pod_spans else padded
+            return (*self._static_args, rows)
 
-    def _queue_readback(self, outs: tuple) -> tuple:
-        """The arrays a dispatch reads back from a program's REPLICATED
-        outputs, each one's device→host copy requested NOW: called on the
-        not-yet-ready outputs the launch returned, it queues the copies
-        behind the program, so ONE wait (the ``device_get`` of what this
-        returns) ends the run, instead of a wake-up for the program and a
-        second round trip asked for after it.  Multi-process
-        safe: any one addressable shard of a replicated array is the whole
-        value."""
-        outs = self._fetched(outs)
-        with self._lock:
-            self.readbacks_queued += 1
-        return outs
-
-    def _fetched(self, outs: tuple) -> tuple:
+    def _fetch(self, outs: tuple) -> tuple:
+        """What a dispatch reads back of a program's REPLICATED outputs:
+        all of them ((vals, idx), and the merge counters where the program
+        was compiled with them).  Multi-process safe: any one addressable
+        shard of a replicated array is the whole value."""
         if self._pod_spans:
-            outs = tuple(x.addressable_data(0) for x in outs)
-        for x in outs:
-            x.copy_to_host_async()
+            return tuple(x.addressable_data(0) for x in outs)
         return outs
 
     def _init_replicated_placement(
@@ -617,9 +557,7 @@ class BucketedScorer:
         to the replicated user matrix on every placement; item rows are
         routed to their owning shard/cluster slot through the active
         ShardingPlan layout.  Quantized factors are re-quantized row-wise
-        (same per-row-scale scheme as publish).  Affected users fall out
-        of the hot-set table so their next lookup re-ranks against the
-        patched factors.
+        (same per-row-scale scheme as publish).
         """
         import jax.numpy as jnp
 
@@ -657,8 +595,6 @@ class BucketedScorer:
         n_items = self._apply_item_rows(item_idx, item_rows)
         with self._lock:
             self._rebuild_static_args()
-            for u in users:
-                self._hot_rows.pop(int(u), None)
         return {
             "users": int(len(users)), "items": int(n_items),
             "compile_count": self.compile_count,
@@ -772,8 +708,6 @@ class BucketedScorer:
             .lower(*self._static_args, dummy_idx)
             .compile()
         )
-        with self._lock:
-            self.compile_count += 1
         self._annotate_cost(b, compiled)
         return compiled
 
@@ -855,8 +789,6 @@ class BucketedScorer:
             .lower(*self._static_args, dummy_idx)
             .compile()
         )
-        with self._lock:
-            self.compile_count += 1
         # always the analytic model: the probe scan's Pallas calls are
         # opaque to XLA cost analysis, and the analytic scanned-rows
         # number (P_b·cap_pad, not the full catalog) IS the story
@@ -874,7 +806,8 @@ class BucketedScorer:
                 b, scanned, rank, dtype=self.factor_dtype
             )
             self.devprof.set_cost(b, a_flops, a_bytes, source="analytic")
-        self._cost_bytes[b] = a_bytes
+        # construction-time: RungPrograms calls _compile from __init__ only
+        self._cost_bytes[b] = a_bytes  # pio: ignore[race-unguarded-rmw]
         return compiled
 
     def _compile_sharded(self, b: int):
@@ -985,8 +918,6 @@ class BucketedScorer:
             .lower(*self._static_args, dummy_idx)
             .compile()
         )
-        with self._lock:
-            self.compile_count += 1
         self._annotate_cost(b, compiled)
         return compiled
 
@@ -1039,51 +970,16 @@ class BucketedScorer:
         any size works without growing the compile cache.  ``k`` beyond the
         compiled width raises ValueError — callers route that to their
         exact path instead of silently truncating.
-
-        With the hot set enabled, users present in the materialized table
-        are answered from host memory (their scores cannot change until
-        the next model generation replaces this scorer); only the cold
-        remainder pays a device pass.  Output order is preserved.
         """
         if k > self.k:
             raise ValueError(f"k={k} exceeds compiled top-k width {self.k}")
-        users = np.asarray(user_indices, np.int32)
-        if self._hot_counts is None:
-            return self._device_topk(users, k)
-        self._note_traffic(users)
-        with self._lock:
-            rows = self._hot_rows
-            table_idx = self._hot_table_idx
-            table_val = self._hot_table_val
-        if table_idx is None:
-            return self._device_topk(users, k)
-        hot_rows = np.fromiter(
-            (rows.get(int(u), -1) for u in users), np.int64, count=len(users)
-        )
-        hot_mask = hot_rows >= 0
-        n_hot = int(hot_mask.sum())
-        with self._lock:
-            self.hot_hits += n_hot
-            self.hot_misses += len(users) - n_hot
-        if n_hot == 0:
-            return self._device_topk(users, k)
-        idx_out = np.empty((len(users), k), table_idx.dtype)
-        val_out = np.empty((len(users), k), table_val.dtype)
-        idx_out[hot_mask] = table_idx[hot_rows[hot_mask], :k]
-        val_out[hot_mask] = table_val[hot_rows[hot_mask], :k]
-        cold = users[~hot_mask]
-        if len(cold):
-            c_idx, c_val = self._device_topk(cold, k)
-            idx_out[~hot_mask] = c_idx
-            val_out[~hot_mask] = c_val
-        return idx_out, val_out
+        return self._device_topk(np.asarray(user_indices, np.int32), k)
 
     def _device_topk(
-        self, users: np.ndarray, k: int, more: bool = False
+        self, users: np.ndarray, k: int
     ) -> tuple[np.ndarray, np.ndarray]:
-        """The bucketed device path (pre-hot-set ``score_topk`` body).
-        ``more``: the caller has further launches to make in the same
-        batch run (the hot-set refresh, ahead of the rows asked for)."""
+        """``score_topk`` past its checks: ``users`` in top-rung chunks,
+        each padded to its rung (benchmark/tests/ alter answers here)."""
         top = self.buckets[-1]
         idx_parts, val_parts = [], []
         for s in range(0, len(users), top):
@@ -1091,55 +987,30 @@ class BucketedScorer:
             b = bucket_for(len(chunk), self.buckets)
             padded = np.zeros(b, np.int32)
             padded[: len(chunk)] = chunk
-            for t in _tracing.active_traces():
-                t.annotate(bucket=b)
-            disp = _tracing.active_dispatch()
-            if disp is not None:
-                # what the batcher times this launch by, and whether the
-                # run's end can be told from it
-                disp.rung, disp.lag = b, self.launch_lag_s
-                disp.more = more or s + top < len(users)
-            with _tracing.stage("h2d"):
-                # the padded rows ride the compiled call; only a pod that
-                # spans processes still places them here
-                u_in = self._call_input(padded)
-            with _tracing.stage("device_compute"), self._gate.flight(b):
-                t0 = time.perf_counter()
-                # (vals, idx), and the merge counters where the program
-                # was compiled with them (_compile)
-                with _tracing.launch():
-                    outs = self._fns[b](*self._static_args, u_in)
-                # asked for at launch, not after the wake-up
-                back = self._queue_readback(outs)
-                # the ONE wait, INSIDE the stage: the get of the queued
-                # copies returns when the program has run and its outputs
-                # have landed, so async dispatch can't smear device time
-                # past the stage and the utilization accountant charges
-                # the run and its readback, not enqueue time.  (On the chip
-                # a wait for the program first and the get after it cost
-                # one more wake-up of this thread: PERF.md §6, PR 38.)
-                val_h, idx_h, *merge = jax.device_get(back)
-                t1 = time.perf_counter()
-                # launched behind a program in flight, this one sat queued
-                # until that one returned: the accountant is charged what
-                # no earlier dispatch's wait covered
-                with self._lock:
-                    wall = t1 - max(t0, self._last_return)
-                    self._last_return = t1
-                self.devprof.record(b, wall)
+            # more: rows past the top rung are still to launch
+            (val_h, idx_h, *merge), t0, t1 = self._rungs.run(
+                b, functools.partial(self._call_args, padded),
+                more=s + top < len(users))
+            # launched behind a program in flight, this one sat queued
+            # until that one returned: the accountant is charged the run
+            # and its readback less what an earlier dispatch's wait covered
+            with self._lock:
+                wall = t1 - max(t0, self._last_return)
+                self._last_return = t1
+            self.devprof.record(b, wall)
             with _tracing.stage("d2h"):
                 # the readback's residue on the host: the rows asked for
                 # (padded tail rows are real top-k rows for user 0)
                 idx_rows = idx_h[: len(chunk), :k]
                 val_rows = val_h[: len(chunk), :k]
             with self._lock:
-                self.hits[b] += 1
                 self.queries += len(chunk)
                 self.padded_rows += b - len(chunk)
                 if merge:
                     passes, blocks = map(int, merge[0])
                     self.merge_passes += passes
                     self.merge_blocks += blocks
+                    disp = _tracing.active_dispatch()
                     if disp is not None:
                         disp.merge_passes += passes
                 if self._shard_acct is not None:
@@ -1158,47 +1029,6 @@ class BucketedScorer:
             val_parts.append(val_rows)
         return np.concatenate(idx_parts), np.concatenate(val_parts)
 
-    # -- hot set -------------------------------------------------------------
-    def _note_traffic(self, users: np.ndarray) -> None:
-        refresh = False
-        with self._lock:
-            np.add.at(self._hot_counts, users, 1.0)
-            self._hot_since_refresh += len(users)
-            if self._hot_since_refresh >= self.hot_refresh_queries:
-                self._hot_since_refresh = 0
-                refresh = True
-        if refresh:
-            self._refresh_hot_set()
-
-    def _refresh_hot_set(self) -> None:
-        """Re-rank the working set and materialize its top-k table.
-
-        Runs on the calling thread (one batch pays ~hot_size/top_rung
-        device passes per refresh interval) through the already-compiled
-        rungs, so ``compile_count`` stays flat — the AOT contract the
-        bench's zero-recompile check enforces.  The decay halves every
-        count afterward so the ranking follows traffic drift rather than
-        all-time popularity.
-        """
-        with self._lock:
-            counts = self._hot_counts.copy()
-        n = self.hot_size
-        if n < len(counts):
-            cand = np.argpartition(-counts, n - 1)[:n]
-        else:
-            cand = np.arange(len(counts))
-        cand = cand[counts[cand] > 0]
-        if len(cand) == 0:
-            return
-        cand = np.sort(cand).astype(np.int32)
-        idx, vals = self._device_topk(cand, self.k, more=True)
-        with self._lock:
-            self._hot_rows = {int(u): i for i, u in enumerate(cand)}
-            self._hot_table_idx = idx
-            self._hot_table_val = vals
-            self.hot_refreshes += 1
-            self._hot_counts *= 0.5
-
     def stats(self) -> dict:
         """Counters for ``GET /`` stats and bench artifacts.
 
@@ -1207,19 +1037,7 @@ class BucketedScorer:
         zero-recompile check.
         """
         with self._lock:
-            hits = dict(self.hits)
-            hot_lookups = self.hot_hits + self.hot_misses
-            hotset = {
-                "size": self.hot_size,
-                "resident": len(self._hot_rows),
-                "refresh_queries": self.hot_refresh_queries,
-                "hits": self.hot_hits,
-                "misses": self.hot_misses,
-                "refreshes": self.hot_refreshes,
-                "hit_rate": round(self.hot_hits / hot_lookups, 4)
-                if hot_lookups
-                else None,
-            }
+            rungs = self._rungs.stats()
             top = self.buckets[-1]
             costs = self.devprof.costs()
             top_cost = costs.get(top) or {}
@@ -1236,7 +1054,7 @@ class BucketedScorer:
                     self.buckets, self._V.shape[1], self._V.dtype,
                     self._n_items_pad,
                 ) if self.backend == "fused" else None,
-                "warmup_executions": self.warmup_executions,
+                "warmup_executions": rungs.pop("warmup_executions"),
                 # top-rung arithmetic intensity: the roofline position the
                 # docs derive (docs/perf_roofline.md)
                 "intensity_flops_per_byte": (
@@ -1308,14 +1126,7 @@ class BucketedScorer:
                 "retrieval_backend": self.retrieval,
                 "retrieval": retrieval,
                 "kernel": kernel,
-                "compile_count": self.compile_count,
-                "bucket_hits": {str(b): h for b, h in hits.items()},
-                "calls": sum(hits.values()),
-                "readbacks_queued": self.readbacks_queued,
-                # launches that waited for the program in flight because
-                # the two would not fit the device together
-                "held_launches": self._gate.held,
-                "launch_lag_ms": round(self.launch_lag_s * 1e3, 4),
+                **rungs,
                 "queries": self.queries,
                 "padded_rows": self.padded_rows,
                 "merge_passes": self.merge_passes,
@@ -1325,6 +1136,5 @@ class BucketedScorer:
                 )
                 if self.queries
                 else None,
-                "hotset": hotset if self.hot_size else None,
                 "devprof": dev,
             }

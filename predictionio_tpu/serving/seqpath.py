@@ -17,18 +17,23 @@ and hands over what differs: ``FAMILY`` (its name in ``stats()``),
 ``pack`` / ``flatten`` (the host side of a dispatch), ``forward_flat`` (the
 device program) and ``DispatchCounters`` (its own counters, and ``fetch``:
 the program's small outputs they are counted from).  The ladder, the
-compile-and-warm, the dispatch and the common counters are here, once.
+packing, the cut over the top rung and the sequence counters are here,
+once; how a rung's program is compiled, warmed, launched and read back is
+:class:`serving.rungs.RungPrograms`, shared with the bucketed scorer.
 
-A dispatch is ``h2d`` (one small index array) → ``device_compute`` (the
-whole forward pass and the head's top-k, ``pio_seq_forward``) → ``d2h``
-(ONE ``device_get`` of the (rows, k) values and indices and the program's
-small counters), the stage names every dispatch record and request trace
-already has.  What stays on the device unless asked for: ``h_last`` and
-the routing picks (:meth:`forward` returns them, for audits and tests).
+A dispatch is ``batch_assembly`` (the pack) → ``h2d`` (one small index
+array, which rides the compiled call) → ``device_compute`` (the whole
+forward pass and the head's top-k, ``pio_seq_forward``, and the one wait
+for the (rows, k) values and indices and the program's small counters) →
+``d2h`` (the rows asked for), the stage names every dispatch record and
+request trace already has.  What stays on the device unless asked for:
+``h_last`` and the routing picks (:meth:`forward` returns them, for audits
+and tests).
 """
 
 from __future__ import annotations
 
+import functools
 import importlib
 import threading
 from typing import Optional, Sequence
@@ -39,9 +44,7 @@ import numpy as np
 from predictionio_tpu.obs import tracing as _tracing
 from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.topk import resolve_backend
-from predictionio_tpu.serving.launch_gate import (
-    LaunchGate, measure_lag, program_bytes,
-)
+from predictionio_tpu.serving.rungs import RungPrograms
 
 # token counts a dispatch pads to; the top rung also bounds one dispatch
 TOKEN_LADDER = (256, 512, 1024, 2048, 4096, 8192)
@@ -77,36 +80,25 @@ class PackedSequenceScorer:
         self.resident_bytes = sum(
             int(np.prod(v.shape)) * v.dtype.itemsize
             for v in self._params.values())
-        self.compile_count = 0
-        self.warmup_executions = 0
-        self.hits = {t: 0 for t in self.ladder}
         self.queries = 0  # rows dispatched
         self.tokens = 0  # real tokens dispatched
         self.padded_tokens = 0
         # (query, key) pairs attention must visit: sum of n(n+1)/2 over rows
         self.causal_pairs = 0
         self.merge_passes = 0
-        # dispatches whose readback was requested before the wait, counted
-        # where it is requested (_queue_readback): equals stats()["calls"]
-        self.readbacks_queued = 0
         self._own = self._family.DispatchCounters(config)
-        self._fns = {t: self._compile(t) for t in self.ladder}
-        # score_topk is entered by two threads at once (the batcher's
-        # launch-ahead): two programs enqueued only where both fit
-        self._gate = LaunchGate(
-            self._device, {t: program_bytes(f) for t, f in self._fns.items()})
-        self._warm()
-        # the host hears of a program's end this much after it (the batcher
-        # aims its launch-ahead by it): the lowest rung's program on the
-        # warm-up's input, twice in a row on the idle device
-        flat = self._family.flatten(self._family.pack(
-            [np.zeros(1, np.int32)], self.ladder[0], self.max_rows))
-        self.launch_lag_s = measure_lag(
-            lambda: self._fetched(self._fns[self.ladder[0]](
-                self._params, flat)),
-            jax.device_get)
+        # read back: the leaderboard, the merge counter, the family's own
+        fetched = ("values", "indices", "merge") + self._own.fetch
+        self._rungs = RungPrograms(
+            self._device, self.ladder, self._compile,
+            warm_args=lambda t: self._call_args([np.zeros(1, np.int32)], t),
+            fetch=lambda out: {n: out[n] for n in fetched if n in out})
+        self._fns = self._rungs.fns
 
-    # -- compile + warm --------------------------------------------------
+    compile_count = property(lambda self: self._rungs.compile_count)
+    warmup_executions = property(lambda self: self._rungs.warmup_executions)
+
+    # -- compile ---------------------------------------------------------
     def _program(self, t: int):
         cfg, k, be = self.config, self.k, self.backend
         forward_flat = self._family.forward_flat
@@ -120,23 +112,11 @@ class PackedSequenceScorer:
         """Lower + compile the ``t``-token program ahead of time."""
         dummy = self._put(self._family.pack(
             [np.zeros(1, np.int32)], t, self.max_rows))
-        compiled = (
+        return (
             jax.jit(self._program(t))
             .lower(self._params, dummy)
             .compile()
         )
-        with self._lock:
-            self.compile_count += 1
-        return compiled
-
-    def _warm(self) -> None:
-        for t in self.ladder:
-            # the host array itself, as a dispatch hands it over, so the
-            # call's handling of it is warm too
-            flat = self._family.flatten(self._family.pack(
-                [np.zeros(1, np.int32)], t, self.max_rows))
-            jax.block_until_ready(self._fns[t](self._params, flat))
-            self.warmup_executions += 1
 
     def _put(self, batch: dict):
         return jax.device_put(self._family.flatten(batch), self._device)
@@ -164,10 +144,21 @@ class PackedSequenceScorer:
         n_tok = sum(len(h) for h in histories)
         batch = self._family.pack(
             histories, self.rung_for(n_tok), self.max_rows)
-        out = jax.device_get(self._fns[len(batch["tokens"])](
-            self._params, self._put(batch)))
+        out = self._rungs.direct(
+            len(batch["tokens"]), (self._params, self._put(batch)))
         out["batch"] = batch
         return out
+
+    def _call_args(self, rows, t: int) -> tuple:
+        """The ``t``-token program's arguments for ``rows``: the weights as
+        they are resident NOW and the packed histories."""
+        with _tracing.stage("batch_assembly"):
+            batch = self._family.pack(rows, t, self.max_rows)
+        with _tracing.stage("h2d"):
+            # the flat host array rides the compiled call, whose own
+            # argument handling places it: no device_put (a host ↔ device
+            # round trip) of its own
+            return self._params, self._family.flatten(batch)
 
     def score_topk(self, histories, k: int):
         """Top-``k`` (indices, values), one row per history (item-index
@@ -179,62 +170,21 @@ class PackedSequenceScorer:
             rows = histories[lo:hi]
             n_tok = sum(len(h) for h in rows)
             t = self.rung_for(n_tok)
-            for tr in _tracing.active_traces():
-                tr.annotate(bucket=t)
-            disp = _tracing.active_dispatch()
-            if disp is not None:
-                # what the batcher times this launch by, and whether the
-                # run's end can be told from it: not while rows past the
-                # top rung are still to launch
-                disp.rung, disp.more = t, hi < len(histories)
-                disp.lag = self.launch_lag_s
-            with _tracing.stage("batch_assembly"):
-                batch = self._family.pack(rows, t, self.max_rows)
-            with _tracing.stage("h2d"):
-                # the flat host array rides the compiled call, whose own
-                # argument handling places it: no device_put (a host ↔
-                # device round trip) of its own
-                flat = self._family.flatten(batch)
-            with _tracing.stage("device_compute"), self._gate.flight(t):
-                with _tracing.launch():
-                    out = self._fns[t](self._params, flat)
-                # asked for at launch, not after the wake-up: the copies
-                # queue behind the program
-                small = self._queue_readback(out)
-                # the ONE wait, INSIDE the stage as the bucketed scorer's:
-                # the get returns when the program has run and its outputs
-                # have landed.  Launched behind a program in flight, the
-                # stage also holds the time this one sat queued
-                got = jax.device_get(small)
+            # more: rows past the top rung are still to launch
+            got, _, _ = self._rungs.run(
+                t, functools.partial(self._call_args, rows, t),
+                more=hi < len(histories))
             with _tracing.stage("d2h"):
                 # the readback's residue on the host: the rows asked for
                 idx_rows = got["indices"][: len(rows), :k]
                 val_rows = got["values"][: len(rows), :k]
-            self._count(t, rows, n_tok, got, disp)
+            self._count(t, rows, n_tok, got)
             idx_parts.append(idx_rows)
             val_parts.append(val_rows)
         return np.concatenate(idx_parts), np.concatenate(val_parts)
 
-    def _queue_readback(self, out: dict) -> dict:
-        """The outputs a dispatch reads back (the leaderboard, the merge
-        counter, the family's ``fetch``), each one's device→host copy
-        requested NOW, on the not-yet-ready arrays the launch returned."""
-        small = self._fetched(out)
+    def _count(self, t, rows, n_tok, got) -> None:
         with self._lock:
-            self.readbacks_queued += 1
-        return small
-
-    def _fetched(self, out: dict) -> dict:
-        small = {name: out[name] for name in
-                 ("values", "indices", "merge") + self._own.fetch
-                 if name in out}
-        for x in small.values():
-            x.copy_to_host_async()
-        return small
-
-    def _count(self, t, rows, n_tok, got, disp) -> None:
-        with self._lock:
-            self.hits[t] += 1
             self.queries += len(rows)
             self.tokens += n_tok
             self.padded_tokens += t - n_tok
@@ -243,6 +193,7 @@ class PackedSequenceScorer:
             if "merge" in got:
                 passes = int(got["merge"][0])
                 self.merge_passes += passes
+                disp = _tracing.active_dispatch()
                 if disp is not None:
                     disp.merge_passes += passes
 
@@ -264,15 +215,7 @@ class PackedSequenceScorer:
                     head.shape[0],
                 ) if self.backend == "fused" else None,
                 "resident_bytes": self.resident_bytes,
-                "compile_count": self.compile_count,
-                "warmup_executions": self.warmup_executions,
-                "bucket_hits": {str(t): n for t, n in self.hits.items()},
-                "calls": sum(self.hits.values()),
-                "readbacks_queued": self.readbacks_queued,
-                # launches that waited for the program in flight because
-                # the two would not fit the device together
-                "held_launches": self._gate.held,
-                "launch_lag_ms": round(self.launch_lag_s * 1e3, 4),
+                **self._rungs.stats(),
                 "queries": self.queries,
                 "tokens": self.tokens,
                 "padded_tokens": self.padded_tokens,

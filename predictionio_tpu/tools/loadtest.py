@@ -101,9 +101,6 @@ def summarize_metrics(series: dict) -> dict:
         )
     if ("pio_batcher_coalesced_total", ()) in series:
         out["coalesced"] = total("pio_batcher_coalesced_total")
-    if total("pio_hotset_size"):
-        out["hotsetHits"] = total("pio_hotset_lookups_total", outcome="hit")
-        out["hotsetResident"] = total("pio_hotset_resident")
     # device-utilization families (ISSUE 8) only exist once the scorer has
     # recorded at least one cost-annotated dispatch; they carry a
     # {generation} label, so take the max across label sets — after a
@@ -249,7 +246,7 @@ def run_loadtest(
     ``dist="zipf"`` replaces the round-robin rotation with Zipf-Mandelbrot
     draws (``P(k) ∝ (k+q)^-s``, early sample values hottest) — the shape
     real traffic has, and the one the serving hot path (result cache,
-    single-flight, hot-set) is built to exploit.  Draws are seeded, so a
+    single-flight) is built to exploit.  Draws are seeded, so a
     run is reproducible.  With ``samples`` set, the summary also carries
     ``perKey``: per-key latency percentiles for the hottest keys plus a
     cold-tail aggregate, which is where a skew win (hot keys far below

@@ -1103,14 +1103,15 @@ def test_repo_analyzes_clean(repo_report):
 
 
 def test_the_serving_path_waives_no_block_sync_any_more():
-    """ISSUE 38: each scorer's ONE wait for the device is the `device_get`
+    """ISSUE 38: a dispatch's ONE wait for the device is the `device_get`
     of the readback it queued at launch, inside its `device_compute` stage.
     The two `hotpath-block-sync` waivers on the scorers' `block_until_ready`
     went with that call; none was added elsewhere (`models/als.py` keeps the
-    trainer's two)."""
+    trainer's two).  ISSUE 45: that sequence is written once, in
+    `RungPrograms.run`, and neither scorer's module names its parts."""
     import inspect
 
-    from predictionio_tpu.serving import fastpath, seqpath
+    from predictionio_tpu.serving import fastpath, rungs, seqpath
 
     waiver = "pio: ignore[hotpath-block-sync]"
     pkg = os.path.join(ROOT, "predictionio_tpu")
@@ -1124,14 +1125,21 @@ def test_the_serving_path_waives_no_block_sync_any_more():
                     counts[os.path.relpath(
                         os.path.join(dirpath, name), pkg)] = n
     assert counts == {os.path.join("models", "als.py"): 2}
-    for fn in (fastpath.BucketedScorer._device_topk,
-               seqpath.PackedSequenceScorer.score_topk):
-        body = inspect.getsource(fn)
-        assert "block_until_ready(" not in body
-        # launch, the copy asked for, the get that waits, then the d2h stage
-        assert body.index("_tracing.launch()") < body.index(
-            "_queue_readback(") < body.index("jax.device_get(") < body.index(
-            '_tracing.stage("d2h")')
+    body = inspect.getsource(rungs.RungPrograms.run)
+    assert "block_until_ready(" not in body
+    # the record is told, then inside the stage: launch, the copy asked
+    # for, the get that waits
+    assert body.index("disp.rung") < body.index(
+        '_tracing.stage("device_compute")') < body.index(
+        "_tracing.launch()") < body.index("self._request(") < body.index(
+        "jax.device_get(")
+    assert "copy_to_host_async()" in inspect.getsource(
+        rungs.RungPrograms._request)
+    for mod in (fastpath, seqpath):
+        text = inspect.getsource(mod)
+        for name in ("device_get", "copy_to_host_async", "launch_gate",
+                     "block_until_ready", "_tracing.launch"):
+            assert name not in text, (mod.__name__, name)
 
 
 def test_repo_knob_registry_is_fully_documented(repo_report):

@@ -226,18 +226,18 @@ class TestChipSmoke:
     def test_failed_warmup_fails_the_run_and_still_ends_in_a_summary(
         self, tmp_path
     ):
-        """The fast path cannot warm (a score backend that does not exist):
+        """The fast path cannot warm (a factor placement that does not exist):
         cold-start ``batching=True`` raises, the deploy phase fails, its
         dependents are skipped — exit 1, and the output still ends in the
         summary, naming all three, and the result line."""
         wd = tmp_path / "wd"
         r = self._run("--preset", "tiny", "--workdir", str(wd),
-                      PIO_SCORE_KERNEL="bogus")
+                      PIO_SERVING_SHARDING="bogus")
         assert r.returncode == 1, r.stdout[-2000:] + r.stderr[-2000:]
         summary, _ = _smoke_output(r.stdout)
         assert summary["ok"] is False and summary["phases_ok"] is False
         assert summary["failed"] == ["deploy", "queries", "readback"]
-        assert "PIO_SCORE_KERNEL" in summary["phases"]["deploy"]["error"]
+        assert "PIO_SERVING_SHARDING" in summary["phases"]["deploy"]["error"]
         assert summary["phases"]["queries"]["skipped"] == "needs ['deploy']"
         assert summary["phases"]["readback"]["skipped"] == "needs ['queries']"
         assert summary["phases"]["train"]["ok"] is True

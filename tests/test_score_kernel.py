@@ -458,22 +458,12 @@ class TestBackendResolution:
             assert resolve_backend("auto") == "reference"
             assert resolve_backend(None) == "reference"
 
-    def test_env_selector(self, monkeypatch):
-        monkeypatch.setenv("PIO_SCORE_KERNEL", "fused")
-        assert resolve_backend() == "fused"
-        monkeypatch.setenv("PIO_SCORE_KERNEL", "reference")
-        assert resolve_backend() == "reference"
-
-    def test_explicit_argument_wins_over_env(self, monkeypatch):
-        monkeypatch.setenv("PIO_SCORE_KERNEL", "reference")
-        assert resolve_backend("fused") == "fused"
-
     def test_pio_native_kill_switch(self, monkeypatch):
         monkeypatch.setenv("PIO_NATIVE", "0")
         assert resolve_backend("fused") == "reference"
 
     def test_invalid_backend_raises(self):
-        with pytest.raises(ValueError, match="PIO_SCORE_KERNEL"):
+        with pytest.raises(ValueError, match="backend="):
             resolve_backend("vectorized")
         assert set(BACKENDS) == {"fused", "reference", "auto"}
 

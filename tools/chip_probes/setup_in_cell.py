@@ -110,35 +110,32 @@ def cache_entries():
 
 
 from predictionio_tpu.parallel import mesh as mesh_mod  # noqa: E402
-from predictionio_tpu.serving import query_server, seqpath  # noqa: E402
+from predictionio_tpu.serving import query_server, rungs, seqpath  # noqa: E402
 
 doc["cache_before"] = cache_entries()
 
 Scorer = seqpath.PackedSequenceScorer
 timed(Scorer, "__init__", "scorer.__init__")
 timed(Scorer, "_compile", lambda self, t: f"compile.{t}")
-timed(seqpath, "measure_lag")
-timed(seqpath, "program_bytes")
+timed(rungs, "measure_lag")
+timed(rungs, "program_bytes")
 timed(query_server.QueryServer, "__init__", "QueryServer.__init__")
 timed(query_server.QueryServer, "start", "QueryServer.start")
 
 
-def warm_by_rung(self):
-    """`PackedSequenceScorer._warm`, each rung's run timed."""
-    import numpy as np
-
+def warm_by_rung(self, warm_args):
+    """`RungPrograms._warm`, each rung's run timed."""
     for t in self.ladder:
-        flat = self._family.flatten(self._family.pack(
-            [np.zeros(1, np.int32)], t, self.max_rows))
+        args = warm_args(t)
         t0 = time.perf_counter()
-        jax.block_until_ready(self._fns[t](self._params, flat))
+        jax.block_until_ready(self.fns[t](*args))
         doc["phases"].append({"name": f"warm.{t}", "at": t0 - T0,
                               "s": time.perf_counter() - t0})
         self.warmup_executions += 1
 
 
-Scorer._warm = warm_by_rung
-timed(Scorer, "_warm", "warm")
+rungs.RungPrograms._warm = warm_by_rung
+timed(rungs.RungPrograms, "_warm", "warm")
 
 PROFILE = {int(t) for t in
            os.environ.get("SETUP_IN_CELL_PROFILE", "").split(",") if t}
