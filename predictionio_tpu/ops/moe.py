@@ -1,4 +1,4 @@
-"""Sparse-expert feed-forward: sigmoid routing and grouped expert products.
+"""Sparse-expert feed-forward: routing and grouped expert products.
 
 Two device stages, each under a ``jax.named_scope``.  A TPU trace names a
 Pallas call after the innermost scope, so the three grouped products of a
@@ -9,7 +9,9 @@ products are in the HLO's metadata but cannot be found by name in a trace:
 * ``pio.moe_route`` — :func:`route_sigmoid_topk`: ``sigma = sigmoid(x @ W_g)``
   in f32, the ``top_k`` largest of ``sigma + bias`` are picked (the bias
   SELECTS), the weights are the unbiased ``sigma`` of the picked experts
-  (the bias never WEIGHS), normalised and scaled.
+  (the bias never WEIGHS), normalised and scaled.  Beside it, under the
+  same scope, :func:`route_topk_softmax`: the ``top_k`` largest LOGITS are
+  picked (no bias) and the weights are a softmax over the picked alone.
 * ``pio.moe_experts`` — :func:`expert_products`: the ``T * top_k``
   (token, expert) assignments are sorted by expert, each expert's rows go
   through its SwiGLU as one group of a grouped matmul, and the results are
@@ -75,6 +77,22 @@ def route_sigmoid_topk(
         if normalize:
             w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
         return picked.astype(jnp.int32), w * scale, sigma
+
+
+def route_topk_softmax(x: jax.Array, w_gate: jax.Array, *, top_k: int):
+    """Route ``x`` (T, D) over ``E`` experts (``w_gate`` (D, E), no bias),
+    all in f32 at HIGHEST: the ``top_k`` largest LOGITS are picked (ties to
+    the lower index) and the weights are a softmax OVER THE PICKED logits
+    alone, so they sum to one whatever the other experts score.  Returns
+    ``picked`` (T, top_k) int32, ``weights`` (T, top_k) f32 and the logits
+    (T, E)."""
+    with jax.named_scope(ROUTE_SCOPE):
+        logits = jnp.dot(
+            x.astype(jnp.float32), w_gate.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        top, picked = jax.lax.top_k(logits, top_k)
+        return picked.astype(jnp.int32), jax.nn.softmax(top, axis=1), logits
 
 
 # the most one expert's (K, N) weight tile may take of VMEM (it is held

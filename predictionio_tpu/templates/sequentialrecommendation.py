@@ -7,10 +7,10 @@ history is read live from the event store (same pattern as the e-commerce
 template's serving-time lookups) so recommendations track events newer than
 the model.
 
-Five algorithms share the template and its one history seam
+Six algorithms share the template and its one history seam
 (:class:`EventStoreHistory` unless the model or the algorithm carries
 another provider): ``sasrec``, the small trained transformer, served one
-query at a time from the host; and four packed sequence families at
+query at a time from the host; and five packed sequence families at
 published widths that serve through ``deploy --batching`` — the batcher's
 rows are packed into one dispatch of a resident, ahead-of-time compiled
 device program (:mod:`predictionio_tpu.serving.seqpath`, ONE scorer class
@@ -21,10 +21,14 @@ linear-attention layers interleaved with full-attention layers
 (:mod:`predictionio_tpu.models.gdn_hybrid`), ``windowmoe``
 (:class:`WindowMoEAlgorithm`), window and global grouped-query attention
 over sparse experts of which a model may hold a slice
-(:mod:`predictionio_tpu.models.window_moe`), and ``ssmparallel``
+(:mod:`predictionio_tpu.models.window_moe`), ``ssmparallel``
 (:class:`SSMParallelAlgorithm`), a state-space mixer and grouped-query
 attention side by side in every layer under muP multipliers
-(:mod:`predictionio_tpu.models.ssm_parallel`).  What a packed family needs of
+(:mod:`predictionio_tpu.models.ssm_parallel`), and ``ssmmoe``
+(:class:`SSMMoEAlgorithm`), state-space layers with an attention layer
+among them, every layer followed by softmax-routed small experts (of which
+a model may hold a slice) and a shared expert, head tied to the embedding
+(:mod:`predictionio_tpu.models.ssm_moe`).  What a packed family needs of
 an algorithm is :class:`PackedSequenceAlgorithm`'s; a family adds its
 model module's name.
 """
@@ -387,6 +391,13 @@ class SSMParallelAlgorithm(PackedSequenceAlgorithm):
     family = "predictionio_tpu.models.ssm_parallel"
 
 
+class SSMMoEAlgorithm(PackedSequenceAlgorithm):
+    """The state-space / attention recommender with routed experts behind
+    every layer (``ssmmoe``)."""
+
+    family = "predictionio_tpu.models.ssm_moe"
+
+
 class SequentialRecommendationEngine(EngineFactory):
     @classmethod
     def apply(cls) -> Engine:
@@ -399,6 +410,7 @@ class SequentialRecommendationEngine(EngineFactory):
                 "gdnhybrid": GDNHybridAlgorithm,
                 "windowmoe": WindowMoEAlgorithm,
                 "ssmparallel": SSMParallelAlgorithm,
+                "ssmmoe": SSMMoEAlgorithm,
             },
             serving_cls=FirstServing,
             query_cls=Query,

@@ -268,7 +268,9 @@ def test_the_cell_is_declared_as_the_issue_says(cfg, bench):
     assert tuple(names[at:at + 4]) == NEW
     assert names[at - 1] == "batch.ahead_share"
     for m in bench["per_layer"][at:at + 4]:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve.p50_ms"
+        # this cell first; a later state-space family that reports the
+        # metric too (PR 46) is appended behind it
+        assert m["workloads"][0] == CELL and m["moves"] == "serve.p50_ms"
         assert m["unit"] == "%" and m["better"] == "higher"
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "metrics", m["name"] + ".py"))
