@@ -27,6 +27,12 @@ stream, norms, softmax and the router (weights and logits) f32.  The
 compute dtype follows the weights': the tests also run the same program on
 f32 weights, where it must meet the reference to rounding.
 
+The layers are a Python loop over unrolled weights (``L<i>.<name>``), and
+a layer is two residual branches.  Branches that present an equal signature
+— every layer's attention, every sparse layer's feed-forward — are ONE
+function under ``jax.jit`` (:class:`SharedBranches`): a rung's program
+traces and lowers each body, kernels included, once, not once a layer.
+
 :func:`forward_packed` is the serving program: several histories packed
 into one token axis (``seg_start`` marks them), the last position of each
 row scored against the head by ``ops/topk.gather_score_topk`` on the
@@ -213,6 +219,61 @@ def init_params(cfg: LatentMoEConfig, seed: int, *, std: float = 0.02,
 # -- the blocks ---------------------------------------------------------------
 
 
+class SharedBranches:
+    """The residual branches that the layers of a family's programs share.
+
+    ``@shared(0, 1)`` on ``body(<static arguments>, W, *arrays)`` makes the
+    branch ONE function under ``jax.jit`` (the numbered arguments static):
+    the first layer of a program that hands it a signature (the static
+    arguments, the shapes of the layer's own weights ``W`` — handed in
+    without their ``L<i>.`` prefix, so that two layers' are equal — and of
+    the arrays) traces and lowers the body, kernels included; a later layer
+    with an equal one gets the cached jaxpr back and lowers to a call of the
+    one private function.  XLA inlines those calls before it fuses, so the
+    executable is that of the bodies composed layer by layer.
+    ``pallas_call`` keeps no trace cache of its own: composed in a Python
+    loop, every kernel of every layer is traced and lowered again, and
+    those are most of a program's set-up once its executable is cached.
+
+    ``traces`` counts the times Python ran a body (it does only while JAX
+    traces it), ``calls`` the times a layer called one, in this process."""
+
+    def __init__(self):
+        self.traces = self.calls = 0
+
+    def __call__(self, *static_argnums):
+        def share(body):
+            @functools.partial(jax.jit, static_argnums=static_argnums)
+            @functools.wraps(body)
+            def traced(*args):
+                self.traces += 1
+                return body(*args)
+
+            @functools.wraps(body)
+            def call(*args):
+                self.calls += 1
+                return traced(*args)
+            return call
+        return share
+
+    def stats(self) -> dict:
+        return {"branch_traces": self.traces, "branch_calls": self.calls}
+
+
+def layer_weights(P: dict, i: int, names) -> dict:
+    """Layer ``i``'s tensors of ``names``, under those names alone."""
+    return {n: P[f"L{i}.{n}"] for n in names}
+
+
+_shared = SharedBranches()
+# the tensors of a layer that each of its branches reads
+ATTENTION = ("attn_norm", "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm",
+             "kv_b", "o")
+DENSE_FFN = ("ffn_norm", "w1", "w3", "w2")
+SPARSE_FFN = ("ffn_norm", "gate", "gate_bias", "e_w1", "e_w3", "e_w2",
+              "s_w1", "s_w3", "s_w2")
+
+
 def rms_norm(x, scale, eps):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
@@ -242,39 +303,47 @@ def _swiglu(x, w1, w3, w2):
     return _mm(h, w2)
 
 
-def _attention(cfg, P, p, x, positions, seg_start, interpret):
+@_shared(0, 1)
+def _attention(cfg, interpret, W, x, positions, seg_start):
     t = x.shape[0]
     h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
-    bf = P[p + "o"].dtype  # the compute dtype is the weights' (bf16)
-    xn = rms_norm(x, P[p + "attn_norm"], cfg.rms_norm_eps)
-    c_q = rms_norm(_mm(xn, P[p + "q_a"]), P[p + "q_a_norm"], cfg.rms_norm_eps)
-    q = _mm(c_q, P[p + "q_b"]).reshape(t, h, dn + dr).transpose(1, 0, 2)
-    kv = _mm(xn, P[p + "kv_a"])
-    c_kv = rms_norm(kv[:, :cfg.kv_lora_rank], P[p + "kv_a_norm"],
+    bf = W["o"].dtype  # the compute dtype is the weights' (bf16)
+    xn = rms_norm(x, W["attn_norm"], cfg.rms_norm_eps)
+    c_q = rms_norm(_mm(xn, W["q_a"]), W["q_a_norm"], cfg.rms_norm_eps)
+    q = _mm(c_q, W["q_b"]).reshape(t, h, dn + dr).transpose(1, 0, 2)
+    kv = _mm(xn, W["kv_a"])
+    c_kv = rms_norm(kv[:, :cfg.kv_lora_rank], W["kv_a_norm"],
                     cfg.rms_norm_eps)
     k_r = rope_interleaved(kv[:, cfg.kv_lora_rank:], positions,
                            cfg.rope_theta)
-    kvb = _mm(c_kv, P[p + "kv_b"]).reshape(t, h, dn + dv).transpose(1, 0, 2)
+    kvb = _mm(c_kv, W["kv_b"]).reshape(t, h, dn + dv).transpose(1, 0, 2)
     q_r = rope_interleaved(q[..., dn:], positions, cfg.rope_theta)
     o = mla_attention(
         q[..., :dn].astype(bf), q_r.astype(bf), kvb[..., :dn].astype(bf),
         k_r.astype(bf), kvb[..., dn:].astype(bf), seg_start,
         scale=1.0 / math.sqrt(dn + dr), interpret=interpret)
-    return _mm(o.transpose(1, 0, 2).reshape(t, h * dv), P[p + "o"])
+    return _mm(o.transpose(1, 0, 2).reshape(t, h * dv), W["o"])
 
 
-def _sparse_ffn(cfg, P, p, x, valid, interpret):
-    xn = rms_norm(x, P[p + "ffn_norm"], cfg.rms_norm_eps)
+@_shared(0)
+def _dense_ffn(cfg, W, x):
+    xn = rms_norm(x, W["ffn_norm"], cfg.rms_norm_eps)
+    return _swiglu(xn, W["w1"], W["w3"], W["w2"])
+
+
+@_shared(0, 1)
+def _sparse_ffn(cfg, interpret, W, x, valid):
+    xn = rms_norm(x, W["ffn_norm"], cfg.rms_norm_eps)
     picked, weights, _ = _moe.route_sigmoid_topk(
-        xn, P[p + "gate"], P[p + "gate_bias"],
+        xn, W["gate"], W["gate_bias"],
         top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
         normalize=cfg.norm_topk_prob)
-    xb = xn.astype(P[p + "e_w1"].dtype)
+    xb = xn.astype(W["e_w1"].dtype)
     y, counts = _moe.expert_products(
-        xb, picked, weights, P[p + "e_w1"], P[p + "e_w3"], P[p + "e_w2"],
+        xb, picked, weights, W["e_w1"], W["e_w3"], W["e_w2"],
         valid, interpret=interpret)
-    shared = _swiglu(xb, P[p + "s_w1"], P[p + "s_w3"], P[p + "s_w2"])
+    shared = _swiglu(xb, W["s_w1"], W["s_w3"], W["s_w2"])
     return y + shared, picked, counts
 
 
@@ -287,13 +356,13 @@ def trunk(cfg: LatentMoEConfig, P: dict, tokens, positions, seg_start,
     x = P["embed"][tokens].astype(jnp.float32)
     picks, counts = [], []
     for i in range(cfg.num_hidden_layers):
-        p = f"L{i}."
-        x = x + _attention(cfg, P, p, x, positions, seg_start, interpret)
+        x = x + _attention(cfg, interpret, layer_weights(P, i, ATTENTION), x,
+                           positions, seg_start)
         if i < cfg.first_k_dense_replace:
-            xn = rms_norm(x, P[p + "ffn_norm"], cfg.rms_norm_eps)
-            x = x + _swiglu(xn, P[p + "w1"], P[p + "w3"], P[p + "w2"])
+            x = x + _dense_ffn(cfg, layer_weights(P, i, DENSE_FFN), x)
         else:
-            y, picked, c = _sparse_ffn(cfg, P, p, x, valid, interpret)
+            y, picked, c = _sparse_ffn(
+                cfg, interpret, layer_weights(P, i, SPARSE_FFN), x, valid)
             x = x + y
             picks.append(picked)
             counts.append(c)
@@ -431,6 +500,7 @@ class DispatchCounters:
             "expert_assignments": self.expert_assignments,
             "load_max_over_mean_sum": round(self.load_max_over_mean_sum, 4),
             "sparse_layer_dispatches": self.sparse_layer_dispatches,
+            **_shared.stats(),
         }
 
 

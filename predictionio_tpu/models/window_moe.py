@@ -40,6 +40,9 @@ weights as ``gdn_hybrid.trunk`` is: a scan's body slices its layer out of
 the stack, and for an expert layer that is a copy of its experts (1.8 GB
 at the published widths) a pass, where the unrolled program reads them in
 place; equal kinds are at most three layers in a row in this family.
+What the unrolled layers run with an equal signature they share all the
+same, as ONE traced and lowered function a rung
+(``latent_moe.SharedBranches``).
 
 Precision: weights and matmul operands bf16, accumulation f32; the residual
 stream, norms, RoPE, softmax, the gate's sigmoid and the router (weights
@@ -279,28 +282,44 @@ def runs_in_tiles(t_pad: int, tile: int = DENSE_TILE) -> bool:
 
 # A layer is position-wise parts around two cross-token operations, the
 # attention kernel and the routed experts' products.  Each part is written
-# once, below; `trunk` puts them together sublayer by sublayer on a short
-# rung (`_layer`: the order, and so the program, there was before tiles) and
-# as three segments over the token tiles that hold a real token on a long
+# once, below, on the tensors of ONE layer that it reads (``W``:
+# ``layer_weights``, the names without their ``L<i>.``); `trunk` puts them
+# together as two residual branches on a short rung (`_attention`,
+# `_feed_forward`: the order, and so the arithmetic, there was before tiles)
+# and as three segments over the token tiles that hold a real token on a long
 # one (`_layer_in_tiles`, which also keeps all but the gate's columns of the
 # projection from crossing the kernel).  That the two agree on every real
-# row is `tests/test_window_moe.py`'s to hold, at a tile of 16.
+# row is `tests/test_window_moe.py`'s to hold, at a tile of 16.  Either way
+# what the layers call is shared (`_shared`): the first layer with a
+# signature traces and lowers it, kernels included, the next ones call it.
+
+_shared = _lm.SharedBranches()
+layer_weights = _lm.layer_weights
+# the tensors of a layer that each part reads: the attention branch before
+# and behind its kernel, the feed-forward branch of a dense layer and, by
+# its three parts, of a sparse one
+ATTN_IN = ("in_norm", "qkvg", "q_norm", "k_norm")
+ATTN_OUT = ("o", "post_attn_norm")
+DENSE_FFN = ("pre_mlp_norm", "w1", "w3", "w2", "post_mlp_norm")
+ROUTE = ("pre_mlp_norm", "gate", "gate_bias")
+EXPERTS = ("e_w1", "e_w3", "e_w2")
+FFN_OUT = ("s_w1", "s_w3", "s_w2", "post_mlp_norm")
 
 
-def _qkvg(cfg, P, p, kind, x, positions):
+def _qkvg(cfg, W, kind, x, positions):
     """Before the kernel: q, k and v heads first, (heads, T, head_dim) in
     the compute dtype, and the projection itself (T, qkvg_width) f32, whose
     last columns the output gate reads."""
     t = x.shape[0]
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    cdt = P[p + "o"].dtype  # the compute dtype is the weights' (bf16)
-    a = rms_norm(x, P[p + "in_norm"], cfg.rms_norm_eps)
-    qkvg = _mm(a, P[p + "qkvg"])
+    cdt = W["qkvg"].dtype  # the compute dtype is the weights' (bf16)
+    a = rms_norm(x, W["in_norm"], cfg.rms_norm_eps)
+    qkvg = _mm(a, W["qkvg"])
     q_end, k_end, v_end = hq * hd, (hq + hkv) * hd, (hq + 2 * hkv) * hd
-    q = rms_norm(qkvg[:, :q_end].reshape(t, hq, hd), P[p + "q_norm"],
+    q = rms_norm(qkvg[:, :q_end].reshape(t, hq, hd), W["q_norm"],
                  cfg.rms_norm_eps)
-    k = rms_norm(qkvg[:, q_end:k_end].reshape(t, hkv, hd), P[p + "k_norm"],
+    k = rms_norm(qkvg[:, q_end:k_end].reshape(t, hkv, hd), W["k_norm"],
                  cfg.rms_norm_eps)
     v = qkvg[:, k_end:v_end].reshape(t, hkv, hd)
     if kind == WINDOW:
@@ -310,117 +329,147 @@ def _qkvg(cfg, P, p, kind, x, positions):
     return heads_first(q), heads_first(k), heads_first(v), qkvg
 
 
-def _attend(cfg, kind, q, k, v, seg_start, interpret):
+def _attend(cfg, kind, interpret, q, k, v, seg_start):
     return _fa.packed_grouped_attention(
         q, k, v, seg_start,
         window=cfg.sliding_window if kind == WINDOW else None,
         scale=1.0 / math.sqrt(cfg.head_dim), interpret=interpret)
 
 
-def _attention_out(cfg, P, p, o, qkvg):
+def _attention_out(cfg, W, o, qkvg):
     """Behind the kernel: ``o`` (heads, T, head_dim) under the sigmoid of
     the gate's pre-activation — the last ``heads * head_dim`` columns of
     ``qkvg``, which may be those columns alone — through ``W_o`` and the
     output norm."""
     width = o.shape[0] * o.shape[2]
     o = o.transpose(1, 0, 2).reshape(o.shape[1], width).astype(jnp.float32)
-    y = _mm(o * jax.nn.sigmoid(qkvg[:, qkvg.shape[1] - width:]), P[p + "o"])
-    return rms_norm(y, P[p + "post_attn_norm"], cfg.rms_norm_eps)
+    y = _mm(o * jax.nn.sigmoid(qkvg[:, qkvg.shape[1] - width:]), W["o"])
+    return rms_norm(y, W["post_attn_norm"], cfg.rms_norm_eps)
 
 
-def _attention(cfg, P, p, kind, x, positions, seg_start, interpret):
-    q, k, v, qkvg = _qkvg(cfg, P, p, kind, x, positions)
-    return _attention_out(
-        cfg, P, p, _attend(cfg, kind, q, k, v, seg_start, interpret), qkvg)
-
-
-def _route(cfg, P, p, m):
+def _route(cfg, W, m):
     """The router's picks and weights for the normed ``m``, and ``m`` in
     the experts' dtype."""
     picked, weights, _ = _moe.route_sigmoid_topk(
-        m, P[p + "gate"], P[p + "gate_bias"],
+        m, W["gate"], W["gate_bias"],
         top_k=cfg.num_experts_per_tok, scale=cfg.route_scale,
         normalize=cfg.route_norm)
-    return picked, weights, m.astype(P[p + "e_w1"].dtype)
+    return picked, weights, m.astype(W["e_w1"].dtype)
 
 
-def _held_products(cfg, mb, picked, weights, w1, w3, w2, valid, interpret):
+def _held_products(cfg, interpret, W, mb, picked, weights, valid):
     """The held experts' part for their tokens.  Never in tiles: the held
     experts' weights cross HBM once a dispatch, not once a tile, and it
     sorts padded tokens past the last expert itself."""
     return _moe.expert_products(
-        mb, picked, weights, w1, w3, w2, valid,
+        mb, picked, weights, W["e_w1"], W["e_w3"], W["e_w2"], valid,
         first=cfg.first_expert_held, n_experts=cfg.num_experts,
         interpret=interpret)
 
 
-def _shared_and_held(cfg, P, p, y, mb, picked):
+def _shared_and_held(cfg, W, y, mb, picked):
     """The shared expert added, and per token whether any pick is held."""
     if cfg.num_shared_experts:
-        y = y + _swiglu(mb, P[p + "s_w1"], P[p + "s_w3"], P[p + "s_w2"])
+        y = y + _swiglu(mb, W["s_w1"], W["s_w3"], W["s_w2"])
     local = picked - cfg.first_expert_held
     return y, ((local >= 0) & (local < cfg.n_held)).any(axis=1)
 
 
-def _sparse_ffn(cfg, P, p, m, valid, interpret):
-    picked, weights, mb = _route(cfg, P, p, m)
-    y, counts = _held_products(
-        cfg, mb, picked, weights, P[p + "e_w1"], P[p + "e_w3"],
-        P[p + "e_w2"], valid, interpret)
-    y, held = _shared_and_held(cfg, P, p, y, mb, picked)
+def _sparse_ffn(cfg, interpret, W, m, valid):
+    picked, weights, mb = _route(cfg, W, m)
+    y, counts = _held_products(cfg, interpret, W, mb, picked, weights, valid)
+    y, held = _shared_and_held(cfg, W, y, mb, picked)
     return y, picked, counts, jnp.sum(valid & ~held, dtype=jnp.int32)
 
 
-def _layer(cfg, P, p, i, kind, x, positions, seg_start, valid, interpret):
-    """One layer on the stream, sublayer by sublayer: the stream, and for a
-    sparse layer its picks, the held experts' counts and the valid tokens
-    without a held pick (else None)."""
-    eps = cfg.rms_norm_eps
-    x = x + _attention(cfg, P, p, kind, x, positions, seg_start, interpret)
-    m = rms_norm(x, P[p + "pre_mlp_norm"], eps)
-    if i < cfg.num_dense_layers:
-        f, routed = _swiglu(m, P[p + "w1"], P[p + "w3"], P[p + "w2"]), None
+# -- a short rung: a layer is its two residual branches -----------------------
+
+
+@_shared(0, 1, 2)
+def _attention(cfg, kind, interpret, W, x, positions, seg_start):
+    q, k, v, qkvg = _qkvg(cfg, W, kind, x, positions)
+    return _attention_out(
+        cfg, W, _attend(cfg, kind, interpret, q, k, v, seg_start), qkvg)
+
+
+@_shared(0, 1, 2)
+def _feed_forward(cfg, dense, interpret, W, x, valid):
+    """The branch's output and, of a sparse layer's routing, its picks, the
+    held experts' counts and the valid tokens without a held pick."""
+    m = rms_norm(x, W["pre_mlp_norm"], cfg.rms_norm_eps)
+    if dense:
+        f, routed = _swiglu(m, W["w1"], W["w3"], W["w2"]), ()
     else:
-        f, *routed = _sparse_ffn(cfg, P, p, m, valid, interpret)
-    return x + rms_norm(f, P[p + "post_mlp_norm"], eps), routed
+        f, *routed = _sparse_ffn(cfg, interpret, W, m, valid)
+    return rms_norm(f, W["post_mlp_norm"], cfg.rms_norm_eps), *routed
 
 
-def _layer_in_tiles(cfg, P, p, i, kind, x, positions, valid, tiles, attend,
-                    products):
-    """:func:`_layer`, its position-wise parts run by ``tiles``
-    (``token_tiles.real_tiles``) as one segment before the attention kernel,
-    one between it and the routed experts' products (a dense layer's ends
-    the layer) and one behind those: no sublayer's (T, intermediate) array
-    is written whole, and of the projection only the gate's columns cross
-    the kernel.  ``attend`` / ``products``: :func:`_attend` of the layer's
-    kind and :func:`_held_products` with the dispatch's own bound."""
-    eps = cfg.rms_norm_eps
-    hq, hd = cfg.num_attention_heads, cfg.head_dim
+# -- a long rung: a layer's position-wise parts run in token tiles, as one
+# segment before the attention kernel, one between it and the routed experts'
+# products (a dense layer's ends the layer) and one behind those: no
+# sublayer's (T, intermediate) array is written whole, and of the projection
+# only the gate's columns cross the kernel --------------------------------------
 
+
+@_shared(0, 1, 2)
+def _before(cfg, kind, tile, W, x, positions, n_real):
     def before(x, positions):
-        q, k, v, qkvg = _qkvg(cfg, P, p, kind, x, positions)
-        return q, k, v, qkvg[:, -hq * hd:]
+        q, k, v, qkvg = _qkvg(cfg, W, kind, x, positions)
+        return q, k, v, qkvg[:, -cfg.num_attention_heads * cfg.head_dim:]
+
+    return _tiles.over_real_tiles(before, n_real, x, positions, tile=tile,
+                                  out_axes=(1, 1, 1, 0))
+
+
+@_shared(0, 1, 2)
+def _between(cfg, dense, tile, W, o, gate, x, n_real):
+    eps = cfg.rms_norm_eps
 
     def between(o, gate, x):
-        x = x + _attention_out(cfg, P, p, o, gate)
-        m = rms_norm(x, P[p + "pre_mlp_norm"], eps)
-        if i < cfg.num_dense_layers:
-            f = _swiglu(m, P[p + "w1"], P[p + "w3"], P[p + "w2"])
-            return x + rms_norm(f, P[p + "post_mlp_norm"], eps)
-        return (x, *_route(cfg, P, p, m))
+        x = x + _attention_out(cfg, W, o, gate)
+        m = rms_norm(x, W["pre_mlp_norm"], eps)
+        if dense:
+            f = _swiglu(m, W["w1"], W["w3"], W["w2"])
+            return x + rms_norm(f, W["post_mlp_norm"], eps)
+        return (x, *_route(cfg, W, m))
 
+    return _tiles.over_real_tiles(between, n_real, o, gate, x, tile=tile,
+                                  in_axes=(1, 0, 0))
+
+
+@_shared(0, 1)
+def _behind(cfg, tile, W, y, mb, picked, x, n_real):
     def behind(y, mb, picked, x):
-        f, held = _shared_and_held(cfg, P, p, y, mb, picked)
-        return x + rms_norm(f, P[p + "post_mlp_norm"], eps), held
+        f, held = _shared_and_held(cfg, W, y, mb, picked)
+        return x + rms_norm(f, W["post_mlp_norm"], cfg.rms_norm_eps), held
 
-    q, k, v, gate = tiles(before, x, positions, out_axes=(1, 1, 1, 0))
-    o = attend(q, k, v)
-    if i < cfg.num_dense_layers:
-        return tiles(between, o, gate, x, in_axes=(1, 0, 0)), None
-    x, picked, weights, mb = tiles(between, o, gate, x, in_axes=(1, 0, 0))
-    y, counts = products(mb, picked, weights, P[p + "e_w1"], P[p + "e_w3"],
-                         P[p + "e_w2"])
-    x, held = tiles(behind, y, mb, picked, x)
+    return _tiles.over_real_tiles(behind, n_real, y, mb, picked, x, tile=tile)
+
+
+# the two cross-token operations between the segments: a short rung's
+# branches hold them inside
+_attend_in_tiles = _shared(0, 1, 2)(_attend)
+_products_in_tiles = _shared(0, 1)(_held_products)
+
+
+def _layer_in_tiles(cfg, kind, dense, interpret, tile, P, i, x, positions,
+                    seg_start, valid, n_real):
+    """Layer ``i`` on the stream in tiles of ``tile`` tokens, the first
+    ``n_real`` real: the stream and, of a sparse layer's routing, what
+    :func:`_feed_forward` returns (else nothing)."""
+    W = functools.partial(layer_weights, P, i)
+    q, k, v, gate = _before(cfg, kind, tile, W(ATTN_IN), x, positions, n_real)
+    o = _attend_in_tiles(cfg, kind, interpret, q, k, v, seg_start)
+    if dense:
+        return _between(cfg, dense, tile, W(ATTN_OUT + DENSE_FFN), o, gate,
+                        x, n_real), ()
+    # `_route` reads the experts' dtype off their first tensor
+    x, picked, weights, mb = _between(
+        cfg, dense, tile, W(ATTN_OUT + ROUTE + EXPERTS[:1]), o, gate, x,
+        n_real)
+    y, counts = _products_in_tiles(cfg, interpret, W(EXPERTS), mb, picked,
+                                   weights, valid)
+    x, held = _behind(cfg, tile, W(FFN_OUT), y, mb, picked, x, n_real)
     return x, (picked, counts, jnp.sum(valid & ~held, dtype=jnp.int32))
 
 
@@ -442,27 +491,26 @@ def trunk(cfg: WindowMoEConfig, P: dict, tokens, positions, seg_start,
     in_tiles = runs_in_tiles(tokens.shape[0], dense_tile)
     if in_tiles:
         # `pack` lays rows end to end from token 0: the real tokens lead
-        tiles = _tiles.real_tiles(jnp.sum(valid, dtype=jnp.int32), dense_tile)
-        # jitted, so that the layers of a kind call ONE traced and lowered
-        # kernel function (traced and lowered a layer each, the kernels are
-        # most of a program's set-up time)
-        attend = {kind: jax.jit(functools.partial(
-            _attend, cfg, kind, seg_start=seg_start, interpret=interpret))
-            for kind in set(cfg.layer_types)}
-        products = jax.jit(functools.partial(
-            _held_products, cfg, valid=valid, interpret=interpret))
+        n_real = jnp.sum(valid, dtype=jnp.int32)
     x = P["embed"][tokens].astype(jnp.float32)
     if cfg.mup_enabled:
         x = x * math.sqrt(cfg.hidden_size)
     picks, counts, unheld = [], [], []
     for i, kind in enumerate(cfg.layer_types):
+        dense = i < cfg.num_dense_layers
         if in_tiles:
-            x, routed = _layer_in_tiles(cfg, P, f"L{i}.", i, kind, x,
-                                        positions, valid, tiles,
-                                        attend[kind], products)
+            x, routed = _layer_in_tiles(
+                cfg, kind, dense, interpret, dense_tile, P, i, x, positions,
+                seg_start, valid, n_real)
         else:
-            x, routed = _layer(cfg, P, f"L{i}.", i, kind, x, positions,
-                               seg_start, valid, interpret)
+            x = x + _attention(
+                cfg, kind, interpret, layer_weights(P, i, ATTN_IN + ATTN_OUT),
+                x, positions, seg_start)
+            f, *routed = _feed_forward(
+                cfg, dense, interpret, layer_weights(
+                    P, i, DENSE_FFN if dense else ROUTE + EXPERTS + FFN_OUT),
+                x, valid)
+            x = x + f
         if routed:
             picks.append(routed[0])
             counts.append(routed[1])
@@ -618,6 +666,7 @@ class DispatchCounters:
             "dense_tile": DENSE_TILE,
             "dense_tiles": self.dense_tiles,
             "dense_tiles_rung": self.dense_tiles_rung,
+            **_shared.stats(),
         }
 
 

@@ -59,9 +59,13 @@ class RungPrograms:
         # where it is requested: equals stats()["calls"]
         self.readbacks_queued = 0
         self.fns = {}
+        t0 = time.perf_counter()
         for r in self.ladder:
             self.fns[r] = compile(r)
             self.compile_count += 1
+        # the wall of the ladder's compiles: trace, lowering and the
+        # backend's compile or the persistent cache's read, rung after rung
+        self.compile_s = time.perf_counter() - t0
         # run() is entered by two threads at once (the batcher's
         # launch-ahead)
         self.gate = LaunchGate(
@@ -133,6 +137,7 @@ class RungPrograms:
         with self._lock:
             return {
                 "compile_count": self.compile_count,
+                "compile_s": round(self.compile_s, 4),
                 "warmup_executions": self.warmup_executions,
                 "bucket_hits": {str(r): n for r, n in self.hits.items()},
                 "calls": sum(self.hits.values()),

@@ -3,14 +3,23 @@ program is the parent's, jaxpr for jaxpr" without keeping the parent's
 modules here line for line: ``sha256(str(make_jaxpr(forward_flat)))`` at the
 family's test configuration (``test_<family>.CFG``) on shapes alone.
 
-``PARENT`` holds the readings of commit c907cf6 (PR 41), the parent of the
-PR that made the window family's dense sublayers run in token tiles from
-2,048 tokens on (PR 42): that family's lower rungs, and the family whose
-helpers (``_mm``, ``_swiglu``, ``rms_norm``) it shares and must not move.  To read a
-tree's own: ``JAX_PLATFORMS=cpu python tests/fingerprints.py`` from its
-root.  A deliberate change to a family's low-rung program re-reads its rows
-and says so in its PR; the text holds no address, so the reading repeats
-from process to process."""
+``PARENT`` holds the readings of PR 48's tree, RE-READ for its deliberate
+change: both families' layers now call their equal residual branches as ONE
+jitted function a rung (``latent_moe.SharedBranches``), so each text holds
+the branches' bodies once (``let _attention = {...}``) and a ``pjit`` a
+call where it held every layer's equations in line.  The equations
+themselves are those of commit c907cf6 (PR 41), whose readings stood here
+until then — ab6c7c88821039e3, bba1fcaacd37cdea, 58596a14e43c0616 for
+``window_moe`` and 965798f795dfda16, 272381f2eb07c00a, c321c74534306657,
+8d422c2fcb1ed55a for ``latent_moe`` — and what pins THAT is the bit-for-bit
+comparison with the unshared composition in each family's test file
+(``shared_branch_cases.check_bits``).  The rows: the window family's rungs
+below 2,048 tokens (from there on it runs in token tiles, PR 42), and the
+family whose helpers (``_mm``, ``_swiglu``, ``rms_norm``) it shares and
+must not move.  To read a tree's own: ``JAX_PLATFORMS=cpu python
+tests/fingerprints.py`` from its root.  A deliberate change to a family's
+low-rung program re-reads its rows and says so in its PR; the text holds no
+address, so the reading repeats from process to process."""
 
 import hashlib
 import importlib
@@ -22,11 +31,11 @@ K = 10
 RUNGS = {"window_moe": (256, 512, 1024),  # runs in tiles from 2,048 on
          "latent_moe": (256, 512, 1024, 2048)}
 PARENT = {
-    "window_moe.256": "ab6c7c88821039e3", "window_moe.512": "bba1fcaacd37cdea",
-    "window_moe.1024": "58596a14e43c0616",
-    "latent_moe.256": "965798f795dfda16", "latent_moe.512": "272381f2eb07c00a",
-    "latent_moe.1024": "c321c74534306657",
-    "latent_moe.2048": "8d422c2fcb1ed55a",
+    "window_moe.256": "0c2a0c7e66be638a", "window_moe.512": "514cf5939110efb3",
+    "window_moe.1024": "6c4b55947fd511b7",
+    "latent_moe.256": "9739bd424798035d", "latent_moe.512": "cdf023500eef0cfe",
+    "latent_moe.1024": "77ed83275456bebb",
+    "latent_moe.2048": "baf09b986e2380c9",
 }
 
 

@@ -162,3 +162,32 @@ def test_a_held_dispatch_sheds_nothing_in_the_cell(cfg, capsys, monkeypatch):
     assert res["failed"] == 0 and res["correct"] is True
     assert res["attempted"] == 420
     assert int(out.split("peak in flight ")[1].split()[0]) > 256
+
+
+# what the scorers' set-up leaves in the counters, read as the window opens
+SETUP = {"fastpath.compile_s": 9.5, "fastpath.branch_traces": 18,
+         "fastpath.branch_calls": 60, "fastpath.compile_count": 6}
+
+
+@pytest.mark.parametrize("name, before, want", [
+    ("setup.compile_s", SETUP, 9.5),
+    ("setup.branch_trace_share", SETUP, 30.0),
+    # the parent of ISSUE 48 has neither the timer nor the counters, a
+    # family whose depth is a scan no counters: the line leaves them out
+    ("setup.compile_s", {"fastpath.compile_count": 6}, None),
+    ("setup.branch_trace_share", {"fastpath.compile_s": 9.5}, None),
+], ids=["compile_s", "branch_trace_share", "no-timer", "no-counters"])
+def test_the_set_ups_metrics_read_the_counters_before_the_window(
+        name, before, want):
+    from pio_bench.readers import load_reader
+
+    # whatever the window added is not the set-up's
+    after = {k: v + 1 for k, v in before.items()}
+    got = load_reader(name)({"counters_before": before,
+                             "counters_after": after})
+    assert got == (want if want is None else pytest.approx(want))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry["moves"] == "setup_s"
+    assert "joyai-flash-l5.serve-steady" in entry["workloads"]

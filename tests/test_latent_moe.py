@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 import fingerprints
+import shared_branch_cases as branches
 
 from predictionio_tpu.models import latent_moe as lm
 from predictionio_tpu.models.latent_moe_reference import (
@@ -185,7 +186,9 @@ def test_the_shared_expert_is_counted_once(weights):
     P[p + "e_w2"] = jnp.zeros_like(P[p + "e_w2"])
     x = jnp.asarray(np.random.default_rng(7).standard_normal((16, 64)),
                     jnp.float32)
-    y, _, _ = lm._sparse_ffn(CFG, P, p, x, None, None)
+    y, _, _ = lm._sparse_ffn(
+        CFG, None, lm.layer_weights(P, CFG.first_k_dense_replace,
+                                    lm.SPARSE_FFN), x, None)
     xn = lm.rms_norm(x, P[p + "ffn_norm"], CFG.rms_norm_eps)
     want = lm._swiglu(xn, P[p + "s_w1"], P[p + "s_w3"], P[p + "s_w2"])
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
@@ -454,10 +457,28 @@ def test_every_rungs_program_is_the_parents_jaxpr_for_jaxpr(t):
     """The window family runs its dense sublayers in token tiles from 2,048
     tokens on (``ops/token_tiles``) and imports this module's ``_mm``,
     ``_swiglu`` and ``rms_norm``; this one is left as it was — a dispatch
-    here is the experts' weights from HBM — at EVERY rung: PR 41's text, by
-    its fingerprint."""
+    here is the experts' weights from HBM — at EVERY rung, by its
+    fingerprint: the text as PR 48 left it (the layers' equal branches one
+    ``pjit`` each; the equations are PR 41's, held to the bit below)."""
     assert fingerprints.fingerprint("latent_moe", CFG, t) == \
         fingerprints.PARENT[f"latent_moe.{t}"]
+
+
+# -- equal residual branches are one traced and lowered function (PR 48) ---------
+
+
+@pytest.mark.parametrize("what, t, kw", [
+    ("counts", 64, dict(distinct=3, calls=2 * CFG.num_hidden_layers)),
+    ("bits", 64, {}), ("bits", 128, {}),
+    ("lowered", 256, dict(kernels=4, unshared_kernels=9)),
+])
+def test_the_layers_share_their_equal_branches(what, t, kw, weights,
+                                               monkeypatch):
+    """One dense layer of three: attention is ONE branch for all three, the
+    dense and the sparse feed-forward one each — 3 bodies traced for 2 x 3
+    calls, and of the kernels one attention and the sparse branch's three
+    grouped products where the layers' own come to 3 + 2 x 3."""
+    branches.check(what, monkeypatch, lm, CFG, weights["bf16"], t, **kw)
 
 
 def test_the_scorer_reports_no_tiles_for_a_family_that_runs_none(weights):

@@ -133,11 +133,12 @@ def test_the_record_is_told_before_the_launch(more):
 
 # -- through the real scorers ---------------------------------------------------
 
-# `stats()` keys at the parent (511bf64) less the hot-set's block; nested under
+# `stats()` keys at 511bf64 less the hot-set's block, with PR 48's `compile_s` (both
+# scorers) and `branch_traces` / `branch_calls` (`latent_moe`'s own); nested under
 # `kernel` where the bucketed scorer nests them
 BUCKETED_KEYS = {
     "buckets", "top_k", "serving_backend", "sharding", "pod",
-    "retrieval_backend", "retrieval", "kernel", "compile_count",
+    "retrieval_backend", "retrieval", "kernel", "compile_count", "compile_s",
     "bucket_hits", "calls", "readbacks_queued", "held_launches",
     "launch_lag_ms", "queries", "padded_rows", "merge_passes",
     "merge_blocks", "row_occupancy", "devprof",
@@ -147,12 +148,14 @@ BUCKETED_KEYS = {
 }
 PACKED_KEYS = {
     "family", "token_ladder", "max_rows", "top_k", "backend", "block_items",
-    "resident_bytes", "compile_count", "warmup_executions", "bucket_hits",
-    "calls", "readbacks_queued", "held_launches", "launch_lag_ms", "queries",
-    "tokens", "padded_tokens", "causal_pairs", "merge_passes",
+    "resident_bytes", "compile_count", "compile_s", "warmup_executions",
+    "bucket_hits", "calls", "readbacks_queued", "held_launches",
+    "launch_lag_ms", "queries", "tokens", "padded_tokens", "causal_pairs",
+    "merge_passes",
     # latent_moe's own
     "experts", "sparse_layers", "sparse_layer_dispatches", "experts_touched",
-    "expert_assignments", "load_max_over_mean_sum",
+    "expert_assignments", "load_max_over_mean_sum", "branch_traces",
+    "branch_calls",
 }
 
 
@@ -201,6 +204,8 @@ def test_a_dispatch_through_either_scorer_is_the_one_protocol(
     s = sc.stats()
     assert _keys(s) == golden
     assert s["compile_count"] == sc.compile_count == len(ladder)
+    # the ladder's compiles timed as one wall, no program more for it
+    assert 0.0 < s["compile_s"] == round(sc._rungs.compile_s, 4)
     assert s.get("warmup_executions",
                  s.get("kernel", {}).get("warmup_executions")) == len(ladder)
     assert s["calls"] == s["readbacks_queued"] == 0
@@ -224,6 +229,7 @@ def test_a_dispatch_through_either_scorer_is_the_one_protocol(
     assert s["calls"] == s["readbacks_queued"] == sum(
         s["bucket_hits"].values()) >= 2
     assert s["compile_count"] == len(ladder)  # no request compiles
+    assert s["compile_s"] == round(sc._rungs.compile_s, 4)  # nor is timed
     # every dispatch: h2d, device_compute, d2h one after the other, the
     # launch inside device_compute
     stages = [n for n in seen if n != "pio.batch_assembly"]

@@ -32,6 +32,7 @@ import numpy as np
 import pytest
 
 import fingerprints
+import shared_branch_cases as branches
 import token_tile_cases as cases
 
 from predictionio_tpu.models import latent_moe as lm
@@ -458,7 +459,9 @@ def test_a_token_with_no_held_pick_gets_the_shared_expert_alone(weights):
     r = np.random.default_rng(8)
     m = jnp.asarray(r.normal(size=(32, 64)), jnp.float32)
     valid = jnp.arange(32) < 30
-    f, picked, counts, unheld = wm._sparse_ffn(CFG, P, "L2.", m, valid, None)
+    f, picked, counts, unheld = wm._sparse_ffn(
+        CFG, None, wm.layer_weights(P, 2, wm.ROUTE + wm.EXPERTS + wm.FFN_OUT),
+        m, valid)
     assert int(picked.max()) > 7 and int(picked.min()) < 4  # over all 16
     none = ~((np.asarray(picked) >= 4) & (np.asarray(picked) < 8)).any(1)
     assert none[:30].sum() > 0 and int(unheld) == int(none[:30].sum())
@@ -706,14 +709,41 @@ def test_the_trunk_runs_the_tiles_that_hold_a_real_token_and_no_other(
 @pytest.mark.parametrize("t", fingerprints.RUNGS["window_moe"])
 def test_a_rung_of_two_tiles_or_fewer_is_the_parents_program_jaxpr_for_jaxpr(
         t):
-    """The 256-, 512- and 1,024-token programs at the program's own tile:
-    the parent's (PR 41's) text, by its fingerprint; from 2,048 tokens the
-    loops are there."""
+    """The 256-, 512- and 1,024-token programs at the program's own tile,
+    by their fingerprints: the text as PR 48 left it (the layers' equal
+    branches one ``pjit`` each; the equations are PR 41's, held to the bit
+    below); from 2,048 tokens the loops are there."""
     assert fingerprints.fingerprint("window_moe", CFG, t) == fingerprints.PARENT[
         f"window_moe.{t}"]
     low, high = (fingerprints.program_jaxpr("window_moe", CFG, n).count(
         "dynamic_update_slice") for n in (t, 2048))
     assert high > low
+
+
+# -- equal residual branches are one traced and lowered function (PR 48) ---------
+
+
+TILED = dict(dense_tile=cases.TILE)
+
+
+@pytest.mark.parametrize("what, t, kw", [
+    ("counts", cases.T, dict(distinct=4, calls=2 * CFG.num_hidden_layers)),
+    ("counts", cases.T, dict(distinct=8, calls=23, **TILED)),
+    ("bits", 64, {}), ("bits", cases.T, {}), ("bits", cases.T, TILED),
+    ("lowered", 256, dict(kernels=5, unshared_kernels=17)),
+    ("lowered", 2048, dict(kernels=5, unshared_kernels=17)),  # in tiles
+])
+def test_the_layers_share_what_they_run_with_an_equal_signature(
+        what, t, kw, weights, monkeypatch):
+    """``[W, W, G, W, W]`` with one dense layer.  A rung run whole: window
+    attention, global attention, the dense and the sparse feed-forward — 4
+    bodies for 2 x 5 calls; of the kernels two attentions and the sparse
+    branch's three grouped products, where the layers' own come to 5 + 4 x
+    3.  A rung in tiles shares part by part: the segment before the kernel
+    and the kernel by kind, the segment between by dense or sparse, the
+    products and the segment behind them — 8 bodies for 3 x 5 + 2 x 4
+    calls, and the same five kernels."""
+    branches.check(what, monkeypatch, wm, CFG, weights["bf16"], t, **kw)
 
 
 @pytest.mark.parametrize("t_pad, n_tok, ran, rung", [
