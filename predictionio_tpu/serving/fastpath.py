@@ -267,10 +267,14 @@ class BucketedScorer:
         self._last_return = 0.0
         # AOT: every rung compiled and run once before the first request
         self._rungs = RungPrograms(
-            ctx.mesh.devices.flat[0], self.buckets, self._compile,
+            ctx.mesh.devices.flat[0], self.buckets, self._lower,
             warm_args=lambda b: self._call_args(np.zeros(b, np.int32)),
-            fetch=self._fetch)
+            fetch=self._fetch,
+            # the sharded path never touches the program store
+            describe=None if self.sharding == "sharded" else self._describe)
         self._fns = self._rungs.fns
+        for b, compiled in self._fns.items():
+            self._annotate_cost(b, compiled)
 
     compile_count = property(lambda self: self._rungs.compile_count)
 
@@ -385,7 +389,7 @@ class BucketedScorer:
         # what the exact path would have scanned per row — the
         # scanned-fraction denominator
         self._exact_items_pad = int(pad_to(self.n_items))
-        self._local_k = min(self.k, layout.cap_pad)
+        self._local_k = min(self.k, layout.cap_pad)  # pio: ignore[race-unguarded-rebind]
         # deploy-time probe budget: PIO_IVF_NPROBE overrides the
         # publish-time default, clamped to [1, nlist]
         env_nprobe = os.environ.get("PIO_IVF_NPROBE", "")
@@ -467,7 +471,7 @@ class BucketedScorer:
         # per-shard leaderboard width: a shard with fewer than k real
         # items simply contributes its whole block; S·local_k ≥ k always
         # holds because S·cap_pad ≥ n_items ≥ self.k
-        self._local_k = min(self.k, layout.cap_pad)
+        self._local_k = min(self.k, layout.cap_pad)  # pio: ignore[race-unguarded-rebind]
         if self._pod:
             # 2-D (host, data) mesh: shard s lands on host row s // G —
             # the plan's contiguous group blocks, by construction of the
@@ -673,12 +677,30 @@ class BucketedScorer:
             else:
                 self._static_args = (self._U, self._V, self._item_pad_mask)
 
-    def _compile(self, b: int):
-        """Lower + compile the bucket-b program ahead of time."""
-        if self.sharding == "sharded":
-            return self._compile_sharded(b)
+    def _lower_args(self, b: int) -> tuple:
+        return (*self._static_args, self._put_repl(np.zeros(b, np.int32)))
+
+    def _describe(self, b: int) -> tuple:
+        """What a replicated rung's program closes over, for the program
+        store's key, and the arguments it is lowered on (replicated over a
+        mesh of several devices the store leaves the rung alone too:
+        ``program_store.lowered_on``)."""
+        statics = {
+            "scorer": "BucketedScorer", "variant": self.retrieval, "rung": b,
+            "k": self.k, "backend": self.backend,
+            "factor_dtype": self.factor_dtype,
+        }
         if self.retrieval == "ivf":
-            return self._compile_ivf(b)
+            statics.update(local_k=self._local_k, probes=self._probes[b],
+                           cap_pad=self._ivf_layout.cap_pad)
+        return statics, self._lower_args(b)
+
+    def _lower(self, b: int):
+        """The bucket-b program traced and lowered, ahead of time."""
+        if self.sharding == "sharded":
+            return self._lower_sharded(b)
+        if self.retrieval == "ivf":
+            return self._lower_ivf(b)
         k = self.k
         be = self.backend
         # the fused kernel also returns its merge counters: a third output
@@ -702,17 +724,10 @@ class BucketedScorer:
                     with_stats=ws,
                 )
 
-        dummy_idx = self._put_repl(np.zeros(b, np.int32))
-        compiled = (
-            jax.jit(fn)
-            .lower(*self._static_args, dummy_idx)
-            .compile()
-        )
-        self._annotate_cost(b, compiled)
-        return compiled
+        return jax.jit(fn).lower(*self._lower_args(b))
 
-    def _compile_ivf(self, b: int):
-        """AOT-compile the bucket-b IVF probe → scan → merge program.
+    def _lower_ivf(self, b: int):
+        """The bucket-b IVF probe → scan → merge program, lowered.
 
         One program per rung, same ladder/warmup contract as the other
         placements.  The batch's dequantized query rows score against the
@@ -783,35 +798,10 @@ class BucketedScorer:
                 cand_g = jnp.swapaxes(pg, 0, 1).reshape(b, P_b * lk)
                 return merge_topk(cand_v, cand_g, k)
 
-        dummy_idx = self._put_repl(np.zeros(b, np.int32))
-        compiled = (
-            jax.jit(fn)
-            .lower(*self._static_args, dummy_idx)
-            .compile()
-        )
-        # always the analytic model: the probe scan's Pallas calls are
-        # opaque to XLA cost analysis, and the analytic scanned-rows
-        # number (P_b·cap_pad, not the full catalog) IS the story
-        rank = self._U.shape[1]
-        scanned = P_b * cap
-        if be == "fused":
-            a_flops, a_bytes = _devprof.fused_score_cost(
-                b, scanned, rank, lk, self.factor_dtype
-            )
-            self.devprof.set_cost(
-                b, a_flops, a_bytes, source="analytic-fused"
-            )
-        else:
-            a_flops, a_bytes = _devprof.score_cost(
-                b, scanned, rank, dtype=self.factor_dtype
-            )
-            self.devprof.set_cost(b, a_flops, a_bytes, source="analytic")
-        # construction-time: RungPrograms calls _compile from __init__ only
-        self._cost_bytes[b] = a_bytes  # pio: ignore[race-unguarded-rmw]
-        return compiled
+        return jax.jit(fn).lower(*self._lower_args(b))
 
-    def _compile_sharded(self, b: int):
-        """AOT-compile the bucket-b fan-out → local top-k → merge program.
+    def _lower_sharded(self, b: int):
+        """The bucket-b fan-out → local top-k → merge program, lowered.
 
         One program per rung, same ladder and warmup contract as the
         replicated path.  Inside ``shard_map`` each device runs the
@@ -912,14 +902,7 @@ class BucketedScorer:
                 cand_g = jnp.swapaxes(lg, 0, 1).reshape(b, S * lk)
                 return merge_topk(cand_v, cand_g, k)
 
-        dummy_idx = self._put_repl(np.zeros(b, np.int32))
-        compiled = (
-            jax.jit(fn)
-            .lower(*self._static_args, dummy_idx)
-            .compile()
-        )
-        self._annotate_cost(b, compiled)
-        return compiled
+        return jax.jit(fn).lower(*self._lower_args(b))
 
     def _annotate_cost(self, b: int, compiled) -> None:
         """Record bucket-b per-dispatch FLOPs/bytes on the accountant.
@@ -929,9 +912,27 @@ class BucketedScorer:
         declines (some backends return nothing useful).  Fused buckets
         always use the analytic fused model: the Pallas call is opaque to
         XLA's cost analysis, which would report the custom-call as ~free
-        and make MFU read as zero forever.
+        and make MFU read as zero forever.  IVF rungs always use the
+        analytic model: the probe scan's Pallas calls are opaque to XLA
+        cost analysis, and the analytic scanned-rows number (P_b·cap_pad,
+        not the full catalog) IS the story.  Called from ``__init__`` only.
         """
         rank = self._U.shape[1]
+        if self.retrieval == "ivf":
+            scanned = self._probes[b] * self._ivf_layout.cap_pad
+            if self.backend == "fused":
+                a_flops, a_bytes = _devprof.fused_score_cost(
+                    b, scanned, rank, self._local_k, self.factor_dtype
+                )
+                source = "analytic-fused"
+            else:
+                a_flops, a_bytes = _devprof.score_cost(
+                    b, scanned, rank, dtype=self.factor_dtype
+                )
+                source = "analytic"
+            self.devprof.set_cost(b, a_flops, a_bytes, source=source)
+            self._cost_bytes[b] = a_bytes
+            return
         if self.backend == "fused":
             a_flops, a_bytes = _devprof.fused_score_cost(
                 b, self._n_items_pad, rank, self.k, self.factor_dtype

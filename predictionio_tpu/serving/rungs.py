@@ -7,9 +7,12 @@ in what a rung's program computes, how a batch is padded to it and what they
 count.  What they share is HOW a program gets to the device and its answer
 back, and what the micro-batcher is told about it:
 
-* construction: every rung compiled → the :class:`LaunchGate` built from the
-  compiler's own byte counts → every rung run once on its input *in the form
-  a dispatch hands it over* → the launch lag measured on the lowest rung;
+* construction: every rung's program made ready — LOADED from the
+  :mod:`serving.program_store` where that engages and holds it, else traced,
+  lowered and compiled (and then kept there) → the :class:`LaunchGate` built
+  from the compiler's own byte counts → every rung run once on its input *in
+  the form a dispatch hands it over* → the launch lag measured on the lowest
+  rung;
 * a dispatch (:meth:`RungPrograms.run`): the dispatch record is told the
   ``rung``, the ``lag`` and whether ``more`` launches of the run follow (the
   batcher times its launch-ahead by them) → stage ``device_compute``, holding
@@ -28,11 +31,12 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 
 from predictionio_tpu.obs import tracing as _tracing
+from predictionio_tpu.serving import program_store as _program_store
 from predictionio_tpu.serving.launch_gate import (
     LaunchGate, measure_lag, program_bytes,
 )
@@ -41,18 +45,26 @@ from predictionio_tpu.serving.launch_gate import (
 class RungPrograms:
     """The compiled programs of one scorer, warm, and the way through them.
 
-    The scorer hands over what differs: ``compile(rung)`` (lower + compile
-    that rung's program), ``warm_args(rung)`` (the call's arguments as a
-    dispatch passes them) and ``fetch(outs)`` (the outputs a dispatch reads
-    back, as a pytree; the rest stay on the device).
+    The scorer hands over what differs: ``lower(rung)`` (that rung's
+    program traced and lowered: what ``.compile()`` is called on),
+    ``warm_args(rung)`` (the call's arguments as a dispatch passes them),
+    ``fetch(outs)`` (the outputs a dispatch reads back, as a pytree; the
+    rest stay on the device) and ``describe(rung)``: the rung's statics as
+    the program store's key takes them (JSON-able: everything of the scorer
+    that reaches the trace) and the arguments ``lower`` lowers it on.
+    Without ``describe`` every rung is compiled.
     """
 
-    def __init__(self, device, ladder: Sequence, compile: Callable,
-                 warm_args: Callable, fetch: Callable):
+    def __init__(self, device, ladder: Sequence, lower: Callable,
+                 warm_args: Callable, fetch: Callable,
+                 describe: Optional[Callable] = None):
         self.ladder = tuple(ladder)
         self._fetch = fetch
         self._lock = threading.Lock()
+        # rungs made ready, loaded or compiled
         self.compile_count = 0
+        # of those, taken from the program store: no trace, no lowering
+        self.programs_loaded = 0
         self.warmup_executions = 0
         self.hits = {r: 0 for r in self.ladder}
         # dispatches whose readback was requested before the wait, counted
@@ -60,11 +72,16 @@ class RungPrograms:
         self.readbacks_queued = 0
         self.fns = {}
         t0 = time.perf_counter()
+        store = _program_store.open_store() if describe else None
+        saves = store and _program_store.SavesBesideCompiles(store)
         for r in self.ladder:
-            self.fns[r] = compile(r)
+            self.fns[r] = self._ready(r, lower, describe, saves)
             self.compile_count += 1
-        # the wall of the ladder's compiles: trace, lowering and the
-        # backend's compile or the persistent cache's read, rung after rung
+        if saves:
+            saves.finish()
+        # the wall of the ladder: a rung's load from the program store, or
+        # its trace, lowering and the backend's compile or the persistent
+        # cache's read, rung after rung
         self.compile_s = time.perf_counter() - t0
         # run() is entered by two threads at once (the batcher's
         # launch-ahead)
@@ -80,6 +97,29 @@ class RungPrograms:
         args = warm_args(low)
         self.launch_lag_s = measure_lag(
             lambda: self._request(self.fns[low](*args)), jax.device_get)
+
+    def _ready(self, rung, lower, describe, saves):
+        """``rung``'s executable: the store's where it holds the program
+        this rung would lower to on ONE device, else compiled now and kept
+        there for the next deploy (written beside the next rung's backend
+        compile, which needs no GIL)."""
+        pre = None
+        if saves:
+            statics, args = describe(rung)
+            on = _program_store.lowered_on(args)
+            if on is not None:
+                pre = _program_store.preimage(statics, args, on)
+                loaded = saves.store.load(pre, on)
+                if loaded is not None:
+                    self.programs_loaded += 1
+                    return loaded
+        lowered = lower(rung)
+        if saves:
+            saves.start()
+        compiled = lowered.compile()
+        if pre is not None:
+            saves.add(pre, compiled, lowered)
+        return compiled
 
     def _warm(self, warm_args: Callable) -> None:
         for r in self.ladder:
@@ -137,6 +177,7 @@ class RungPrograms:
         with self._lock:
             return {
                 "compile_count": self.compile_count,
+                "programs_loaded": self.programs_loaded,
                 "compile_s": round(self.compile_s, 4),
                 "warmup_executions": self.warmup_executions,
                 "bucket_hits": {str(r): n for r, n in self.hits.items()},

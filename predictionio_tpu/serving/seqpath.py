@@ -7,8 +7,9 @@ parameter pytree stays on the device, one dispatch is the histories of the
 batcher's rows PACKED end to end on one token axis
 (``models/latent_moe.pack``), and the ladder is of TOKEN counts: a dispatch
 pads to the next rung, rows pad to a fixed count (a padded row repeats row
-0, which costs the score kernel nothing).  Every rung is lowered, compiled
-and run once before the scorer is handed out, so no request compiles; as
+0, which costs the score kernel nothing).  Every rung is made ready (lowered
+and compiled, or loaded from the program store: ``serving/rungs.py``) and
+run once before the scorer is handed out, so no request compiles; as
 with the bucketed scorer ``compile_count`` moves only then.
 
 ONE scorer for every packed sequence family.  The family is the module the
@@ -44,6 +45,7 @@ import numpy as np
 from predictionio_tpu.obs import tracing as _tracing
 from predictionio_tpu.ops import score_kernel as _score_kernel
 from predictionio_tpu.ops.topk import resolve_backend
+from predictionio_tpu.serving.program_store import config_statics
 from predictionio_tpu.serving.rungs import RungPrograms
 
 # token counts a dispatch pads to; the top rung also bounds one dispatch
@@ -90,9 +92,10 @@ class PackedSequenceScorer:
         # read back: the leaderboard, the merge counter, the family's own
         fetched = ("values", "indices", "merge") + self._own.fetch
         self._rungs = RungPrograms(
-            self._device, self.ladder, self._compile,
+            self._device, self.ladder, self._lower,
             warm_args=lambda t: self._call_args([np.zeros(1, np.int32)], t),
-            fetch=lambda out: {n: out[n] for n in fetched if n in out})
+            fetch=lambda out: {n: out[n] for n in fetched if n in out},
+            describe=self._describe)
         self._fns = self._rungs.fns
 
     compile_count = property(lambda self: self._rungs.compile_count)
@@ -108,15 +111,24 @@ class PackedSequenceScorer:
 
         return pio_seq_forward
 
-    def _compile(self, t: int):
-        """Lower + compile the ``t``-token program ahead of time."""
-        dummy = self._put(self._family.pack(
+    def _lower_args(self, t: int) -> tuple:
+        return self._params, self._put(self._family.pack(
             [np.zeros(1, np.int32)], t, self.max_rows))
-        return (
-            jax.jit(self._program(t))
-            .lower(self._params, dummy)
-            .compile()
-        )
+
+    def _lower(self, t: int):
+        """The ``t``-token program traced and lowered, ahead of time."""
+        return jax.jit(self._program(t)).lower(*self._lower_args(t))
+
+    def _describe(self, t: int) -> tuple:
+        """What ``_program(t)`` closes over, for the program store's key,
+        and the arguments it is lowered on."""
+        return {
+            "scorer": "PackedSequenceScorer",
+            "family": self._family.__name__,
+            "config": config_statics(self.config),
+            "rung": t, "k": self.k, "backend": self.backend,
+            "max_rows": self.max_rows,
+        }, self._lower_args(t)
 
     def _put(self, batch: dict):
         return jax.device_put(self._family.flatten(batch), self._device)

@@ -166,7 +166,8 @@ def test_a_held_dispatch_sheds_nothing_in_the_cell(cfg, capsys, monkeypatch):
 
 # what the scorers' set-up leaves in the counters, read as the window opens
 SETUP = {"fastpath.compile_s": 9.5, "fastpath.branch_traces": 18,
-         "fastpath.branch_calls": 60, "fastpath.compile_count": 6}
+         "fastpath.branch_calls": 60, "fastpath.compile_count": 6,
+         "fastpath.programs_loaded": 6}
 
 
 @pytest.mark.parametrize("name, before, want", [
@@ -176,7 +177,15 @@ SETUP = {"fastpath.compile_s": 9.5, "fastpath.branch_traces": 18,
     # family whose depth is a scan no counters: the line leaves them out
     ("setup.compile_s", {"fastpath.compile_count": 6}, None),
     ("setup.branch_trace_share", {"fastpath.compile_s": 9.5}, None),
-], ids=["compile_s", "branch_trace_share", "no-timer", "no-counters"])
+    # ISSUE 49: rungs taken from the program store over rungs made ready;
+    # 0 in the run that built the store, nothing on a program without the
+    # counter (the parent of ISSUE 49)
+    ("setup.program_load_share", SETUP, 100.0),
+    ("setup.program_load_share", {**SETUP, "fastpath.programs_loaded": 0},
+     0.0),
+    ("setup.program_load_share", {"fastpath.compile_count": 6}, None),
+], ids=["compile_s", "branch_trace_share", "no-timer", "no-counters",
+        "all-loaded", "none-loaded", "no-load-counter"])
 def test_the_set_ups_metrics_read_the_counters_before_the_window(
         name, before, want):
     from pio_bench.readers import load_reader

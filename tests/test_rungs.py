@@ -36,18 +36,20 @@ DEVICE = types.SimpleNamespace(memory_stats=lambda: None)
 
 
 def _programs(log, fetch=lambda outs: outs, on_call=None):
-    def compile(rung):
-        log.append(("compile", rung))
-
+    def lower(rung):
         def fn(x):
             log.append(("run", rung, x))
             if on_call is not None:
                 on_call(rung)
             return {n: _Out(n, log) for n in ("values", "indices", "h_last")}
 
-        return fn
+        def compile():
+            log.append(("compile", rung))
+            return fn
 
-    return RungPrograms(DEVICE, LADDER, compile,
+        return types.SimpleNamespace(compile=compile)
+
+    return RungPrograms(DEVICE, LADDER, lower,
                         warm_args=lambda r: (f"warm{r}",), fetch=fetch)
 
 
@@ -133,13 +135,13 @@ def test_the_record_is_told_before_the_launch(more):
 
 # -- through the real scorers ---------------------------------------------------
 
-# `stats()` keys at 511bf64 less the hot-set's block, with PR 48's `compile_s` (both
-# scorers) and `branch_traces` / `branch_calls` (`latent_moe`'s own); nested under
+# `stats()` keys at 511bf64 less the hot-set's block, with PR 48's `compile_s` and
+# PR 49's `programs_loaded` (both scorers) and `branch_traces` / `branch_calls` (`latent_moe`'s own); nested under
 # `kernel` where the bucketed scorer nests them
 BUCKETED_KEYS = {
     "buckets", "top_k", "serving_backend", "sharding", "pod",
     "retrieval_backend", "retrieval", "kernel", "compile_count", "compile_s",
-    "bucket_hits", "calls", "readbacks_queued", "held_launches",
+    "programs_loaded", "bucket_hits", "calls", "readbacks_queued", "held_launches",
     "launch_lag_ms", "queries", "padded_rows", "merge_passes",
     "merge_blocks", "row_occupancy", "devprof",
     "kernel.backend", "kernel.factor_dtype", "kernel.resident_factor_bytes",
@@ -148,7 +150,8 @@ BUCKETED_KEYS = {
 }
 PACKED_KEYS = {
     "family", "token_ladder", "max_rows", "top_k", "backend", "block_items",
-    "resident_bytes", "compile_count", "compile_s", "warmup_executions",
+    "resident_bytes", "compile_count", "compile_s", "programs_loaded",
+    "warmup_executions",
     "bucket_hits", "calls", "readbacks_queued", "held_launches",
     "launch_lag_ms", "queries", "tokens", "padded_tokens", "causal_pairs",
     "merge_passes",

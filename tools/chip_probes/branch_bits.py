@@ -50,6 +50,8 @@ sc = PackedSequenceScorer(
 doc = {"config": name, "seed": seed, "platform": jax.devices()[0].platform,
        "device_kind": jax.devices()[0].device_kind,
        "compile_count": sc.compile_count,
+       # PR 49: rungs taken from the program store (None: a tree without it)
+       "programs_loaded": sc.stats().get("programs_loaded"),
        "warmup_executions": sc.warmup_executions, "rungs": {}}
 rng = np.random.default_rng(seed)
 for t in sc.ladder:

@@ -12,7 +12,8 @@
 # Results: chiprun_out/<tag>.setup_in_cell.jsonl (one line a run: the result
 # line's metrics, seconds, phases, per rung trace / lowering / cache retrieval
 # / backend compile and the inner traces, cache hits and misses, the cache
-# directory's bytes before and after), each run's log and whole JSON under
+# directory's bytes before and after, since PR 49 `programs_loaded` and the
+# program store's bytes and entries beside them), each run's log and whole JSON under
 # chiprun_out/<tag>/.
 tag=$1; shift
 root=$(pwd); mkdir -p $root/chiprun_out/$tag
@@ -58,6 +59,7 @@ with open(f"chiprun_out/{tag}.setup_in_cell.jsonl", "w") as out:
                 "backend_compile": round(sum(kind("backend_compile")), 3),
                 "inner_traces": [round(s, 3) for s in traces[:-1]]}
         ca, cb = d.get("cache_after") or {}, d.get("cache_before") or {}
+        sa, sb = d.get("store_after") or {}, d.get("store_before") or {}
         fp = ((d.get("root") or {}).get("fastpath") or [{}])[0]
         line = {
             "run": int(order), "side": side, "cell_and": rest,
@@ -74,11 +76,15 @@ with open(f"chiprun_out/{tag}.setup_in_cell.jsonl", "w") as out:
             "counts": {k.rsplit("/", 1)[-1]: v for k, v in d["counts"].items()},
             "cache_bytes": [cb.get("bytes"), ca.get("bytes")],
             "cache_entries": [cb.get("entries"), ca.get("entries")],
+            # PR 49: the program store beside the cache, before and after
+            "programs_loaded": fp.get("programs_loaded"),
+            "store_bytes": [sb.get("bytes"), sa.get("bytes")],
+            "store_entries": [sb.get("entries"), sa.get("entries")],
             "written_mb": [round(r["bytes"] / 1e6, 1) for r in ca.get("large", [])
                            if r["written_by_this_run"]],
             "stats": {k: fp.get(k) for k in (
-                "compile_count", "compile_s", "warmup_executions",
-                "branch_traces", "branch_calls")}}
+                "compile_count", "programs_loaded", "compile_s",
+                "warmup_executions", "branch_traces", "branch_calls")}}
         out.write(json.dumps(line) + "\n")
         tl = {t: (r["trace"], r["lower"], r["cache_retrieval"], r["backend_compile"])
               for t, r in per_rung.items()}
@@ -86,11 +92,13 @@ with open(f"chiprun_out/{tag}.setup_in_cell.jsonl", "w") as out:
             "result": result and {
                 "correct": result["correct"], "failed": result["failed"],
                 **{k: round(v["value"], 3) for k, v in result["metrics"].items()
-                   if k in ("setup_s", "serve.p50_ms", "setup.compile_s",
+                   if k in ("setup_s", "serve.p50_ms", "serve.p95_ms",
+                            "setup.compile_s", "setup.program_load_share",
                             "setup.branch_trace_share")}},
             "seconds": line["seconds"], "compile": {k: v for k, v in ph.items() if k.startswith("compile.")},
             "trace,lower,retrieval,backend": tl,
             "hits": line["cache_hits"], "misses": line["cache_misses"],
             "cache_mb": [round((b or 0) / 1e6, 1) for b in line["cache_bytes"]],
+            "store_mb": [round((b or 0) / 1e6, 1) for b in line["store_bytes"]],
             "stats": line["stats"]}))
 PY

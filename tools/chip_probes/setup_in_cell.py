@@ -7,9 +7,14 @@ run's result, as JSON:
 * `env`: where JAX's persistent compile cache lies and what bounds it (the
   variables the machine sets, and `jax.config`'s own);
 * `cache_before` / `cache_after`: the cache directory's entries (name, bytes,
-  written by this run or found);
+  written by this run or found); `store_before` / `store_after`: the same of
+  the program store's directory beside it (PR 49; `programs_loaded` is in
+  `root`, the scorer's counters);
 * `phases`: wall seconds of `QueryServer.__init__`, the scorer's `__init__`,
-  each rung's `_compile`, `program_bytes` of each rung, each rung's warm-up
+  each rung made ready (`compile.<rung>`: the scorer's `_compile` on a tree
+  before PR 49, `RungPrograms._ready` since, a load from the program store
+  or trace + lowering + compile; `store.load` / `store.save` inside it),
+  `program_bytes` of each rung, each rung's warm-up
   run (`_warm` is replaced by a copy of itself that times them),
   `measure_lag`, `QueryServer.start`, each with its offset from the process's
   start;
@@ -29,8 +34,8 @@ Nothing here touches the measured window: the wrappers sit on set-up calls,
 and the listeners fire only when something compiles.  The result line is
 still the last line of stdout.
 
-`SETUP_IN_CELL_PROFILE=2048,16384` runs those rungs' `_compile` under
-cProfile and keeps the 60 dearest functions of each (`profiles`, by own
+`SETUP_IN_CELL_PROFILE=2048,16384` runs those rungs' `_compile` (`_lower`
+since PR 49) under cProfile and keeps the 60 dearest functions of each (`profiles`, by own
 time and cumulative), with the process's state at that point (`state`:
 threads, stack depth, log levels, profile and trace hooks, GC counts);
 `SETUP_IN_CELL_SAMPLE=1` instead samples the main thread's stack every 5 ms
@@ -95,8 +100,12 @@ def cache_dir():
             or mesh_mod.COMPILE_CACHE_DIR)
 
 
-def cache_entries():
-    d = cache_dir()
+def store_dir():
+    return os.path.normpath(cache_dir()) + "-programs"
+
+
+def cache_entries(d=None):
+    d = d or cache_dir()
     if not d or not os.path.isdir(d):
         return None
     rows = []
@@ -113,10 +122,20 @@ from predictionio_tpu.parallel import mesh as mesh_mod  # noqa: E402
 from predictionio_tpu.serving import query_server, rungs, seqpath  # noqa: E402
 
 doc["cache_before"] = cache_entries()
+doc["store_before"] = cache_entries(store_dir())
 
 Scorer = seqpath.PackedSequenceScorer
 timed(Scorer, "__init__", "scorer.__init__")
-timed(Scorer, "_compile", lambda self, t: f"compile.{t}")
+if hasattr(rungs.RungPrograms, "_ready"):  # since PR 49, both scorers
+    from predictionio_tpu.serving import program_store
+
+    timed(rungs.RungPrograms, "_ready", lambda self, r, *a: f"compile.{r}")
+    timed(program_store.ProgramStore, "load", "store.load")
+    timed(program_store.ProgramStore, "save", "store.save")
+    LOWER = "_lower"
+else:
+    timed(Scorer, "_compile", lambda self, t: f"compile.{t}")
+    LOWER = "_compile"
 timed(rungs, "measure_lag")
 timed(rungs, "program_bytes")
 timed(query_server.QueryServer, "__init__", "QueryServer.__init__")
@@ -140,7 +159,7 @@ timed(rungs.RungPrograms, "_warm", "warm")
 PROFILE = {int(t) for t in
            os.environ.get("SETUP_IN_CELL_PROFILE", "").split(",") if t}
 if PROFILE:
-    compile_rung = Scorer._compile
+    compile_rung = getattr(Scorer, LOWER)
     doc["profiles"] = {}
 
     def compile_under_profile(self, t):
@@ -166,7 +185,7 @@ if PROFILE:
                 pstats.Stats(prof, stream=text).sort_stats(order).print_stats(60)
                 doc["profiles"][str(t)][order] = text.getvalue()
 
-    Scorer._compile = compile_under_profile
+    setattr(Scorer, LOWER, compile_under_profile)
 
 cell = sys.argv[sys.argv.index("--workload") + 1]
 config = next(w["config"] for w in
@@ -261,6 +280,7 @@ finally:
             jax.config.jax_persistent_cache_min_entry_size_bytes,
     }
     doc["cache_after"] = cache_entries()
+    doc["store_after"] = cache_entries(store_dir())
     doc["counts"] = dict(doc["counts"])
     # the thousands of sub-millisecond traces of inner functions: summed
     small = collections.defaultdict(lambda: [0, 0.0])

@@ -37,9 +37,12 @@ family = importlib.import_module(
     "predictionio_tpu.models." + c["engine"].replace("_sequence", ""))
 # `model_config` takes the rehearsal's widths off the chip: the published
 # ones are wanted here
-hf = engine.model_config({**c, "rehearsal": {**c["rehearsal"], "model": {},
-                                             "router_experts": (
-    c.get("published") or {}).get("num_experts")}})
+published = c.get("published") or {}
+hf = engine.model_config({**c, "rehearsal": {
+    **c["rehearsal"], "model": {},
+    # the window family's key, or the routed state-space family's
+    "router_experts": published.get(
+        "num_experts", published.get("num_local_experts"))}})
 cfg = family.Config.from_hf(hf, max_len=c["serving"]["max_len"])
 P = {n: jax.ShapeDtypeStruct(s, d)
      for n, (s, d) in family.param_shapes(cfg).items()}
